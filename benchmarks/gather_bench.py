@@ -160,16 +160,15 @@ def run(n_db=20_000, qs=(16, 64, 256), ms=(8, 16, 32), beam=4, ef=64,
                     return jax.lax.top_k(s, kk)
 
                 @functools.partial(jax.jit, static_argnames=("kk",))
-                def blocked_kernel(queries, nbr, nbr_cnt, pop, flat, worst,
-                                   kk):
+                def blocked_kernel(queries, nbr, pop, flat, worst, kk):
                     return ops.expand_tanimoto_sorted(
-                        queries, nbr, nbr_cnt, pop, flat, worst, kk)
+                        queries, nbr, pop, flat, worst, kk)
 
                 t_rk = timeit(lambda: row_kernel(
                     c["queries"], c["db"], c["flat"], c["worst"], kk),
                     repeats=1, warmup=1)
                 t_bk = timeit(lambda: blocked_kernel(
-                    c["queries"], c["nbr"], c["nbr_cnt"], c["pop"],
+                    c["queries"], c["nbr"], c["pop"],
                     c["flat"], c["worst"], kk), repeats=1, warmup=1)
                 row.update(
                     us_row_kernel=round(t_rk * 1e6, 1),

@@ -2,7 +2,7 @@
 
 ``--backend`` sweeps the same grid through the engine's execution paths
 (``numpy`` host reference / ``jnp`` device traversal / ``tpu`` device
-traversal with the Pallas gather-distance kernel, interpret-mode off-TPU),
+traversal with the Pallas gather-distance kernel, interpreted on CPU),
 so the paper's Fig. 12 recall-vs-QPS operating point is directly trackable
 per backend. Rows land in the ``experiments/bench`` JSON schema with
 ``backend``/``beam`` columns plus the traversal telemetry

@@ -17,6 +17,9 @@ def main() -> None:
     ap.add_argument("--only", default=None)
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
+
     from . import (bitbound_speedup, engine_throughput, exhaustive_qps,
                    folding_accuracy, hnsw_grid, pareto)
 

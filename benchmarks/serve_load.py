@@ -49,6 +49,7 @@ import time
 import numpy as np
 
 from repro.data.molecules import SyntheticConfig, synthetic_fingerprints, queries_from_db
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.search_serve import make_workload
 from repro.serve.service import SearchService
 from .common import emit
@@ -625,6 +626,7 @@ def main():
                     help="override the emitted artifact suffix (e.g. "
                          "_smoke keeps CI runs off the committed rows)")
     args = ap.parse_args()
+    use_compile_cache()
     if args.frontend:
         rows = run_frontend_slo(
             n_db=args.n_db, n_ops=args.ops, k=args.k, backend=args.backend,

@@ -21,7 +21,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .fingerprints import Metric, TANIMOTO, popcount
+from .fingerprints import Metric, TANIMOTO, metric_from_counts_np, popcount
 
 
 @dataclass
@@ -49,60 +49,78 @@ def build_index(db: jax.Array) -> BitBoundIndex:
                          mu=float(counts.mean()), sigma=float(counts.std()))
 
 
-def bound_range(index: BitBoundIndex, query_count: jax.Array, cutoff: float,
+def bound_range(index: BitBoundIndex, query_count, cutoff: float,
                 metric: Metric = TANIMOTO):
     """Per-metric candidate range [lo, hi) in the popcount-sorted database
-    (Tanimoto: the paper's Eq. 2)."""
-    a = query_count.astype(jnp.float32)
-    if metric.name == "tanimoto":
-        lo_cnt = jnp.ceil(a * cutoff)
-        hi_cnt = jnp.floor(a / jnp.maximum(cutoff, 1e-6))
-    else:
-        lo_r, hi_r = metric.bound_ratios(cutoff)
-        lo_cnt = jnp.ceil(a * lo_r) if metric.bounded_below else jnp.zeros_like(a)
-        hi_cnt = (jnp.minimum(jnp.floor(a * hi_r), 2.0**30)
-                  if metric.bounded_above else jnp.full_like(a, 2.0**30))
-    lo = jnp.searchsorted(index.counts, lo_cnt.astype(jnp.int32), side="left")
-    hi = jnp.searchsorted(index.counts, hi_cnt.astype(jnp.int32), side="right")
-    return lo, hi
+    (Tanimoto: the paper's Eq. 2) — :func:`bound_range_np` on one index."""
+    return bound_range_np(np.asarray(index.counts), np.asarray(query_count),
+                          cutoff, metric=metric)
+
+
+def _best_score_f32(metric: Metric, a: np.ndarray, b: np.ndarray):
+    """Highest score a print of popcount ``b`` can reach against a query of
+    popcount ``a`` (overlap c = min(a, b) — every metric grows with c), in
+    the f32 arithmetic the engines score with."""
+    return metric_from_counts_np(metric, np.minimum(a, b), a, b)
+
+
+def _widen(edge: np.ndarray, step: int, a: np.ndarray, sc: np.float32,
+           metric: Metric) -> np.ndarray:
+    """Move each window edge outward while the next popcount can still
+    score >= ``sc``. The best score falls monotonically away from b = a, so
+    the loop stops at the exact f32 boundary."""
+    while True:
+        nxt = edge + step
+        ok = np.isfinite(nxt) & (nxt >= 0)
+        ok[ok] = _best_score_f32(metric, a[ok], nxt[ok].astype(np.int64)) >= sc
+        if not ok.any():
+            return edge
+        edge = np.where(ok, nxt, edge)
 
 
 def bound_counts_np(query_counts: np.ndarray, cutoff: float,
                     metric: Metric = TANIMOTO):
-    """Per-metric popcount bounds in float64 (Tanimoto: Eq. 2
-    ``[ceil(a*Sc), floor(a/Sc)]``; others via ``Metric.bound_ratios``).
+    """Per-metric popcount bounds ``[lo_cnt, hi_cnt]`` (float64 arrays of
+    integer values; an unbounded side is 0 / +inf).
 
-    THE host-side bound formula: :func:`bound_range_np` (main-segment
-    windows) and the engines' delta-segment masks all call this one helper —
-    the insert-then-rebuild bit-parity contract requires the main window and
-    the delta mask to agree on every boundary popcount, so the clamp and
-    float width must never diverge between call sites. Unbounded sides come
-    back as 0 / +inf (searchsorted treats them as full-scan windows, and
-    ``scanned`` reflects the full scan).
+    THE window formula: every engine, kernel wrapper and oracle derives its
+    BitBound window from this one function — main-segment row windows
+    (:func:`bound_range_np`), delta-segment masks and the kernel tests —
+    so no two of them can disagree on a boundary popcount. The start is
+    the real-arithmetic bound (Tanimoto: Eq. 2 ``[ceil(a*Sc), floor(a/Sc)]``;
+    others via ``Metric.bound_ratios``). Scores are f32, whose rounding can
+    lift a boundary popcount's best score onto the f32 cutoff, so each edge
+    is then widened to the last popcount whose best f32 score still
+    reaches ``float32(cutoff)``: any print that scores >= the cutoff on
+    the device lies inside the window.
     """
-    a = np.asarray(query_counts, dtype=np.float64)
+    shape = np.shape(query_counts)
+    a = np.asarray(query_counts, dtype=np.int64).reshape(-1)
+    af = a.astype(np.float64)
     if metric.name == "tanimoto":
-        lo_cnt = np.ceil(a * cutoff)
-        hi_cnt = np.floor(a / max(cutoff, 1e-6))
-        return lo_cnt, hi_cnt
-    lo_r, hi_r = metric.bound_ratios(cutoff)
-    lo_cnt = np.ceil(a * lo_r) if metric.bounded_below else np.zeros_like(a)
-    hi_cnt = (np.floor(a * hi_r) if metric.bounded_above
-              else np.full_like(a, np.inf))
-    return lo_cnt, hi_cnt
+        lo_cnt = np.ceil(af * cutoff)
+        hi_cnt = np.floor(af / max(cutoff, 1e-6))
+    else:
+        lo_r, hi_r = metric.bound_ratios(cutoff)
+        lo_cnt = (np.ceil(af * lo_r) if metric.bounded_below
+                  else np.zeros_like(af))
+        hi_cnt = (np.floor(af * hi_r) if metric.bounded_above
+                  else np.full_like(af, np.inf))
+    sc = np.float32(cutoff)
+    if sc > 0:
+        if metric.bounded_below:
+            lo_cnt = _widen(lo_cnt, -1, a, sc, metric)
+        if metric.bounded_above:
+            hi_cnt = _widen(hi_cnt, 1, a, sc, metric)
+    return lo_cnt.reshape(shape), hi_cnt.reshape(shape)
 
 
 def bound_range_np(counts_sorted: np.ndarray, query_counts: np.ndarray,
                    cutoff: float, metric: Metric = TANIMOTO):
-    """Host-side batched per-metric windows [lo, hi) for a whole query batch.
-
-    Numpy analogue of :func:`bound_range`; the engine uses it to size the
-    static kernel grid (a Python int) before dispatching to device. Note the
-    bound is evaluated in float64 here vs float32 on device, so for popcounts
-    landing exactly on the a/Sc boundary the two can differ by one count
-    value — both are valid Eq.2 windows, but don't cross-validate them
-    expecting bit-equality.
-    """
+    """Host-side batched per-metric windows [lo, hi) for a whole query batch
+    over a popcount-sorted count vector (:func:`bound_counts_np` bounds).
+    The engines use it to size the static kernel grid (a Python int) and
+    pass the rows to the device."""
     lo_cnt, hi_cnt = bound_counts_np(query_counts, cutoff, metric)
     lo = np.searchsorted(counts_sorted, lo_cnt, side="left")
     hi = np.searchsorted(counts_sorted, hi_cnt, side="right")

@@ -28,7 +28,6 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from .fingerprints import popcount, tanimoto_scores
 from .topk import NEG_INF, merge_sorted_many, streaming_topk
@@ -95,10 +94,10 @@ def make_sharded_search(mesh, n_total: int, k: int, use_kernel: bool = False,
         vals = jnp.where(ids >= 0, vals, 0.0)
         return vals, ids
 
-    fn = shard_map(local_fn, mesh=mesh,
-                   in_specs=(P(), db_spec, cnt_spec),
-                   out_specs=(P(), P()),
-                   check_rep=False)
+    fn = jax.shard_map(local_fn, mesh=mesh,
+                       in_specs=(P(), db_spec, cnt_spec),
+                       out_specs=(P(), P()),
+                       check_vma=False)
     return jax.jit(fn), db_spec, cnt_spec
 
 

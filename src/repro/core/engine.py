@@ -3,8 +3,8 @@
 Three engines, one per paper design point, on one shared base:
 
 * :class:`BruteForceEngine` — exhaustive linear scan with the fused
-  scan+top-k path (on-the-fly engine; Pallas kernel when enabled, streaming
-  jnp fallback otherwise).
+  scan+top-k path (on-the-fly engine; the Pallas kernel on the ``tpu``
+  backend, a streaming jnp scan on ``jnp``).
 * :class:`BitBoundFoldingEngine` — exhaustive with Eq.2 popcount pruning and
   2-stage modulo-OR folding; host-side numpy reference plus a fully
   device-resident ``search_tpu`` path.
@@ -22,8 +22,9 @@ Every engine exposes ``search(queries, k) -> (ids, sims)`` numpy arrays and a
 * ``"jnp"``   — fully device-resident fixed-shape path built from plain jnp
   ops (works on any JAX backend, no Pallas required).
 * ``"tpu"``   — same device-resident path with its hot stage swapped for the
-  Pallas kernel (Mosaic on TPU, interpret mode elsewhere). Engines fall back
-  to the ``jnp`` stage automatically when Pallas cannot be imported.
+  Pallas kernel: compiled through Mosaic on a TPU, run in the Pallas
+  interpreter on CPU (how the tests run), refused on any other backend. A
+  kernel that fails to import or lower raises; nothing falls back.
 
 Invalid names raise ``ValueError`` listing the engine's supported backends.
 The legacy ``use_kernel=True`` flag maps onto ``backend="tpu"`` when
@@ -71,18 +72,9 @@ from . import hnsw as hn
 from ..obs.trace import TRACER as _TR
 from .distributed import merge_shard_topk, shard_devices
 from .fingerprints import (Metric, TANIMOTO, batched_metric_scores,
-                           batched_tanimoto_scores, metric_from_counts,
-                           metric_from_counts_np, metric_scores, popcount,
-                           resolve_metric, tanimoto_scores)
+                           metric_from_counts, metric_from_counts_np,
+                           metric_scores, popcount, resolve_metric)
 from .topk import merge_sorted, streaming_topk
-
-
-def _kernels_available() -> bool:
-    try:
-        from ..kernels import ops  # noqa: F401
-        return True
-    except Exception:  # Pallas/Mosaic not importable on this install
-        return False
 
 
 def _store_mod():
@@ -239,13 +231,8 @@ def _brute_topk(queries: jax.Array, db: jax.Array, db_cnt: jax.Array, k: int,
                                   metric=metric)
 
     def one(q):
-        # the tanimoto branch keeps the historical scorer verbatim (HLO
-        # bit-identity for the default path)
-        if metric.name == "tanimoto":
-            s = tanimoto_scores(q, db, db_cnt)
-        else:
-            s = metric_scores(q, db, metric, db_cnt)
-        return streaming_topk(s, k, tile=tile)
+        return streaming_topk(metric_scores(q, db, metric, db_cnt), k,
+                              tile=tile)
 
     vals, idxs = jax.vmap(one)(queries)
     return idxs, vals
@@ -254,7 +241,7 @@ def _brute_topk(queries: jax.Array, db: jax.Array, db_cnt: jax.Array, k: int,
 @dataclass
 class BruteForceEngine(SearchEngine):
     """Exhaustive scan. ``backend``: ``"tpu"`` = fused Pallas kernel
-    (interpret-mode off-TPU), ``"jnp"`` = streaming jnp path.
+    (the Pallas interpreter on CPU), ``"jnp"`` = streaming jnp path.
 
     Online inserts append to the store's delta segment; a search scans the
     (capacity-padded, global-id-ordered) main segment with the compiled
@@ -297,7 +284,7 @@ class BruteForceEngine(SearchEngine):
 
     def __post_init__(self):
         self._init_engine()
-        self.use_kernel = self.backend == "tpu" and _kernels_available()
+        self.use_kernel = self.backend == "tpu"
         if self.store is None:
             self.store = _store_mod().MutableFingerprintStore(
                 np.asarray(self.db), sorted_main=False, fold_m=1,
@@ -468,15 +455,22 @@ class BruteForceEngine(SearchEngine):
             tiered_stall_fraction=(stall / total) if total > 0 else 0.0)
         return run_ids, run_vals
 
+    def device_pipeline(self, queries, k: int):
+        """The jitted main-segment scan (device residency) for this batch
+        and its arguments: ``fn(*args) -> (ids, vals)`` device arrays."""
+        self._sync()
+        fn = self._cached(("main", int(k), self.db.shape[0]),
+                          self._main_builder(k))
+        return fn, (jnp.asarray(queries), self.db, self.db_cnt)
+
     def search(self, queries, k: int):
         self._sync()
         q = jnp.asarray(queries)
         if self.residency == "tiered":
             ids, vals = self._tiered_main_topk(q, k)
         else:
-            fn = self._cached(("main", int(k), self.db.shape[0]),
-                              self._main_builder(k))
-            ids, vals = fn(q, self.db, self.db_cnt)
+            fn, args = self.device_pipeline(q, k)
+            ids, vals = fn(*args)
         if self._delta_dev is not None:
             ddb, dcnt, bucket = self._delta_dev
             dfn = self._cached(("delta", int(k), bucket),
@@ -528,8 +522,8 @@ class BitBoundFoldingEngine(SearchEngine):
       searchsorteds) is rescored at full resolution with a fused top-k — one
       jitted function per ``(window bucket, k, delta bucket, capacity)``, no
       host round-trips, returning ``(ids, sims, scanned)`` device arrays.
-      When Pallas is unavailable (or ``backend="jnp"``) stage 1 falls back to
-      a masked jnp scan with identical results.
+      With ``backend="jnp"`` stage 1 is a masked jnp scan with identical
+      results.
 
     ``backend`` selects what :meth:`search` runs: ``"numpy"`` (default,
     reference), ``"tpu"`` (Pallas device path) or ``"jnp"`` (device path
@@ -749,18 +743,10 @@ class BitBoundFoldingEngine(SearchEngine):
         if self._device_state is not None and \
                 self._device_state["capacity"] == cap:
             return self._device_state
-        kops = None
-        if self.backend != "jnp":
-            try:
-                from ..kernels import ops as kops_mod
-                kops = kops_mod
-            except Exception:  # Pallas unavailable: fall back to jnp stage 1
-                kops = None
-        if kops is not None:
-            tile = kops._pick_tile(cap, None)
-        else:
-            tile = min(2048, max(128, 1 << (max(cap - 1, 1).bit_length() - 1)))
-        self._device_state = {"kops": kops, "tile": tile,
+        from ..kernels import ops as kops
+        tile = kops._pick_tile(cap, None)
+        self._device_state = {"kops": kops if self.backend == "tpu" else None,
+                              "tile": tile,
                               "total_tiles": (cap + tile - 1) // tile,
                               "capacity": cap}
         return self._device_state
@@ -1101,19 +1087,13 @@ class BitBoundFoldingEngine(SearchEngine):
         sims = np.where(ok, vals_np, 0.0).astype(np.float32)
         return ids, sims
 
-    def search_tpu(self, queries, k: int):
-        """Fixed-shape device path -> ``(ids, sims, scanned)`` jax arrays.
-
-        Host work is only window metadata (two searchsorteds + the delta
-        popcount mask per batch and the power-of-two grid bucket); the folded
-        scans, merge, gather, rescore and top-k all run inside one jitted
-        function per ``(bucket, k, delta bucket, capacity)``.
-        """
+    def _batch_plan(self, q_np: np.ndarray):
+        """Host-side metadata of one batch: per-query Eq.2 row windows
+        ``[lo, hi)``, the power-of-two grid bucket, the delta bucket and
+        the delta rows' popcount-window mask (None without a delta)."""
         self._sync()
         state = self._device_meta()
         tile, total_tiles = state["tile"], state["total_tiles"]
-        queries = jnp.asarray(queries)
-        q_np = np.asarray(queries)
         a = np.bitwise_count(q_np).sum(-1)
         lo, hi = bb.bound_range_np(self._counts_np, a, self.cutoff,
                                    metric=self.metric)
@@ -1121,11 +1101,9 @@ class BitBoundFoldingEngine(SearchEngine):
                            (hi + tile - 1) // tile - lo // tile, 0)
         bucket = bb.bucket_tiles(int(n_tiles.max(initial=0)), total_tiles)
         if state["kops"] is None:
-            bucket = total_tiles  # jnp fallback scans full rows, one variant
+            bucket = total_tiles  # the jnp stage scans full rows, one variant
         dd = self._delta_dev
         delta_bucket = dd["bucket"] if dd is not None else 0
-        lo_j = jnp.asarray(lo, jnp.int32)
-        hi_j = jnp.asarray(hi, jnp.int32)
         ok_np = None
         if dd is not None:
             lo_cnt, hi_cnt = bb.bound_counts_np(a, self.cutoff,
@@ -1135,14 +1113,51 @@ class BitBoundFoldingEngine(SearchEngine):
             ok_np[:, :d_cnt_np.shape[0]] = (
                 (d_cnt_np[None, :] >= lo_cnt[:, None]) &
                 (d_cnt_np[None, :] <= hi_cnt[:, None]))
+        return lo, hi, bucket, delta_bucket, ok_np
+
+    def device_pipeline(self, queries, k: int):
+        """The jitted device-resident pipeline for this batch and its
+        arguments: ``fn(*args) -> (ids, sims, scanned)`` device arrays."""
+        queries = jnp.asarray(queries)
+        plan = self._batch_plan(np.asarray(queries))
+        return self._resident_call(queries, k, *plan)
+
+    def _resident_call(self, queries, k, lo, hi, bucket, delta_bucket,
+                       ok_np):
+        fn = self._cached(
+            (bucket, int(k), delta_bucket, self._device_meta()["capacity"]),
+            lambda: self._build_device_search(bucket, k, delta_bucket))
+        args = (queries, jnp.asarray(lo, jnp.int32), jnp.asarray(hi, jnp.int32),
+                self.folded, self.folded_cnt, self.full, self.full_cnt,
+                self.order)
+        dd = self._delta_dev
+        if dd is not None:
+            args += (dd["full"], dd["folded"], dd["cnt"], dd["folded_cnt"],
+                     jnp.asarray(ok_np), jnp.int32(self.store.n_main))
+        return fn, args
+
+    def search_tpu(self, queries, k: int):
+        """Fixed-shape device path -> ``(ids, sims, scanned)`` jax arrays.
+
+        Host work is only window metadata (two searchsorteds + the delta
+        popcount mask per batch and the power-of-two grid bucket); the folded
+        scans, merge, gather, rescore and top-k all run inside one jitted
+        function per ``(bucket, k, delta bucket, capacity)``.
+        """
+        queries = jnp.asarray(queries)
+        q_np = np.asarray(queries)
+        lo, hi, bucket, delta_bucket, ok_np = self._batch_plan(q_np)
         # m == 1 never gathers full-resolution rows (folded == full, stage-1
         # scores are already exact), so the device pipeline serves tiered
         # mode as-is with `self.full is None` — the traced function simply
         # never touches that argument.
         if self.residency == "tiered" and self.m > 1:
+            dd = self._delta_dev
+            lo_j = jnp.asarray(lo, jnp.int32)
+            hi_j = jnp.asarray(hi, jnp.int32)
             cfn = self._cached(
                 ("tiercand", bucket, int(k), delta_bucket,
-                 state["capacity"]),
+                 self._device_meta()["capacity"]),
                 lambda: self._build_tiered_candidates(bucket, k,
                                                       delta_bucket))
             with _TR.span("bitbound.stage1", bucket=int(bucket),
@@ -1165,23 +1180,11 @@ class BitBoundFoldingEngine(SearchEngine):
             scanned = int(np.maximum(hi - lo, 0).sum()) + extra
             self._record_batch(scanned, q_np.shape[0])
             return ids, sims, scanned
-        fn = self._cached(
-            (bucket, int(k), delta_bucket, state["capacity"]),
-            lambda: self._build_device_search(bucket, k, delta_bucket))
+        fn, args = self._resident_call(queries, k, lo, hi, bucket,
+                                       delta_bucket, ok_np)
         with _TR.span("bitbound.pipeline", bucket=int(bucket),
                       delta_bucket=int(delta_bucket)):
-            if dd is None:
-                ids, sims, scanned = fn(queries, lo_j, hi_j, self.folded,
-                                        self.folded_cnt, self.full,
-                                        self.full_cnt, self.order)
-            else:
-                ids, sims, scanned = fn(queries, lo_j, hi_j, self.folded,
-                                        self.folded_cnt, self.full,
-                                        self.full_cnt, self.order,
-                                        dd["full"], dd["folded"],
-                                        dd["cnt"], dd["folded_cnt"],
-                                        jnp.asarray(ok_np),
-                                        jnp.int32(self.store.n_main))
+            ids, sims, scanned = fn(*args)
         self._record_batch(scanned, queries.shape[0])
         return ids, sims, scanned
 
@@ -1197,8 +1200,7 @@ class HNSWEngine(SearchEngine):
     * ``"jnp"``   — batched device-resident traversal with the jnp
       gather-distance stage.
     * ``"tpu"``   — same traversal with the Pallas ``gather_tanimoto``
-      kernel as the fine-grained distance stage (jnp fallback when Pallas
-      is unavailable).
+      kernel as the fine-grained distance stage.
 
     ``layout`` selects the fine-grained distance stage's memory layout
     (bit-exact results either way):
@@ -1465,7 +1467,7 @@ class HNSWEngine(SearchEngine):
                 self._shard_dirty[s] = True
             return gids
         factory = None
-        if self.backend == "tpu" and _kernels_available():
+        if self.backend == "tpu":
             factory = self._insert_scorer_factory
         gids = hn.insert_hnsw(self.index, fps, scorer_factory=factory)
         # lazy device refresh: N consecutive insert batches cost one
@@ -1474,7 +1476,7 @@ class HNSWEngine(SearchEngine):
         return gids
 
     def _device_search(self, k: int, ef: int, beam: int, max_level: int):
-        use_kernel = self.backend == "tpu" and _kernels_available()
+        use_kernel = self.backend == "tpu"
         layout = self.layout
         max_iters = self.max_iters
         metric = self.metric
@@ -1498,7 +1500,7 @@ class HNSWEngine(SearchEngine):
                     if use_kernel:
                         def expand_fn(qs, qc, pop, flat, worst, kk):
                             return kops.expand_tanimoto_sorted(
-                                qs, nbr_fps, nbr_cnt, pop, flat, worst, kk,
+                                qs, nbr_fps, pop, flat, worst, kk,
                                 q_cnt=qc, metric=metric)
                     else:
                         def expand_fn(qs, qc, pop, flat, worst, kk):
@@ -1574,6 +1576,19 @@ class HNSWEngine(SearchEngine):
         }
         return np.asarray(ids), np.asarray(sims)
 
+    def device_pipeline(self, queries, k: int, ef: int | None = None,
+                        beam: int | None = None):
+        """The jitted lock-step traversal (unsharded, device backend) for
+        this batch and its arguments: ``fn(*args) -> (ids, sims, stats)``
+        device arrays."""
+        if self._graph_dirty:
+            self._refresh_graph()
+        g = self._graph
+        fn = self._device_search(k, ef or self.ef_search, beam or self.beam,
+                                 g.max_level)
+        return fn, (jnp.asarray(queries), g.db, g.db_popcount, g.base_adj,
+                    g.upper_adj, g.entry_point, g.nbr_fps, g.nbr_cnt)
+
     def search(self, queries, k: int, ef: int | None = None,
                beam: int | None = None):
         ef = ef or self.ef_search
@@ -1598,13 +1613,8 @@ class HNSWEngine(SearchEngine):
                               "neighbour_evals": ctr["evals"]}
                 sp.set(iters=ctr["iters"], neighbour_evals=ctr["evals"])
                 return ids, sims
-            if self._graph_dirty:
-                self._refresh_graph()
-            fn = self._device_search(k, ef, beam, self._graph.max_level)
-            g = self._graph
-            ids, sims, tstats = fn(jnp.asarray(queries), g.db, g.db_popcount,
-                                   g.base_adj, g.upper_adj, g.entry_point,
-                                   g.nbr_fps, g.nbr_cnt)
+            fn, args = self.device_pipeline(queries, k, ef, beam)
+            ids, sims, tstats = fn(*args)
             iters = np.asarray(tstats.iters)
             expans = np.asarray(tstats.expansions)
             # each expanded candidate gathers and scores <= 2M neighbour

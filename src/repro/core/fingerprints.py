@@ -9,11 +9,11 @@ the TPU-native analogue of the paper's BitCnt LUT tree (DESIGN.md §2).
 Every supported metric is a rational function of the same popcount triple
 ``(a=|A|, b=|B|, c=|A∩B|)`` the kernels already compute, so metric choice
 is a trace-time parameter (:class:`Metric`): each (metric, shape) pair
-compiles once and the inner loop is unchanged. The Tanimoto dispatch
-branch reproduces the historical op sequence verbatim — int32 union, one
-f32 divide, the ``union > 0`` guard — so the default path emits
-bit-identical HLO to the pre-metric code (docs/ARCHITECTURE.md §Metric
-parameterization).
+compiles once and the inner loop is unchanged (docs/ARCHITECTURE.md
+§Metric parameterization). Tanimoto and Dice divide through
+:func:`exact_ratio`, the IEEE quotient on every backend — TPU v5e's f32
+divide is off by an ulp on some popcount pairs, which would break the
+device's bit-equality with the numpy oracle and between backends.
 """
 from __future__ import annotations
 
@@ -155,26 +155,48 @@ def resolve_metric(metric) -> Metric:
     raise TypeError(f"cannot resolve metric from {metric!r}")
 
 
+def exact_ratio(num, den):
+    """IEEE (correctly rounded) f32 quotient of int32 ``0 <= num <= den``,
+    ``0 < den < 2**16``, from ops every backend computes exactly.
+
+    An accelerator's f32 divide may land an ulp off the IEEE quotient; one
+    residual step puts it back. The residual ``q * den - num`` is exact:
+    ``q`` is split into three 8-bit pieces, each piece times a 16-bit
+    integer fits a 24-bit significand, and the partial sums are
+    representable. ``q - residual / den`` then sits far closer to
+    ``num / den`` than ``num / den`` (16-bit denominator) ever sits to a
+    rounding midpoint, so its rounding is the IEEE one."""
+    n = num.astype(jnp.float32)
+    d = den.astype(jnp.float32)
+    q = n / d
+
+    def top8(x):     # x with its significand cut to the leading 8 bits
+        bits = jax.lax.bitcast_convert_type(x, jnp.int32) & -(1 << 16)
+        return jax.lax.bitcast_convert_type(bits, jnp.float32)
+
+    q1 = top8(q)
+    q2 = top8(q - q1)
+    q3 = (q - q1) - q2
+    residual = ((q1 * d - n) + q2 * d) + q3 * d
+    return q - residual / d
+
+
 def metric_from_counts(metric: Metric, inter, q_cnt, d_cnt):
     """Trace-time metric dispatch on the popcount triple (jnp arrays).
 
     ``inter``/``q_cnt``/``d_cnt`` are int32 (broadcastable); returns f32.
-    The Tanimoto branch is the historical op sequence VERBATIM (int32
-    union, one f32 divide, ``union > 0`` guard) — every kernel body and
-    engine closure routes through here, and the default path's HLO
-    bit-identity depends on this branch never changing shape.
+    Every kernel body and engine closure routes through here. Tanimoto and
+    Dice are the IEEE quotient (:func:`exact_ratio`); cosine and Tversky
+    use the backend's f32 divide.
     """
     if metric.name == "tanimoto":
         union = q_cnt + d_cnt - inter
-        return jnp.where(union > 0,
-                         inter.astype(jnp.float32) / union.astype(jnp.float32),
+        return jnp.where(union > 0, exact_ratio(inter, jnp.maximum(union, 1)),
                          0.0)
     if metric.name == "dice":
         denom = q_cnt + d_cnt
         return jnp.where(denom > 0,
-                         (2 * inter).astype(jnp.float32)
-                         / denom.astype(jnp.float32),
-                         0.0)
+                         exact_ratio(2 * inter, jnp.maximum(denom, 1)), 0.0)
     if metric.name == "cosine":
         # sqrt of the exact integer ratio c^2/(a*b), NOT c / sqrt(a*b) or
         # c * rsqrt(a*b): XLA's algebraic simplifier rewrites
@@ -277,9 +299,8 @@ def tanimoto(a: jax.Array, b: jax.Array) -> jax.Array:
     a: (..., W) uint32, b: (..., W) uint32 -> (...,) float32 in [0, 1].
     S = |A&B| / |A|B| = c / (cnt_a + cnt_b - c).  Empty/empty pairs -> 0.
     """
-    inter = popcount(a & b)
-    union = popcount(a) + popcount(b) - inter
-    return jnp.where(union > 0, inter.astype(jnp.float32) / union.astype(jnp.float32), 0.0)
+    return metric_from_counts(TANIMOTO, popcount(a & b), popcount(a),
+                              popcount(b))
 
 
 def tanimoto_scores(query: jax.Array, db: jax.Array, db_popcount: jax.Array | None = None) -> jax.Array:
@@ -288,22 +309,13 @@ def tanimoto_scores(query: jax.Array, db: jax.Array, db_popcount: jax.Array | No
     ``db_popcount`` may be precomputed (the paper stores DB bit counts once —
     the BitCnt stage runs per query only on the query itself).
     """
-    inter = popcount(query[None, :] & db)
-    q_cnt = popcount(query)
-    d_cnt = popcount(db) if db_popcount is None else db_popcount
-    union = q_cnt + d_cnt - inter
-    return jnp.where(union > 0, inter.astype(jnp.float32) / union.astype(jnp.float32), 0.0)
+    return metric_scores(query, db, TANIMOTO, db_popcount)
 
 
 def batched_tanimoto_scores(queries: jax.Array, db: jax.Array,
                             db_popcount: jax.Array | None = None) -> jax.Array:
     """(Q, W) x (N, W) -> (Q, N) f32 score matrix (brute-force reference)."""
-    if db_popcount is None:
-        db_popcount = popcount(db)
-    q_cnt = popcount(queries)
-    inter = popcount(queries[:, None, :] & db[None, :, :])
-    union = q_cnt[:, None] + db_popcount[None, :] - inter
-    return jnp.where(union > 0, inter.astype(jnp.float32) / union.astype(jnp.float32), 0.0)
+    return batched_metric_scores(queries, db, TANIMOTO, db_popcount)
 
 
 def metric_scores(query: jax.Array, db: jax.Array, metric: Metric = TANIMOTO,
@@ -318,13 +330,7 @@ def metric_scores(query: jax.Array, db: jax.Array, metric: Metric = TANIMOTO,
 def batched_metric_scores(queries: jax.Array, db: jax.Array,
                           metric: Metric = TANIMOTO,
                           db_popcount: jax.Array | None = None) -> jax.Array:
-    """Metric-generic twin of :func:`batched_tanimoto_scores`.
-
-    The Tanimoto case delegates to the original (same jaxpr) so existing
-    default-path callers and the metric path share one trace.
-    """
-    if metric.name == "tanimoto":
-        return batched_tanimoto_scores(queries, db, db_popcount)
+    """Metric-generic twin of :func:`batched_tanimoto_scores`."""
     if db_popcount is None:
         db_popcount = popcount(db)
     q_cnt = popcount(queries)

@@ -157,12 +157,14 @@ def _select_heuristic(cand_ids: np.ndarray, cand_sims: np.ndarray, m: int,
                                      cnts[:, None], cnts[None, :])
 
     selected: list[int] = []
+    # rejected[j]: candidate j is closer to a selected neighbour than to q
+    rejected = np.zeros(len(cand), dtype=bool)
     for j in range(len(cand)):
         if len(selected) >= m:
             break
-        # e closer to an existing neighbour than to q -> rejected
-        if all(pair[j, s] <= sims[j] for s in selected):
+        if not rejected[j]:
             selected.append(j)
+            rejected |= pair[:, j] > sims
     # backfill with best remaining if heuristic selected < m (paper keeps M links)
     if len(selected) < m:
         chosen = set(selected)
@@ -572,12 +574,11 @@ def _sims(q: jax.Array, q_cnt: jax.Array, g: HNSWDeviceGraph, ids: jax.Array,
 
 def score_ids_jnp(queries: jax.Array, q_cnt: jax.Array, g: HNSWDeviceGraph,
                   ids: jax.Array, metric: Metric = TANIMOTO) -> jax.Array:
-    """Batched gather-distance fallback: (Q, W) x (Q, E) ids -> (Q, E) sims.
+    """Batched gather-distance stage: (Q, W) x (Q, E) ids -> (Q, E) sims.
 
     Plain-jnp twin of the Pallas ``kernels.ops.gather_tanimoto`` kernel —
     identical arithmetic (popcount similarity via ``metric_from_counts``,
-    -inf for id -1), used when Pallas is unavailable or the engine backend
-    is ``"jnp"``.
+    -inf for id -1), used by the ``"jnp"`` engine backend.
     """
     safe = jnp.maximum(ids, 0)
     fps = g.db[safe]                                    # (Q, E, W)

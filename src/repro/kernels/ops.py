@@ -5,6 +5,12 @@ documented in docs/ARCHITECTURE.md. All wrappers are placement-transparent:
 they launch on whatever device their operands are committed to, which is
 what lets the sharded HNSW fan-out (``HNSWEngine(shards=N)``) run one
 kernel-backed traversal per shard device with no per-device code here.
+
+The wrappers own every layout rule Mosaic imposes, so the kernels see only
+aligned blocks: queries are padded to a multiple of the query block
+(:func:`_query_block`), k and candidate widths to 128 lanes, count vectors
+become ``(1, N)`` rows, and the gather/expand kernels read fingerprints as
+128-word lines. Results are sliced back before they are returned.
 """
 from __future__ import annotations
 
@@ -14,13 +20,25 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..core import bitbound as bb
 from ..core.fingerprints import Metric, TANIMOTO, popcount
+from . import expand as ke
+from . import gather as kg
 from . import tanimoto_topk as ktk
+from .tanimoto_topk import LANES, SUBLANES, round_up
 
-# Interpret mode on CPU (this container); on TPU backends the kernels compile
-# through Mosaic.
+
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    """Kernels compile through Mosaic on TPU. On CPU — how the tests run —
+    they run in the Pallas interpreter. Any other backend is an error: a
+    device that is not a TPU must never be served by the interpreter."""
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(f"Pallas kernels need a TPU (or the CPU interpreter "
+                       f"for tests); the default JAX backend is {backend!r}")
 
 
 def _pick_tile(n: int, tile_n: int | None) -> int:
@@ -30,62 +48,53 @@ def _pick_tile(n: int, tile_n: int | None) -> int:
     return min(ktk.DEFAULT_TILE_N, max(128, 1 << (max(n - 1, 1)).bit_length() - 1))
 
 
-@functools.partial(jax.jit, static_argnames=("k", "tile_n", "metric"))
-def _tanimoto_topk_impl(queries, db, db_cnt, k: int, tile_n: int,
+def _query_block(q_n: int, qb: int = SUBLANES) -> tuple[int, int]:
+    """(rows per query block, padded Q): the whole batch when it fits in one
+    block, else ``qb`` rounded up to the 8-row sublane tile."""
+    if q_n <= qb:
+        return q_n, q_n
+    qb = round_up(qb, SUBLANES)
+    return qb, round_up(q_n, qb)
+
+
+def _pad_to(x, rows: int, cols: int | None = None, value=0):
+    pad = [(0, rows - x.shape[0])] + [(0, 0)] * (x.ndim - 1)
+    if cols is not None:
+        pad[1] = (0, cols - x.shape[1])
+    return jnp.pad(x, pad, constant_values=value)
+
+
+@functools.partial(jax.jit, static_argnames=("k", "qb", "tile_n", "metric"))
+def _tanimoto_topk_impl(queries, db, db_cnt, k: int, qb: int, tile_n: int,
                         metric: Metric = TANIMOTO):
+    q_n = queries.shape[0]
+    qb, q_pad = _query_block(q_n, qb)
     n = db.shape[0]
-    pad = (-n) % tile_n
-    db_p = jnp.pad(db, ((0, pad), (0, 0)))
-    cnt_p = jnp.pad(db_cnt, (0, pad))
-    return ktk.fused_tanimoto_topk(queries, db_p, cnt_p, k=k, n_valid=n,
-                                   tile_n=tile_n, interpret=_interpret(),
-                                   metric=metric)
+    n_pad = round_up(n, tile_n)
+    ids, vals = ktk.fused_tanimoto_topk(
+        _pad_to(queries, q_pad), _pad_to(db, n_pad),
+        _pad_to(db_cnt.astype(jnp.int32), n_pad).reshape(1, n_pad),
+        k=k, n_valid=n, qb=qb, tile_n=tile_n, interpret=_interpret(),
+        metric=metric)
+    return ids[:q_n, :k], vals[:q_n, :k]
 
 
 def tanimoto_topk(queries: jax.Array, db: jax.Array, k: int,
                   db_popcount: jax.Array | None = None,
                   tile_n: int | None = None,
-                  metric: Metric | None = None):
+                  metric: Metric | None = None, qb: int = SUBLANES):
     """Fused on-the-fly exhaustive KNN: (Q, W) x (N, W) -> ids, vals (Q, k).
 
-    ``metric`` is a trace-time constant: each (metric, shape) pair compiles
-    once; the Tanimoto default emits the historical HLO unchanged."""
+    One DB sweep serves ``qb`` queries (bytes/query divided by qb — see the
+    kernel docstring); Q is padded up to a block multiple. ``metric`` is a
+    trace-time constant: each (metric, shape) pair compiles once."""
     queries = jnp.asarray(queries)
     db = jnp.asarray(db)
     if db_popcount is None:
         db_popcount = popcount(db)
-    tile = min(_pick_tile(db.shape[0], tile_n), db.shape[0] if db.shape[0] >= 128 else 128)
-    ids, vals = _tanimoto_topk_impl(queries, db, db_popcount, k, tile,
-                                    metric if metric is not None else TANIMOTO)
-    return ids, vals
-
-
-@functools.partial(jax.jit, static_argnames=("k", "max_tiles", "tile_n", "n_valid", "cutoff", "metric"))
-def _bitbound_topk_impl(queries, db_sorted, cnt_sorted, counts_i32,
-                        k: int, max_tiles: int, tile_n: int, n_valid: int,
-                        cutoff: float, metric: Metric = TANIMOTO):
-    # per-metric popcount window per query (Tanimoto: Eq.2), in tile units
-    a = jnp.sum(jax.lax.population_count(queries).astype(jnp.int32), -1).astype(jnp.float32)
-    if metric.name == "tanimoto":
-        lo_cnt = jnp.ceil(a * cutoff).astype(jnp.int32)
-        hi_cnt = jnp.floor(a / max(cutoff, 1e-6)).astype(jnp.int32)
-    else:
-        lo_r, hi_r = metric.bound_ratios(cutoff)
-        lo_cnt = (jnp.ceil(a * lo_r) if metric.bounded_below
-                  else jnp.zeros_like(a)).astype(jnp.int32)
-        hi_cnt = (jnp.minimum(jnp.floor(a * hi_r), 2.0**30)
-                  if metric.bounded_above
-                  else jnp.full_like(a, 2.0**30)).astype(jnp.int32)
-    lo = jnp.searchsorted(counts_i32, lo_cnt, side="left").astype(jnp.int32)
-    hi = jnp.searchsorted(counts_i32, hi_cnt, side="right").astype(jnp.int32)
-    lo_tile = lo // tile_n
-    hi_tile = (hi + tile_n - 1) // tile_n
-    n_tiles_q = jnp.clip(hi_tile - lo_tile, 0, max_tiles)
-    ids_sorted, vals = ktk.bitbound_fused_topk(
-        queries, db_sorted, cnt_sorted, lo_tile, n_tiles_q, k=k,
-        max_tiles=max_tiles, n_valid=n_valid, cutoff=cutoff, tile_n=tile_n,
-        interpret=_interpret(), metric=metric)
-    return ids_sorted, vals
+    tile = min(_pick_tile(db.shape[0], tile_n), round_up(db.shape[0], LANES))
+    return _tanimoto_topk_impl(queries, db, db_popcount, k, qb, tile,
+                               metric if metric is not None else TANIMOTO)
 
 
 def bitbound_topk(queries: jax.Array, db_sorted: jax.Array,
@@ -94,36 +103,30 @@ def bitbound_topk(queries: jax.Array, db_sorted: jax.Array,
                   metric: Metric | None = None):
     """BitBound-windowed fused KNN over a popcount-sorted DB.
 
-    Returns ids into the *sorted* database (caller maps through the
-    BitBoundIndex order), and similarity values. Entries that fall outside
-    every window come back as id -1 / val -inf."""
-    queries = jnp.asarray(queries)
-    db_sorted = jnp.asarray(db_sorted)
-    counts_sorted = jnp.asarray(counts_sorted, dtype=jnp.int32)
-    n = db_sorted.shape[0]
-    tile = _pick_tile(n, tile_n)
-    pad = (-n) % tile
-    db_p = jnp.pad(db_sorted, ((0, pad), (0, 0)))
-    cnt_p = jnp.pad(counts_sorted, (0, pad))
-    total_tiles = db_p.shape[0] // tile
-    if max_tiles is None:
-        max_tiles = total_tiles
-    max_tiles = min(max_tiles, total_tiles)
-    ids_sorted, vals = _bitbound_topk_impl(
-        queries, db_p, cnt_p, counts_sorted, k, max_tiles, tile, n,
-        float(cutoff), metric if metric is not None else TANIMOTO)
-    ids_sorted = jnp.where(jnp.isfinite(vals), ids_sorted, -1)
-    return ids_sorted, vals
+    The per-query popcount window (:func:`core.bitbound.bound_range_np`, the
+    one window every engine uses) selects a contiguous row range of the
+    sorted database, which the row-window kernel scans. Returns ids into
+    the *sorted* database (caller maps through the BitBoundIndex order),
+    and similarity values. Entries that fall outside every window come back
+    as id -1 / val -inf."""
+    metric = metric if metric is not None else TANIMOTO
+    counts_np = np.asarray(counts_sorted)
+    lo, hi = bb.bound_range_np(counts_np,
+                               np.asarray(popcount(jnp.asarray(queries))),
+                               cutoff, metric=metric)
+    return window_topk(queries, db_sorted, counts_np, lo, hi, k,
+                       max_tiles=max_tiles, tile_n=tile_n, metric=metric)
 
 
 @functools.partial(jax.jit, static_argnames=("k", "max_tiles", "tile_n", "n_valid", "metric"))
 def _window_topk_impl(queries, db_p, cnt_p, lo_tile, n_tiles, lo_row, hi_row,
                       k: int, max_tiles: int, tile_n: int, n_valid: int,
                       metric: Metric = TANIMOTO):
-    return ktk.windowed_fused_topk(queries, db_p, cnt_p, lo_tile, n_tiles,
-                                   lo_row, hi_row, k=k, max_tiles=max_tiles,
-                                   n_valid=n_valid, tile_n=tile_n,
-                                   interpret=_interpret(), metric=metric)
+    ids, vals = ktk.windowed_fused_topk(
+        queries, db_p, cnt_p.reshape(1, -1), lo_tile, n_tiles, lo_row, hi_row,
+        k=k, max_tiles=max_tiles, n_valid=n_valid, tile_n=tile_n,
+        interpret=_interpret(), metric=metric)
+    return ids[:, :k], vals[:, :k]
 
 
 def window_topk(queries: jax.Array, db: jax.Array, db_cnt: jax.Array,
@@ -174,84 +177,84 @@ def bitcount(words: jax.Array) -> jax.Array:
     return ktk.bitcount(jnp.asarray(words), interpret=_interpret())
 
 
+def _line_layout(queries, q_cnt, q_pad: int):
+    """Queries as 128-word lines (padded to the line width and repeated
+    across the line) plus (Q_pad, 1) counts — the gather/expand kernels'
+    query operands."""
+    q_n, w = queries.shape
+    wp = kg.line_width(w)
+    q_lines = jnp.tile(_pad_to(queries, q_n, wp), (1, LANES // wp))
+    return (wp, _pad_to(q_lines, q_pad),
+            _pad_to(q_cnt.astype(jnp.int32), q_pad).reshape(q_pad, 1))
+
+
 def gather_tanimoto(queries: jax.Array, db: jax.Array, ids: jax.Array,
                     q_cnt: jax.Array | None = None,
                     metric: Metric | None = None) -> jax.Array:
     """Fine-grained gather-distance stage: per-query candidate ids -> sims.
 
-    queries (Q, W) u32, db (N, W) u32, ids (Q, E) i32 -> (Q, E) f32.
-    Entries with id ``-1`` come back as ``-inf``. Jit-compatible — the HNSW
-    traversal calls this from inside its ``lax.while_loop``, scoring one
-    whole beam expansion (B·2M neighbour ids) per kernel launch.
+    queries (Q, W) u32 (W <= 128 words), db (N, W) u32, ids (Q, E) i32 ->
+    (Q, E) f32. Entries with id ``-1`` come back as ``-inf``; ids past the
+    end score the last row. Jit-compatible — the HNSW traversal calls this
+    from inside its ``lax.while_loop``, scoring one whole beam expansion
+    (B·2M neighbour ids) per kernel launch.
     """
-    from . import gather as kg
     queries = jnp.asarray(queries)
     db = jnp.asarray(db)
     ids = jnp.asarray(ids, dtype=jnp.int32)
     if q_cnt is None:
         q_cnt = popcount(queries)
-    return kg.gather_tanimoto_scores(
-        queries, q_cnt, db, ids, interpret=_interpret(),
+    q_n, e_n = ids.shape
+    n, w = db.shape
+    qb, q_pad = _query_block(q_n)
+    wp, q_lines, qc = _line_layout(queries, q_cnt, q_pad)
+    per_line = LANES // wp
+    db_lines = _pad_to(db, round_up(n, per_line), wp).reshape(-1, LANES)
+    ids = jnp.where(ids >= 0, jnp.minimum(ids, n - 1), -1)
+    ids = _pad_to(ids, q_pad, round_up(e_n, LANES), value=-1)
+    s = kg.gather_tanimoto_scores(
+        q_lines, qc, db_lines, ids, wp=wp, qb=qb, interpret=_interpret(),
         metric=metric if metric is not None else TANIMOTO)
+    return s[:q_n, :e_n]
 
 
 def expand_tanimoto_sorted(queries: jax.Array, nbr_fps: jax.Array,
-                           nbr_cnt: jax.Array, pop_ids: jax.Array,
-                           flat_ids: jax.Array, worst: jax.Array, kk: int,
+                           pop_ids: jax.Array, flat_ids: jax.Array,
+                           worst: jax.Array, kk: int,
                            q_cnt: jax.Array | None = None,
                            metric: Metric | None = None):
     """Fused beam-expansion stage over the neighbour-blocked layout.
 
-    queries (Q, W) u32, nbr_fps (N, 2M, W) u32, nbr_cnt (N, 2M) i32,
-    pop_ids (Q, beam) i32, flat_ids (Q, beam*2M) i32, worst (Q,) f32 ->
-    (scores (Q, kk) desc, ids (Q, kk)). One contiguous block DMA per popped
-    node (``beam`` streams per query-iteration vs the row kernel's
-    ``beam*2M`` fetches), scores sorted in-kernel so the traversal merges a
-    single run. Jit-compatible — the HNSW ``lax.while_loop`` launches it
-    once per iteration.
+    queries (Q, W) u32 (W <= 128 words), nbr_fps (N, 2M, W) u32 (zero rows
+    for -1 slots), pop_ids (Q, beam) i32, flat_ids (Q, beam*2M) i32, worst
+    (Q,) f32 -> (scores (Q, kk) desc, ids (Q, kk)). One contiguous block DMA
+    per popped node (``beam`` streams per query-iteration vs the row
+    kernel's ``beam*2M`` fetches), scores sorted in-kernel so the traversal
+    merges a single run. Neighbour popcounts are recounted from ``nbr_fps``.
+    Jit-compatible — the HNSW ``lax.while_loop`` launches it once per
+    iteration.
     """
-    from . import expand as ke
     queries = jnp.asarray(queries)
+    nbr_fps = jnp.asarray(nbr_fps)
     if q_cnt is None:
         q_cnt = popcount(queries)
-    return ke.expand_sorted_scores(
-        queries, q_cnt, jnp.asarray(nbr_fps), jnp.asarray(nbr_cnt),
-        jnp.asarray(pop_ids, dtype=jnp.int32),
-        jnp.asarray(flat_ids, dtype=jnp.int32),
-        jnp.asarray(worst), kk, interpret=_interpret(),
+    q_n = queries.shape[0]
+    n, m2, _ = nbr_fps.shape
+    beam = pop_ids.shape[1]
+    qb, q_pad = _query_block(q_n)
+    wp, q_lines, qc = _line_layout(queries, q_cnt, q_pad)
+    per_line = LANES // wp
+    m2p = round_up(m2, SUBLANES * per_line)            # slab rows % 8 == 0
+    nbr_lines = jnp.pad(nbr_fps, ((0, 0), (0, m2p - m2), (0, wp - nbr_fps.shape[2]))
+                        ).reshape(n, m2p // per_line, LANES)
+    slots = jnp.pad(jnp.asarray(flat_ids, jnp.int32).reshape(q_n, beam, m2),
+                    ((0, 0), (0, 0), (0, m2p - m2)), constant_values=-1)
+    slots = _pad_to(slots.reshape(q_n, beam * m2p), q_pad,
+                    round_up(beam * m2p, LANES), value=-1)
+    pops = _pad_to(jnp.asarray(pop_ids, jnp.int32), q_pad, value=-1)
+    worst = _pad_to(jnp.asarray(worst, jnp.float32), q_pad).reshape(q_pad, 1)
+    s, i = ke.expand_sorted_scores(
+        q_lines, qc, nbr_lines, pops, slots, worst, kk, wp=wp, qb=qb,
+        interpret=_interpret(),
         metric=metric if metric is not None else TANIMOTO)
-
-
-@functools.partial(jax.jit, static_argnames=("k", "qb", "tile_n", "metric"))
-def _blocked_topk_impl(queries, db, db_cnt, k: int, qb: int, tile_n: int,
-                       metric: Metric = TANIMOTO):
-    n = db.shape[0]
-    pad = (-n) % tile_n
-    db_p = jnp.pad(db, ((0, pad), (0, 0)))
-    cnt_p = jnp.pad(db_cnt, (0, pad))
-    return ktk.blocked_tanimoto_topk(queries, db_p, cnt_p, k=k, n_valid=n,
-                                     qb=qb, tile_n=tile_n,
-                                     interpret=_interpret(), metric=metric)
-
-
-def tanimoto_topk_blocked(queries: jax.Array, db: jax.Array, k: int,
-                          db_popcount: jax.Array | None = None, qb: int = 8,
-                          tile_n: int | None = None,
-                          metric: Metric | None = None):
-    """Query-blocked fused engine: one DB sweep serves qb queries
-    (bytes/query divided by qb — see kernel docstring). Pads Q up to a qb
-    multiple."""
-    queries = jnp.asarray(queries)
-    db = jnp.asarray(db)
-    if db_popcount is None:
-        db_popcount = popcount(db)
-    qn = queries.shape[0]
-    qpad = (-qn) % qb
-    if qpad:
-        queries = jnp.concatenate(
-            [queries, jnp.zeros((qpad, queries.shape[1]), queries.dtype)])
-    tile = min(_pick_tile(db.shape[0], tile_n),
-               db.shape[0] if db.shape[0] >= 128 else 128)
-    ids, vals = _blocked_topk_impl(queries, db, db_popcount, k, qb, tile,
-                                   metric if metric is not None else TANIMOTO)
-    return ids[:qn], vals[:qn]
+    return s[:q_n, :kk], i[:q_n, :kk]

@@ -3,7 +3,9 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
+from ..core import bitbound as bb
 from ..core.fingerprints import Metric, TANIMOTO, metric_from_counts, popcount
 
 
@@ -33,19 +35,14 @@ def bitbound_topk_ref(queries: jax.Array, db_sorted: jax.Array,
                       counts_sorted: jax.Array, k: int, cutoff: float,
                       metric: Metric = TANIMOTO):
     """Oracle for the BitBound-pruned kernel: scores outside the metric's
-    popcount window (Tanimoto: Eq.2) are treated as -inf (never returned)."""
+    popcount window (``core.bitbound.bound_counts_np``; Tanimoto: Eq.2) are
+    treated as -inf (never returned)."""
     scores = tanimoto_scores_ref(queries, db_sorted, counts_sorted,
                                  metric=metric)
-    a = popcount(queries).astype(jnp.float32)
-    if metric.name == "tanimoto":
-        lo = jnp.ceil(a * cutoff)[:, None]
-        hi = jnp.floor(a / max(cutoff, 1e-6))[:, None]
-    else:
-        lo_r, hi_r = metric.bound_ratios(cutoff)
-        lo = (jnp.ceil(a * lo_r) if metric.bounded_below
-              else jnp.zeros_like(a))[:, None]
-        hi = (jnp.floor(a * hi_r) if metric.bounded_above
-              else jnp.full_like(a, 2.0**30))[:, None]
+    lo, hi = bb.bound_counts_np(np.asarray(popcount(queries)), cutoff,
+                                metric=metric)
+    lo = jnp.asarray(lo, jnp.float32)[:, None]
+    hi = jnp.asarray(np.minimum(hi, 2.0**30), jnp.float32)[:, None]
     c = counts_sorted[None, :].astype(jnp.float32)
     in_range = jnp.logical_and(c >= lo, c <= hi)
     scores = jnp.where(in_range, scores, -jnp.inf)

@@ -1,4 +1,4 @@
-"""Fused Tanimoto-scan + streaming top-k Pallas kernel — the "on-the-fly"
+"""Fused Tanimoto-scan + streaming top-k Pallas kernels — the "on-the-fly"
 query engine of the paper, TPU-native (DESIGN.md §2).
 
 Design (FPGA -> TPU mapping):
@@ -9,26 +9,35 @@ Design (FPGA -> TPU mapping):
 * Here the database is streamed HBM->VMEM in ``(TILE_N, W)`` BlockSpec tiles;
   each grid step computes the tile's scores in vector registers and merges
   them into a **persistent VMEM top-k scratch** — the scores never get
-  written back to HBM. Only the final (k,) result leaves the chip, so HBM
-  traffic is exactly one read of the database: the kernel is at the
-  streaming-bandwidth roofline by construction (measured in EXPERIMENTS.md).
-* The top-k merge uses a sort-based combine (``lax.top_k`` over the
-  ``k + TILE_N`` candidate window), the constant-shape analogue of the
-  paper's merge-sort unit; resource use scales O(k + TILE_N) like the
-  paper's O(log k) comparator tree scales with stream width.
-* The BitBound variant adds scalar-prefetched per-query tile windows
-  ``(lo_tile, n_tiles)``: the grid is sized for the *worst-case* Eq.2 window
-  and the ``index_map`` offsets DB tile fetches by ``lo_tile[q]`` — the TPU
+  written back to HBM. Only the final top-k rows leave the chip, so HBM
+  traffic is one read of the database per query block.
+* Scoring layout: the tile is transposed in VMEM to ``(W, TILE_N)`` so each
+  query's intersection popcounts reduce over sublanes into one lane-dense
+  ``(1, TILE_N)`` row (:func:`tile_inter`).
+* The top-k merge (:func:`merge_topk`) is the constant-shape analogue of
+  the paper's merge-sort unit, built only from ops Mosaic lowers (``max``,
+  ``min``, selects, a one-lane roll): candidates that beat the running k-th
+  score are pulled out best-first (lowest lane on ties) and each is
+  shift-inserted into the sorted running row. A tile with no such candidate
+  costs one compare. The result is exactly ``lax.top_k`` over
+  ``concat(running, tile)``: score descending, ties to the lower id.
+* Running top-k rows are padded to 128 lanes (``round_up(k, LANES)``) and
+  sliced outside the kernel; count vectors are ``(1, N)`` lane-dense rows.
+* The windowed variant adds scalar-prefetched per-query tile windows
+  ``(lo_tile, n_tiles)``: the grid is sized for the *worst-case* window and
+  the ``index_map`` offsets DB tile fetches by ``lo_tile[q]`` — the TPU
   analogue of the FPGA engine fetching only the popcount-bounded address
-  range from HBM. Tiles beyond the query's window are masked via ``pl.when``
-  (fetch suppressed by clamping the index map to a single repeated tile).
+  range from HBM. Tiles beyond the query's window are skipped via
+  ``pl.when`` (fetch suppressed by clamping the index map to a single
+  repeated tile). BitBound (Eq. 2) search is this kernel over the row range
+  that the popcount window selects in the popcount-sorted database.
 
-VMEM budget (v5e ~16 MiB/core): tile (TILE_N=2048, W=32) uint32 = 256 KiB,
-plus (k + TILE_N) merge window and (1, k) scratch — comfortably resident
-with double-buffering of the DB stream.
+VMEM budget (v5e: 16 MiB scoped by default): tile (TILE_N=2048, W=32) uint32
+= 256 KiB double-buffered, its transpose, and ``(rows, k_pad + TILE_N)``
+merge temporaries.
 
-Validated with ``interpret=True`` on CPU against ``ref.py``; ``lax.top_k``
-and ``population_count`` lower on TPU Mosaic (top_k via sort).
+Validated with ``interpret=True`` on CPU against ``ref.py``, and compiled
+for v5e with ``interpret=False`` by ``tests/test_chip_compile.py``.
 """
 from __future__ import annotations
 
@@ -42,212 +51,231 @@ from jax.experimental.pallas import tpu as pltpu
 from ..core.fingerprints import Metric, TANIMOTO, metric_from_counts
 
 DEFAULT_TILE_N = 2048
+LANES = 128
+SUBLANES = 8
 NEG = float("-inf")  # python scalar: must not be a captured jnp constant
 
 
+def round_up(x: int, m: int) -> int:
+    return -(-int(x) // m) * m
+
+
 # ---------------------------------------------------------------------------
-# full-scan fused kernel (brute force / folded scan)
+# in-kernel building blocks (shared with gather.py / expand.py)
 # ---------------------------------------------------------------------------
 
-def _fused_body(q_ref, qcnt_ref, db_ref, dbcnt_ref, ids_ref, vals_ref,
-                top_s, top_i, *, k: int, tile_n: int, n_tiles: int, n_valid: int,
+def _iota(shape, axis):
+    return jax.lax.broadcasted_iota(jnp.int32, shape, axis)
+
+
+def popcount_rows(x):
+    """(R, W) uint32 -> (R, 1) int32 row popcounts."""
+    return jnp.sum(jax.lax.population_count(x).astype(jnp.int32), axis=1,
+                   keepdims=True)
+
+
+def _row_to_col(x):
+    """(1, W) uint32 row -> (W, 1) column, via a diagonal select and a lane
+    sum (exact: one nonzero term per output)."""
+    w = x.shape[1]
+    xi = jax.lax.bitcast_convert_type(x, jnp.int32)
+    eye = _iota((w, w), 0) == _iota((w, w), 1)
+    col = jnp.sum(jnp.where(eye, jnp.broadcast_to(xi, (w, w)), 0), axis=1,
+                  keepdims=True)
+    return jax.lax.bitcast_convert_type(col, jnp.uint32)
+
+
+def tile_inter(q_rows, db_tile):
+    """Intersection popcounts of ``q_rows (R, W)`` against ``db_tile (T, W)``
+    -> ``(R, T)`` int32, lane-dense: the tile is transposed once and each
+    query's ANDs reduce over sublanes."""
+    r = q_rows.shape[0]
+    t = db_tile.shape[0]
+    db_t = db_tile.T                                   # (W, T)
+    inter = None
+    sub = _iota((r, t), 0)
+    for j in range(r):
+        qcol = _row_to_col(q_rows[j:j + 1, :])         # (W, 1)
+        row = jnp.sum(jax.lax.population_count(db_t & qcol).astype(jnp.int32),
+                      axis=0, keepdims=True)           # (1, T)
+        inter = row if inter is None else jnp.where(sub == j, row, inter)
+    return inter
+
+
+def init_topk(top_s, top_i):
+    top_s[...] = jnp.full(top_s.shape, NEG, jnp.float32)
+    top_i[...] = jnp.full(top_i.shape, -1, jnp.int32)
+
+
+def merge_topk(top_s, top_i, s, ids, k: int):
+    """Fold candidates ``s (R, T)`` with ids ``ids (1 or R, T)`` into the
+    running top-k held in lanes ``[0, k)`` of the ``(R, k_pad)`` refs.
+
+    Every candidate id must exceed every id already in the run and ids must
+    ascend along lanes (true for a tile scan in ascending row order): the
+    result is then exactly ``lax.top_k(concat(run, s), k)`` — scores
+    descending, ties to the lower id — with ``-inf`` slots carrying id -1.
+    Only candidates strictly above the running k-th score can enter; at
+    most ``k`` of them are shift-inserted, best first."""
+    rows, kp = top_s.shape
+    t = s.shape[1]
+    lane_k = _iota((rows, kp), 1)
+    in_k = lane_k < k
+    run_s = top_s[...]
+    kth = jnp.min(jnp.where(in_k, run_s, jnp.inf), axis=1, keepdims=True)
+    cand = jnp.where(s > kth, s, NEG)
+    n_new = jnp.max(jnp.sum((cand > NEG).astype(jnp.int32), axis=1,
+                            keepdims=True))
+
+    @pl.when(n_new > 0)
+    def _merge():
+        lane_t = _iota((rows, t), 1)
+        ids_b = jnp.broadcast_to(ids, (rows, t))
+
+        def insert_best(_, carry):
+            cand, run_s, run_i = carry
+            m = jnp.max(cand, axis=1, keepdims=True)
+            pos = jnp.min(jnp.where(cand == m, lane_t, t), axis=1,
+                          keepdims=True)
+            hit = lane_t == pos
+            mid = jnp.sum(jnp.where(hit, ids_b, 0), axis=1, keepdims=True)
+            cand = jnp.where(hit, NEG, cand)
+            kth = jnp.min(jnp.where(in_k, run_s, jnp.inf), axis=1,
+                          keepdims=True)
+            # insertion lane: after every running score >= m (lower ids)
+            at = jnp.sum(jnp.where(in_k & (run_s >= m), 1, 0), axis=1,
+                         keepdims=True)
+            new_s = jnp.where(lane_k < at, run_s,
+                              jnp.where(lane_k == at, m,
+                                        pltpu.roll(run_s, 1, 1)))
+            new_i = jnp.where(lane_k < at, run_i,
+                              jnp.where(lane_k == at, mid,
+                                        pltpu.roll(run_i, 1, 1)))
+            ins = m > kth
+            run_s = jnp.where(ins & in_k, new_s, run_s)
+            run_i = jnp.where(ins & in_k, new_i, run_i)
+            return cand, run_s, run_i
+
+        _, run_s, run_i = jax.lax.fori_loop(
+            0, jnp.minimum(n_new, k), insert_best,
+            (cand, top_s[...], top_i[...]))
+        top_s[...] = run_s
+        top_i[...] = run_i
+
+
+def select_topk(s, ids, k: int, kp: int):
+    """Top-k of each row of ``s (R, E)`` -> ``(R, kp)`` scores and ids,
+    exactly ``lax.top_k`` order (descending, ties to the lower lane);
+    ``-inf`` slots carry id -1, lanes ``>= k`` are ``-inf`` / -1."""
+    rows, e = s.shape
+    lane = _iota((rows, e), 1)
+    lane_k = _iota((rows, kp), 1)
+
+    def pick(r, carry):
+        s, out_s, out_i = carry
+        m = jnp.max(s, axis=1, keepdims=True)
+        pos = jnp.min(jnp.where(s == m, lane, e), axis=1, keepdims=True)
+        hit = lane == pos
+        mid = jnp.sum(jnp.where(hit, ids, 0), axis=1, keepdims=True)
+        mid = jnp.where(m > NEG, mid, -1)
+        s = jnp.where(hit, NEG, s)
+        out_s = jnp.where(lane_k == r, m, out_s)
+        out_i = jnp.where(lane_k == r, mid, out_i)
+        return s, out_s, out_i
+
+    _, out_s, out_i = jax.lax.fori_loop(
+        0, k, pick, (s, jnp.full((rows, kp), NEG, jnp.float32),
+                     jnp.full((rows, kp), -1, jnp.int32)))
+    return out_s, out_i
+
+
+# ---------------------------------------------------------------------------
+# full-scan fused kernel (brute force), qb queries per DB sweep
+# ---------------------------------------------------------------------------
+#
+# The paper's engine streams the database once PER QUERY: bytes/query =
+# N * 128 B. Blocking qb queries into one sweep streams the DB once per
+# BLOCK (bytes/query /= qb) while the per-tile compute grows only by the
+# popcount ops. The FPGA analogue would be replicating the TFC+top-k
+# pipeline behind one HBM channel.
+
+def _fused_body(q_ref, db_ref, dbcnt_ref, ids_ref, vals_ref, top_s, top_i,
+                *, k: int, tile_n: int, n_tiles: int, n_valid: int,
                 metric: Metric = TANIMOTO):
     t = pl.program_id(1)
 
     @pl.when(t == 0)
     def _init():
-        top_s[...] = jnp.full((1, k), NEG, jnp.float32)
-        top_i[...] = jnp.full((1, k), -1, jnp.int32)
+        init_topk(top_s, top_i)
 
-    q = q_ref[0, :]                                    # (W,) uint32
-    db = db_ref[...]                                   # (tile_n, W) uint32
-    # TFC stage: popcount(AND) and precomputed db counts (BitCnt runs on the
-    # query only, as in the paper); the metric maps the (a, b, c) triple to a
-    # score at trace time — Tanimoto emits the exact historical op sequence.
-    inter = jnp.sum(jax.lax.population_count(q[None, :] & db).astype(jnp.int32),
-                    axis=-1)                           # (tile_n,)
-    s = metric_from_counts(metric, inter, qcnt_ref[0], dbcnt_ref[...])
-    idx = t * tile_n + jax.lax.iota(jnp.int32, tile_n)
+    q = q_ref[...]                                     # (qb, W) uint32
+    inter = tile_inter(q, db_ref[...])                 # (qb, tile_n)
+    # TFC stage: the metric maps the (a, b, c) triple to a score at trace
+    # time.
+    s = metric_from_counts(metric, inter, popcount_rows(q), dbcnt_ref[...])
+    idx = t * tile_n + _iota((1, tile_n), 1)
     s = jnp.where(idx < n_valid, s, NEG)               # mask padded tail rows
-    # top-K merge stage: sort-based combine with the persistent scratch
-    all_s = jnp.concatenate([top_s[0, :], s])
-    all_i = jnp.concatenate([top_i[0, :], idx])
-    new_s, pos = jax.lax.top_k(all_s, k)
-    top_s[0, :] = new_s
-    top_i[0, :] = all_i[pos]
+    merge_topk(top_s, top_i, s, idx, k)
 
     @pl.when(t == n_tiles - 1)
     def _emit():
-        vals_ref[0, :] = top_s[0, :]
-        ids_ref[0, :] = top_i[0, :]
+        vals_ref[...] = top_s[...]
+        ids_ref[...] = top_i[...]
 
 
 def fused_tanimoto_topk(queries: jax.Array, db: jax.Array, db_cnt: jax.Array,
-                        k: int, n_valid: int, tile_n: int = DEFAULT_TILE_N,
-                        interpret: bool = True, metric: Metric = TANIMOTO):
-    """queries (Q, W) u32, db (N_pad, W) u32, db_cnt (N_pad,) i32 (padded to a
-    tile multiple; ``db_cnt`` may be any value in the pad — masking is by row
-    index vs ``n_valid``). Returns ids (Q, k) i32, vals (Q, k) f32."""
+                        k: int, n_valid: int, qb: int,
+                        tile_n: int = DEFAULT_TILE_N, interpret: bool = False,
+                        metric: Metric = TANIMOTO):
+    """queries (Q, W) u32 with Q a multiple of ``qb`` (``qb`` a multiple of
+    8, or ``qb == Q``), db (N_pad, W) u32 and db_cnt (1, N_pad) i32 padded
+    to a tile multiple (``db_cnt`` may hold anything in the pad — masking is
+    by row index vs ``n_valid``). Returns ids (Q, k_pad) i32 and vals
+    (Q, k_pad) f32, ``k_pad = round_up(k, 128)``; lanes >= k are -1 / -inf."""
     q_n, w = queries.shape
     n_pad = db.shape[0]
-    assert n_pad % tile_n == 0, (n_pad, tile_n)
+    assert n_pad % tile_n == 0 and q_n % qb == 0, (n_pad, tile_n, q_n, qb)
     n_tiles = n_pad // tile_n
-    q_cnt = jnp.sum(jax.lax.population_count(queries).astype(jnp.int32), axis=-1)
-
+    kp = round_up(k, LANES)
     body = functools.partial(_fused_body, k=k, tile_n=tile_n, n_tiles=n_tiles,
                              n_valid=n_valid, metric=metric)
     out = pl.pallas_call(
         body,
-        grid=(q_n, n_tiles),
+        grid=(q_n // qb, n_tiles),
         in_specs=[
-            pl.BlockSpec((1, w), lambda q, t: (q, 0)),          # query row
-            pl.BlockSpec((1,), lambda q, t: (q,)),              # query popcount
+            pl.BlockSpec((qb, w), lambda q, t: (q, 0)),         # query block
             pl.BlockSpec((tile_n, w), lambda q, t: (t, 0)),     # DB tile stream
-            pl.BlockSpec((tile_n,), lambda q, t: (t,)),         # DB popcounts
+            pl.BlockSpec((1, tile_n), lambda q, t: (0, t)),     # DB popcounts
         ],
         out_specs=[
-            pl.BlockSpec((1, k), lambda q, t: (q, 0)),
-            pl.BlockSpec((1, k), lambda q, t: (q, 0)),
+            pl.BlockSpec((qb, kp), lambda q, t: (q, 0)),
+            pl.BlockSpec((qb, kp), lambda q, t: (q, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((q_n, k), jnp.int32),
-            jax.ShapeDtypeStruct((q_n, k), jnp.float32),
+            jax.ShapeDtypeStruct((q_n, kp), jnp.int32),
+            jax.ShapeDtypeStruct((q_n, kp), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((1, k), jnp.float32),
-            pltpu.VMEM((1, k), jnp.int32),
+            pltpu.VMEM((qb, kp), jnp.float32),
+            pltpu.VMEM((qb, kp), jnp.int32),
         ],
         interpret=interpret,
-    )(queries, q_cnt, db, db_cnt)
+    )(queries, db, db_cnt)
     return out[0], out[1]
 
 
 # ---------------------------------------------------------------------------
-# BitBound-windowed fused kernel (scalar-prefetched Eq.2 range)
-# ---------------------------------------------------------------------------
-
-def _bitbound_body(lo_ref, nt_ref, q_ref, qcnt_ref, db_ref, dbcnt_ref,
-                   ids_ref, vals_ref, top_s, top_i,
-                   *, k: int, tile_n: int, max_tiles: int, n_valid: int,
-                   cutoff: float, metric: Metric = TANIMOTO):
-    qi = pl.program_id(0)
-    t = pl.program_id(1)
-
-    @pl.when(t == 0)
-    def _init():
-        top_s[...] = jnp.full((1, k), NEG, jnp.float32)
-        top_i[...] = jnp.full((1, k), -1, jnp.int32)
-
-    active = t < nt_ref[qi]
-
-    @pl.when(active)
-    def _scan():
-        q = q_ref[0, :]
-        db = db_ref[...]
-        inter = jnp.sum(jax.lax.population_count(q[None, :] & db).astype(jnp.int32),
-                        axis=-1)
-        s = metric_from_counts(metric, inter, qcnt_ref[0], dbcnt_ref[...])
-        idx = (lo_ref[qi] + t) * tile_n + jax.lax.iota(jnp.int32, tile_n)
-        s = jnp.where(idx < n_valid, s, NEG)
-        # strict bound mask: tile-aligned windows over-fetch boundary rows;
-        # rows whose popcount is outside the metric's window (Tanimoto:
-        # Eq.2 [a*Sc, a/Sc]) are never candidates. ``bound_ratios`` is a
-        # trace-time constant, so non-Tanimoto metrics cost the same mask.
-        a = qcnt_ref[0].astype(jnp.float32)
-        if metric.name == "tanimoto":
-            lo_cnt = jnp.ceil(a * cutoff)
-            hi_cnt = jnp.floor(a / max(cutoff, 1e-6))
-        else:
-            lo_r, hi_r = metric.bound_ratios(cutoff)
-            lo_cnt = jnp.ceil(a * lo_r) if metric.bounded_below else jnp.float32(0.0)
-            hi_cnt = (jnp.floor(a * hi_r) if metric.bounded_above
-                      else jnp.float32(2.0**30))
-        c = dbcnt_ref[...].astype(jnp.float32)
-        s = jnp.where(jnp.logical_and(c >= lo_cnt, c <= hi_cnt), s, NEG)
-        all_s = jnp.concatenate([top_s[0, :], s])
-        all_i = jnp.concatenate([top_i[0, :], idx])
-        new_s, pos = jax.lax.top_k(all_s, k)
-        top_s[0, :] = new_s
-        top_i[0, :] = all_i[pos]
-
-    @pl.when(t == max_tiles - 1)
-    def _emit():
-        vals_ref[0, :] = top_s[0, :]
-        ids_ref[0, :] = top_i[0, :]
-
-
-def bitbound_fused_topk(queries: jax.Array, db_sorted: jax.Array,
-                        dbcnt_sorted: jax.Array, lo_tile: jax.Array,
-                        n_tiles_q: jax.Array, k: int, max_tiles: int,
-                        n_valid: int, cutoff: float,
-                        tile_n: int = DEFAULT_TILE_N,
-                        interpret: bool = True, metric: Metric = TANIMOTO):
-    """Scan only each query's Eq.2 tile window of the popcount-sorted DB.
-
-    lo_tile, n_tiles_q: (Q,) int32 scalar-prefetched window per query.
-    ``max_tiles`` is the static worst-case window (from the Gaussian model or
-    simply the full DB). Returned ids index into the *sorted* DB."""
-    q_n, w = queries.shape
-    n_pad = db_sorted.shape[0]
-    total_tiles = n_pad // tile_n
-    q_cnt = jnp.sum(jax.lax.population_count(queries).astype(jnp.int32), axis=-1)
-
-    def db_index(q, t, lo_ref, nt_ref):
-        # clamp: inactive tiles re-fetch the window's first tile (cheap, masked)
-        blk = jnp.where(t < nt_ref[q], lo_ref[q] + t, lo_ref[q])
-        return (jnp.minimum(blk, total_tiles - 1), 0)
-
-    def cnt_index(q, t, lo_ref, nt_ref):
-        blk = jnp.where(t < nt_ref[q], lo_ref[q] + t, lo_ref[q])
-        return (jnp.minimum(blk, total_tiles - 1),)
-
-    body = functools.partial(_bitbound_body, k=k, tile_n=tile_n,
-                             max_tiles=max_tiles, n_valid=n_valid,
-                             cutoff=cutoff, metric=metric)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(q_n, max_tiles),
-        in_specs=[
-            pl.BlockSpec((1, w), lambda q, t, lo, nt: (q, 0)),
-            pl.BlockSpec((1,), lambda q, t, lo, nt: (q,)),
-            pl.BlockSpec((tile_n, w), db_index),
-            pl.BlockSpec((tile_n,), cnt_index),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, k), lambda q, t, lo, nt: (q, 0)),
-            pl.BlockSpec((1, k), lambda q, t, lo, nt: (q, 0)),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((1, k), jnp.float32),
-            pltpu.VMEM((1, k), jnp.int32),
-        ],
-    )
-    out = pl.pallas_call(
-        body,
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((q_n, k), jnp.int32),
-            jax.ShapeDtypeStruct((q_n, k), jnp.float32),
-        ],
-        interpret=interpret,
-    )(lo_tile.astype(jnp.int32), n_tiles_q.astype(jnp.int32),
-      queries, q_cnt, db_sorted, dbcnt_sorted)
-    return out[0], out[1]
-
-
-# ---------------------------------------------------------------------------
-# row-window fused kernel (stage 1 of the device-resident two-stage engine)
+# row-window fused kernel (BitBound windows; stage 1 of the two-stage engine)
 # ---------------------------------------------------------------------------
 #
-# Same scalar-prefetched tile streaming as the BitBound kernel above, but the
-# valid region is an explicit per-query row interval [lo_row, hi_row) instead
-# of a popcount-vs-cutoff predicate. That is what the folded stage-1 scan
-# needs: the Eq.2 window is defined on *full-resolution* popcounts (the sort
-# key of the database), while the streamed tiles hold the *folded* prints —
-# the folded popcounts say nothing about window membership. Because the DB is
-# popcount-sorted, the row interval IS the Eq.2 set, exactly.
+# Scalar-prefetched tile streaming over an explicit per-query row interval
+# [lo_row, hi_row). On a popcount-sorted database the Eq.2 popcount window
+# IS such an interval, and it is what the folded stage-1 scan needs: the
+# window is defined on *full-resolution* popcounts (the sort key of the
+# database), while the streamed tiles may hold the *folded* prints. Queries
+# have different windows, so each grid step serves one query.
 
-def _window_body(lo_t_ref, nt_ref, lo_ref, hi_ref, q_ref, qcnt_ref, db_ref,
+def _window_body(lo_t_ref, nt_ref, lo_ref, hi_ref, q_ref, db_ref,
                  dbcnt_ref, ids_ref, vals_ref, top_s, top_i,
                  *, k: int, tile_n: int, max_tiles: int, n_valid: int,
                  metric: Metric = TANIMOTO):
@@ -256,50 +284,43 @@ def _window_body(lo_t_ref, nt_ref, lo_ref, hi_ref, q_ref, qcnt_ref, db_ref,
 
     @pl.when(t == 0)
     def _init():
-        top_s[...] = jnp.full((1, k), NEG, jnp.float32)
-        top_i[...] = jnp.full((1, k), -1, jnp.int32)
+        init_topk(top_s, top_i)
 
-    active = t < nt_ref[qi]
-
-    @pl.when(active)
+    @pl.when(t < nt_ref[qi])
     def _scan():
-        q = q_ref[0, :]
-        db = db_ref[...]
-        inter = jnp.sum(jax.lax.population_count(q[None, :] & db).astype(jnp.int32),
-                        axis=-1)
-        s = metric_from_counts(metric, inter, qcnt_ref[0], dbcnt_ref[...])
-        idx = (lo_t_ref[qi] + t) * tile_n + jax.lax.iota(jnp.int32, tile_n)
-        in_window = jnp.logical_and(idx >= lo_ref[qi], idx < hi_ref[qi])
-        s = jnp.where(jnp.logical_and(in_window, idx < n_valid), s, NEG)
-        all_s = jnp.concatenate([top_s[0, :], s])
-        all_i = jnp.concatenate([top_i[0, :], idx])
-        new_s, pos = jax.lax.top_k(all_s, k)
-        top_s[0, :] = new_s
-        top_i[0, :] = all_i[pos]
+        q = q_ref[...]                                 # (1, W)
+        inter = tile_inter(q, db_ref[...])             # (1, tile_n)
+        s = metric_from_counts(metric, inter, popcount_rows(q),
+                               dbcnt_ref[...])
+        idx = (lo_t_ref[qi] + t) * tile_n + _iota((1, tile_n), 1)
+        ok = (idx >= lo_ref[qi]) & (idx < hi_ref[qi]) & (idx < n_valid)
+        merge_topk(top_s, top_i, jnp.where(ok, s, NEG), idx, k)
 
     @pl.when(t == max_tiles - 1)
     def _emit():
-        vals_ref[0, :] = top_s[0, :]
-        ids_ref[0, :] = top_i[0, :]
+        vals_ref[...] = top_s[...]
+        ids_ref[...] = top_i[...]
 
 
 def windowed_fused_topk(queries: jax.Array, db: jax.Array, db_cnt: jax.Array,
                         lo_tile: jax.Array, n_tiles_q: jax.Array,
                         lo_row: jax.Array, hi_row: jax.Array, k: int,
                         max_tiles: int, n_valid: int,
-                        tile_n: int = DEFAULT_TILE_N, interpret: bool = True,
+                        tile_n: int = DEFAULT_TILE_N, interpret: bool = False,
                         metric: Metric = TANIMOTO):
     """Scan only rows [lo_row[q], hi_row[q]) of ``db`` for each query.
 
-    lo_tile, n_tiles_q: (Q,) int32 tile window covering the row interval;
-    lo_row, hi_row: (Q,) int32 exact row bounds (boundary rows of partially
-    covered tiles are masked). ``db`` may be the folded database while the
-    bounds come from the full-resolution popcount sort. Returns ids into the
-    (sorted) DB and similarity values; empty slots are id -1 / val -inf."""
+    queries (Q, W) u32; db (N_pad, W) u32 and db_cnt (1, N_pad) i32 padded
+    to a tile multiple; lo_tile, n_tiles_q: (Q,) int32 tile window covering
+    the row interval; lo_row, hi_row: (Q,) int32 exact row bounds (boundary
+    rows of partially covered tiles are masked). ``db`` may be the folded
+    database while the bounds come from the full-resolution popcount sort.
+    Returns ids into the (sorted) DB and values, both (Q, k_pad); empty
+    slots are id -1 / val -inf."""
     q_n, w = queries.shape
     n_pad = db.shape[0]
     total_tiles = n_pad // tile_n
-    q_cnt = jnp.sum(jax.lax.population_count(queries).astype(jnp.int32), axis=-1)
+    kp = round_up(k, LANES)
 
     def db_index(q, t, lo_t, nt, lo, hi):
         blk = jnp.where(t < nt[q], lo_t[q] + t, lo_t[q])
@@ -307,7 +328,10 @@ def windowed_fused_topk(queries: jax.Array, db: jax.Array, db_cnt: jax.Array,
 
     def cnt_index(q, t, lo_t, nt, lo, hi):
         blk = jnp.where(t < nt[q], lo_t[q] + t, lo_t[q])
-        return (jnp.minimum(blk, total_tiles - 1),)
+        return (0, jnp.minimum(blk, total_tiles - 1))
+
+    def row_index(q, t, lo_t, nt, lo, hi):
+        return (q, 0, 0)
 
     body = functools.partial(_window_body, k=k, tile_n=tile_n,
                              max_tiles=max_tiles, n_valid=n_valid,
@@ -316,32 +340,31 @@ def windowed_fused_topk(queries: jax.Array, db: jax.Array, db_cnt: jax.Array,
         num_scalar_prefetch=4,
         grid=(q_n, max_tiles),
         in_specs=[
-            pl.BlockSpec((1, w), lambda q, t, lo_t, nt, lo, hi: (q, 0)),
-            pl.BlockSpec((1,), lambda q, t, lo_t, nt, lo, hi: (q,)),
+            pl.BlockSpec((None, 1, w), row_index),
             pl.BlockSpec((tile_n, w), db_index),
-            pl.BlockSpec((tile_n,), cnt_index),
+            pl.BlockSpec((1, tile_n), cnt_index),
         ],
         out_specs=[
-            pl.BlockSpec((1, k), lambda q, t, lo_t, nt, lo, hi: (q, 0)),
-            pl.BlockSpec((1, k), lambda q, t, lo_t, nt, lo, hi: (q, 0)),
+            pl.BlockSpec((None, 1, kp), row_index),
+            pl.BlockSpec((None, 1, kp), row_index),
         ],
         scratch_shapes=[
-            pltpu.VMEM((1, k), jnp.float32),
-            pltpu.VMEM((1, k), jnp.int32),
+            pltpu.VMEM((1, kp), jnp.float32),
+            pltpu.VMEM((1, kp), jnp.int32),
         ],
     )
-    out = pl.pallas_call(
+    ids, vals = pl.pallas_call(
         body,
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((q_n, k), jnp.int32),
-            jax.ShapeDtypeStruct((q_n, k), jnp.float32),
+            jax.ShapeDtypeStruct((q_n, 1, kp), jnp.int32),
+            jax.ShapeDtypeStruct((q_n, 1, kp), jnp.float32),
         ],
         interpret=interpret,
     )(lo_tile.astype(jnp.int32), n_tiles_q.astype(jnp.int32),
       lo_row.astype(jnp.int32), hi_row.astype(jnp.int32),
-      queries, q_cnt, db, db_cnt)
-    return out[0], out[1]
+      queries.reshape(q_n, 1, w), db, db_cnt)
+    return ids.reshape(q_n, kp), vals.reshape(q_n, kp)
 
 
 # ---------------------------------------------------------------------------
@@ -354,7 +377,7 @@ def _bitcount_body(w_ref, o_ref):
                          axis=-1)
 
 
-def bitcount(words: jax.Array, tile_n: int = 4096, interpret: bool = True):
+def bitcount(words: jax.Array, tile_n: int = 4096, interpret: bool = False):
     """(N, W) uint32 -> (N,) int32 popcounts, tiled through VMEM."""
     n, w = words.shape
     pad = (-n) % tile_n
@@ -368,86 +391,3 @@ def bitcount(words: jax.Array, tile_n: int = 4096, interpret: bool = True):
         interpret=interpret,
     )(wp)
     return out[:n]
-
-
-# ---------------------------------------------------------------------------
-# query-blocked fused kernel (beyond-paper: amortise the DB stream)
-# ---------------------------------------------------------------------------
-#
-# The paper's engine (and the kernel above) streams the database once PER
-# QUERY: bytes/query = N * 128 B, so a bandwidth-bound chip serves
-# HBM_bw / (N * 128) QPS. Batching QB queries into one sweep streams the DB
-# once per BLOCK: bytes/query /= QB, while the per-tile compute grows only
-# by the (cheap) popcount ops — the scan stays memory-bound up to QB ~ 48
-# (arithmetic intensity rises ~3 ops/B per query). At QB=32 a v5e chip
-# serves ~32x the single-query QPS on the same roofline. The FPGA analogue
-# would be replicating the TFC+top-k pipeline behind one HBM channel — the
-# paper's multi-engine design folded into one data stream.
-
-def _blocked_body(q_ref, qcnt_ref, db_ref, dbcnt_ref, ids_ref, vals_ref,
-                  top_s, top_i, *, k: int, qb: int, tile_n: int,
-                  n_tiles: int, n_valid: int, metric: Metric = TANIMOTO):
-    t = pl.program_id(1)
-
-    @pl.when(t == 0)
-    def _init():
-        top_s[...] = jnp.full((qb, k), NEG, jnp.float32)
-        top_i[...] = jnp.full((qb, k), -1, jnp.int32)
-
-    q = q_ref[...]                                     # (qb, W)
-    db = db_ref[...]                                   # (tile_n, W)
-    inter = jnp.sum(jax.lax.population_count(
-        q[:, None, :] & db[None, :, :]).astype(jnp.int32), axis=-1)  # (qb, tile_n)
-    s = metric_from_counts(metric, inter, qcnt_ref[...][:, None],
-                           dbcnt_ref[...][None, :])
-    idx = t * tile_n + jax.lax.iota(jnp.int32, tile_n)
-    s = jnp.where((idx < n_valid)[None, :], s, NEG)
-    all_s = jnp.concatenate([top_s[...], s], axis=1)   # (qb, k + tile_n)
-    all_i = jnp.concatenate([top_i[...], jnp.broadcast_to(idx, (qb, tile_n))],
-                            axis=1)
-    new_s, pos = jax.lax.top_k(all_s, k)
-    top_s[...] = new_s
-    top_i[...] = jnp.take_along_axis(all_i, pos, axis=1)
-
-    @pl.when(t == n_tiles - 1)
-    def _emit():
-        vals_ref[...] = top_s[...]
-        ids_ref[...] = top_i[...]
-
-
-def blocked_tanimoto_topk(queries: jax.Array, db: jax.Array, db_cnt: jax.Array,
-                          k: int, n_valid: int, qb: int = 8,
-                          tile_n: int = DEFAULT_TILE_N, interpret: bool = True,
-                          metric: Metric = TANIMOTO):
-    """queries (Q, W) with Q a multiple of qb; one DB sweep per qb queries."""
-    q_n, w = queries.shape
-    assert q_n % qb == 0, (q_n, qb)
-    n_pad = db.shape[0]
-    n_tiles = n_pad // tile_n
-    q_cnt = jnp.sum(jax.lax.population_count(queries).astype(jnp.int32), axis=-1)
-    body = functools.partial(_blocked_body, k=k, qb=qb, tile_n=tile_n,
-                             n_tiles=n_tiles, n_valid=n_valid, metric=metric)
-    out = pl.pallas_call(
-        body,
-        grid=(q_n // qb, n_tiles),
-        in_specs=[
-            pl.BlockSpec((qb, w), lambda q, t: (q, 0)),
-            pl.BlockSpec((qb,), lambda q, t: (q,)),
-            pl.BlockSpec((tile_n, w), lambda q, t: (t, 0)),
-            pl.BlockSpec((tile_n,), lambda q, t: (t,)),
-        ],
-        out_specs=[
-            pl.BlockSpec((qb, k), lambda q, t: (q, 0)),
-            pl.BlockSpec((qb, k), lambda q, t: (q, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((q_n, k), jnp.int32),
-            jax.ShapeDtypeStruct((q_n, k), jnp.float32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((qb, k), jnp.float32),
-            pltpu.VMEM((qb, k), jnp.int32),
-        ],
-        interpret=interpret,
-    )(queries, q_cnt, db, db_cnt)
-    return out[0], out[1]

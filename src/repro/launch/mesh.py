@@ -17,28 +17,24 @@ from __future__ import annotations
 import jax
 
 
-def compat_make_mesh(shape, axes):
-    """``jax.make_mesh`` across jax versions: pass explicit Auto axis_types
-    where supported (newer jax defaults shifted), plain call otherwise
-    (<= 0.4.x has neither the kwarg nor ``jax.sharding.AxisType``)."""
-    try:
-        axis_types = (jax.sharding.AxisType.Auto,) * len(axes)
-        return jax.make_mesh(shape, axes, axis_types=axis_types)
-    except (AttributeError, TypeError):
-        return jax.make_mesh(shape, axes)
+def make_mesh(shape, axes):
+    """``jax.make_mesh`` with every axis in Auto (sharding-propagation)
+    mode, the mode the shard_map and jit code here is written for."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat_make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_local_mesh(model_axis: int = 1):
     """Whatever this host has — used by smoke tests and CPU examples."""
     n = len(jax.devices())
     assert n % model_axis == 0
-    return compat_make_mesh((n // model_axis, model_axis), ("data", "model"))
+    return make_mesh((n // model_axis, model_axis), ("data", "model"))
 
 
 def data_axes(mesh) -> tuple[str, ...]:

@@ -26,6 +26,7 @@ from ..configs import CHEMBL_LIKE
 from ..core import BruteForceEngine, BitBoundFoldingEngine, HNSWEngine, recall_at_k
 from ..core.distributed import make_sharded_search, shard_database
 from ..data.molecules import SyntheticConfig, synthetic_fingerprints, queries_from_db
+from .compile_cache import use_compile_cache
 from .mesh import make_local_mesh
 
 
@@ -37,7 +38,8 @@ def serve(engine: str = "sharded-brute", n_db: int = 100_000, k: int = 20,
           log=print):
     """``backend`` selects the engine execution path (shared contract, see
     ``core/engine.py``): "numpy" (host reference), "tpu" (device-resident
-    Pallas pipeline, interpret-mode off-TPU) or "jnp" (device path without
+    Pallas pipeline; Mosaic on TPU, the Pallas interpreter on CPU, refused
+    on any other backend) or "jnp" (device path without
     Pallas). Applies to the ``bitbound-folding`` (two-stage scan) and
     ``hnsw`` (batched graph traversal) engines. ``hnsw_layout`` picks the
     traversal's fine-grained distance layout ("rows" row-gather /
@@ -420,6 +422,7 @@ def main():
                     help="service mode: enable span tracing and write Chrome "
                          "trace-event JSON here (view in Perfetto)")
     args = ap.parse_args()
+    use_compile_cache()
     if args.engine == "service" and args.replicas > 1:
         serve_frontend(engines=tuple(args.service_engines.split(",")),
                        n_db=args.n_db, k=args.k, n_ops=args.ops,

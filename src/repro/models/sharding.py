@@ -100,16 +100,14 @@ def _rp_specs(bspec, tp_axis, ndim):
 
 
 def _row_parallel_custom(w, x, mesh, bspec, tp_axis, ndim):
-    from jax.experimental.shard_map import shard_map
-
     x_spec, w_spec, y_spec = _rp_specs(bspec, tp_axis, ndim)
 
     @jax.custom_vjp
     def rp(w_, x_):
         def fwd_local(x_l, w_l):
             return jax.lax.psum(jnp.einsum("...i,io->...o", x_l, w_l), tp_axis)
-        return shard_map(fwd_local, mesh=mesh, in_specs=(x_spec, w_spec),
-                         out_specs=y_spec, check_rep=False)(x_, w_)
+        return jax.shard_map(fwd_local, mesh=mesh, in_specs=(x_spec, w_spec),
+                             out_specs=y_spec, check_vma=False)(x_, w_)
 
     def rp_fwd(w_, x_):
         return rp(w_, x_), (w_, x_)
@@ -121,8 +119,8 @@ def _row_parallel_custom(w, x, mesh, bspec, tp_axis, ndim):
         def dx_local(dy_l, w_l):                     # no collective
             return jnp.einsum("...o,io->...i", dy_l, w_l)
 
-        dx = shard_map(dx_local, mesh=mesh, in_specs=(y_spec, w_spec),
-                       out_specs=x_spec, check_rep=False)(dy_c, w_)
+        dx = jax.shard_map(dx_local, mesh=mesh, in_specs=(y_spec, w_spec),
+                           out_specs=x_spec, check_vma=False)(dy_c, w_)
 
         dp_axes = bspec
 
@@ -130,8 +128,8 @@ def _row_parallel_custom(w, x, mesh, bspec, tp_axis, ndim):
             dw_ = jnp.einsum("...i,...o->io", x_l, dy_l)
             return jax.lax.psum(dw_, dp_axes) if dp_axes else dw_
 
-        dw = shard_map(dw_local, mesh=mesh, in_specs=(x_spec, y_spec),
-                       out_specs=w_spec, check_rep=False)(x_, dy_c)
+        dw = jax.shard_map(dw_local, mesh=mesh, in_specs=(x_spec, y_spec),
+                           out_specs=w_spec, check_vma=False)(x_, dy_c)
         # cotangent dtypes MUST match the primal dtypes (custom_vjp contract;
         # the whisper encoder runs its residual stream in f32)
         return dw.astype(w_.dtype), dx.astype(x_.dtype)

@@ -27,8 +27,8 @@ def test_sharded_search_matches_oracle():
         from repro.core.distributed import make_sharded_search, shard_database
         from repro.kernels import ref
         from repro.data.molecules import SyntheticConfig, synthetic_fingerprints, queries_from_db
-        from repro.launch.mesh import compat_make_mesh
-        mesh = compat_make_mesh((4, 2), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((4, 2), ("data", "model"))
         db = synthetic_fingerprints(SyntheticConfig(n=4000, seed=0))
         q = jnp.asarray(queries_from_db(db, 8))
         with mesh:
@@ -51,8 +51,8 @@ def test_sharded_search_multipod_hierarchical_merge():
         from repro.core.distributed import make_sharded_search, shard_database
         from repro.kernels import ref
         from repro.data.molecules import SyntheticConfig, synthetic_fingerprints, queries_from_db
-        from repro.launch.mesh import compat_make_mesh
-        mesh = compat_make_mesh((2, 2, 2), ("pod", "data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
         db = synthetic_fingerprints(SyntheticConfig(n=2048, seed=1))
         q = jnp.asarray(queries_from_db(db, 4))
         with mesh:
@@ -74,9 +74,9 @@ def test_sharded_search_masks_pad_rows():
     out = _run_multi_device("""
         import jax, jax.numpy as jnp, numpy as np
         from repro.core.distributed import make_sharded_search, shard_database
-        from repro.launch.mesh import compat_make_mesh
+        from repro.launch.mesh import make_mesh
         from repro.data.molecules import SyntheticConfig, synthetic_fingerprints
-        mesh = compat_make_mesh((4, 2), ("data", "model"))
+        mesh = make_mesh((4, 2), ("data", "model"))
         db = synthetic_fingerprints(SyntheticConfig(n=10, seed=0))  # pads to 12
         with mesh:
             db_s, cnt_s, n_valid = shard_database(mesh, db)
@@ -102,16 +102,16 @@ def test_quantized_psum_close_to_exact():
     out = _run_multi_device("""
         import jax, jax.numpy as jnp, numpy as np
         from functools import partial
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
         from repro.distributed.compression import quantized_psum
-        from repro.launch.mesh import compat_make_mesh
-        mesh = compat_make_mesh((8,), ("data",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((8,), ("data",))
         x = jax.random.normal(jax.random.key(0), (8, 512))
         f_q = shard_map(lambda v: quantized_psum(v[0], "data"), mesh=mesh,
-                        in_specs=P("data"), out_specs=P(), check_rep=False)
+                        in_specs=P("data"), out_specs=P(), check_vma=False)
         f_e = shard_map(lambda v: jax.lax.psum(v[0], "data"), mesh=mesh,
-                        in_specs=P("data"), out_specs=P(), check_rep=False)
+                        in_specs=P("data"), out_specs=P(), check_vma=False)
         got, exact = f_q(x), f_e(x)
         err = float(jnp.abs(got - exact).max())
         scale = float(jnp.abs(x).max())

@@ -65,7 +65,7 @@ def test_dryrun_cell_reduced_subprocess():
         def small_mesh(*, multi_pod=False):
             shape = (2, 2, 4) if multi_pod else (4, 4)
             axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-            return mesh_mod.compat_make_mesh(shape, axes)
+            return mesh_mod.make_mesh(shape, axes)
         mesh_mod.make_production_mesh = small_mesh
         import repro.launch.dryrun as dr
         dr.make_production_mesh = small_mesh

@@ -221,9 +221,76 @@ def test_blocked_topk_matches_oracle(n, q, k, qb, tile):
     """Query-blocked engine (one DB sweep per qb queries) stays exact."""
     db = jnp.asarray(_db(n, seed=4))
     qs = jnp.asarray(queries_from_db(np.asarray(db), q))
-    ids, vals = ops.tanimoto_topk_blocked(qs, db, k=k, qb=qb, tile_n=tile)
+    ids, vals = ops.tanimoto_topk(qs, db, k=k, qb=qb, tile_n=tile)
     _, rvals = ref.tanimoto_topk_ref(qs, db, k=k)
     np.testing.assert_allclose(np.asarray(vals), np.asarray(rvals), rtol=1e-6)
     s = np.asarray(ref.tanimoto_scores_ref(qs, db))
     got = s[np.arange(q)[:, None], np.asarray(ids)]
     np.testing.assert_allclose(got, np.asarray(rvals), rtol=1e-6)
+
+
+# -- Mosaic layout edge cases (interpret mode, bit-for-bit vs ref.py) ---------
+
+def _tied_db(n, seed):
+    """A library with exact duplicate rows across tile boundaries, so equal
+    scores straddle tiles and the kernels' tie order is exercised."""
+    db = _db(n, seed=seed)
+    db[n // 2:n // 2 + 40] = db[:40]
+    db[n - 30:] = db[5:35]
+    return db
+
+
+@pytest.mark.parametrize("q,k,qb", [
+    (13, 20, 8),      # Q not a multiple of 8 -> padded query block
+    (5, 150, 8),      # k past one 128-lane row -> two lanes rows of k
+    (9, 7, 16),       # a 16-query block holding all 9 queries
+])
+def test_fused_topk_layout_cases_bitwise(q, k, qb):
+    db = _tied_db(1500, seed=11)
+    qs = jnp.asarray(np.concatenate([db[:3], queries_from_db(db, q - 3)]))
+    ids, vals = ops.tanimoto_topk(qs, jnp.asarray(db), k=k, tile_n=256,
+                                  qb=qb)
+    rids, rvals = ref.tanimoto_topk_ref(qs, jnp.asarray(db), k=k)
+    np.testing.assert_array_equal(np.asarray(vals), np.asarray(rvals))
+    np.testing.assert_array_equal(np.asarray(ids), np.asarray(rids))
+
+
+@pytest.mark.parametrize("q,k", [(11, 20), (3, 130)])
+def test_window_topk_layout_cases_bitwise(q, k):
+    db = _tied_db(2000, seed=12)
+    idx = bb.build_index(jnp.asarray(db))
+    qs = jnp.asarray(queries_from_db(db, q, seed=4))
+    rng = np.random.default_rng(q)
+    lo = rng.integers(0, 1500, q)
+    hi = np.minimum(lo + rng.integers(0, 2000, q), 2000)
+    ids, vals = ops.window_topk(qs, idx.db, idx.counts, jnp.asarray(lo),
+                                jnp.asarray(hi), k=k, tile_n=128)
+    rids, rvals = ref.window_topk_ref(qs, idx.db, idx.counts,
+                                      jnp.asarray(lo), jnp.asarray(hi), k=k)
+    np.testing.assert_array_equal(np.asarray(vals), np.asarray(rvals))
+    np.testing.assert_array_equal(np.asarray(ids), np.asarray(rids))
+
+
+def test_bitbound_topk_ties_bitwise():
+    db = _tied_db(2500, seed=13)
+    idx = bb.build_index(jnp.asarray(db))
+    qs = jnp.asarray(queries_from_db(db, 10, seed=5))
+    ids, vals = ops.bitbound_topk(qs, idx.db, idx.counts, k=25, cutoff=0.6,
+                                  tile_n=128)
+    rids, rvals = ref.bitbound_topk_ref(qs, idx.db, idx.counts, k=25,
+                                        cutoff=0.6)
+    np.testing.assert_array_equal(np.asarray(vals), np.asarray(rvals))
+    np.testing.assert_array_equal(np.asarray(ids), np.asarray(rids))
+
+
+def test_gather_layout_cases_bitwise():
+    """Q off the sublane grid, E off the lane grid, repeated and invalid
+    ids, and ids past the end (scored as the last row, like the oracle)."""
+    db = jnp.asarray(_tied_db(700, seed=14))
+    rng = np.random.default_rng(14)
+    ids = rng.integers(-1, 700, (11, 37)).astype(np.int32)
+    ids[:, 3] = ids[:, 4]
+    ids[0, 0] = 900
+    got = ops.gather_tanimoto(db[:11], db, jnp.asarray(ids))
+    want = ref.gather_tanimoto_ref(db[:11], db, jnp.asarray(ids))
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))

@@ -36,6 +36,7 @@ except ImportError:  # collection-safe fallback (see tests/_propcheck.py)
     from _propcheck import given, settings, strategies as st
 
 from repro.core import BitBoundFoldingEngine, BruteForceEngine, HNSWEngine
+from repro.core import bitbound as bb
 from repro.core import hnsw as hn
 from repro.core.fingerprints import (Metric, TANIMOTO, TVERSKY_SCALE,
                                      metric_from_counts,
@@ -364,12 +365,15 @@ def test_tanimoto_default_identity():
 
 @settings(max_examples=24, deadline=None)
 @given(st.sampled_from(METRICS),
-       st.floats(min_value=0.05, max_value=0.95, width=32),
+       st.floats(min_value=float(np.float32(0.05)),
+                 max_value=float(np.float32(0.95)), width=32),
        st.integers(min_value=0, max_value=2**31 - 1))
 def test_bound_window_soundness(metric, cutoff, seed):
-    """The defining BitBound property, per metric: any candidate scoring
-    >= cutoff has its popcount inside [ceil(a*lo), floor(a*hi)] — checked
-    against random fingerprints at several densities and widths."""
+    """The defining BitBound property, per metric: any candidate whose f32
+    score is >= cutoff has its popcount inside the window every engine
+    scans (``bitbound.bound_counts_np``: the Eq.2-style ``[ceil(a*lo),
+    floor(a*hi)]`` widened to the f32 boundary) — checked against random
+    fingerprints at several densities and widths."""
     rng = np.random.default_rng(seed)
     words = int(rng.choice([4, 7, 32]))
     density = float(rng.uniform(0.05, 0.6))
@@ -377,18 +381,16 @@ def test_bound_window_soundness(metric, cutoff, seed):
     q = pack_bits(rng.random((4, words * 32)) < density)
     a, b, c = _triples(q, db)
     scores = metric_from_counts_np(metric, c, a[:, None], b[None, :])
-    lo_r, hi_r = metric.bound_ratios(cutoff)
+    lo_cnt, hi_cnt = bb.bound_counts_np(a, cutoff, metric=metric)
     for qi in range(q.shape[0]):
         qual = scores[qi] >= cutoff
         if not qual.any():
             continue
         bq = b[qual]
-        if metric.bounded_below:
-            assert np.all(bq >= np.ceil(a[qi] * lo_r)), \
-                f"{metric.spec} Sc={cutoff}: qualifying count below window"
-        if metric.bounded_above:
-            assert np.all(bq <= np.floor(a[qi] * hi_r)), \
-                f"{metric.spec} Sc={cutoff}: qualifying count above window"
+        assert np.all(bq >= lo_cnt[qi]), \
+            f"{metric.spec} Sc={cutoff}: qualifying count below window"
+        assert np.all(bq <= hi_cnt[qi]), \
+            f"{metric.spec} Sc={cutoff}: qualifying count above window"
 
 
 @settings(max_examples=8, deadline=None)
