@@ -153,22 +153,24 @@ def run(n_db=20_000, qs=(16, 64, 256), ms=(8, 16, 32), beam=4, ef=64,
                 # per eager call otherwise; the traversal launches from
                 # inside a jitted while_loop)
                 @functools.partial(jax.jit, static_argnames=("kk",))
-                def row_kernel(queries, db, flat, worst, kk):
-                    s = ops.gather_tanimoto(queries, db, flat,
+                def row_kernel(queries, lines, flat, worst, kk):
+                    s = ops.gather_tanimoto(queries, lines, flat,
                                             q_cnt=popcount(queries))
                     s = jnp.where(s > worst[:, None], s, -jnp.inf)
                     return jax.lax.top_k(s, kk)
 
                 @functools.partial(jax.jit, static_argnames=("kk",))
-                def blocked_kernel(queries, nbr, pop, flat, worst, kk):
+                def blocked_kernel(queries, slabs, pop, flat, worst, kk):
                     return ops.expand_tanimoto_sorted(
-                        queries, nbr, pop, flat, worst, kk)
+                        queries, slabs, pop, flat, worst, kk)
 
+                # the kernels' layouts are built once, as the engine does
+                lines, slabs = ops.db_lines(c["db"]), ops.nbr_slabs(c["nbr"])
                 t_rk = timeit(lambda: row_kernel(
-                    c["queries"], c["db"], c["flat"], c["worst"], kk),
+                    c["queries"], lines, c["flat"], c["worst"], kk),
                     repeats=1, warmup=1)
                 t_bk = timeit(lambda: blocked_kernel(
-                    c["queries"], c["nbr"], c["pop"],
+                    c["queries"], slabs, c["pop"],
                     c["flat"], c["worst"], kk), repeats=1, warmup=1)
                 row.update(
                     us_row_kernel=round(t_rk * 1e6, 1),

@@ -49,14 +49,6 @@ def build_index(db: jax.Array) -> BitBoundIndex:
                          mu=float(counts.mean()), sigma=float(counts.std()))
 
 
-def bound_range(index: BitBoundIndex, query_count, cutoff: float,
-                metric: Metric = TANIMOTO):
-    """Per-metric candidate range [lo, hi) in the popcount-sorted database
-    (Tanimoto: the paper's Eq. 2) — :func:`bound_range_np` on one index."""
-    return bound_range_np(np.asarray(index.counts), np.asarray(query_count),
-                          cutoff, metric=metric)
-
-
 def _best_score_f32(metric: Metric, a: np.ndarray, b: np.ndarray):
     """Highest score a print of popcount ``b`` can reach against a query of
     popcount ``a`` (overlap c = min(a, b) — every metric grows with c), in

@@ -23,8 +23,9 @@ Every engine exposes ``search(queries, k) -> (ids, sims)`` numpy arrays and a
   ops (works on any JAX backend, no Pallas required).
 * ``"tpu"``   — same device-resident path with its hot stage swapped for the
   Pallas kernel: compiled through Mosaic on a TPU, run in the Pallas
-  interpreter on CPU (how the tests run), refused on any other backend. A
-  kernel that fails to import or lower raises; nothing falls back.
+  interpreter when CPU was asked for (``JAX_PLATFORMS=cpu``, how the tests
+  run), refused otherwise (``kernels/ops.py::interpret_on``). A kernel
+  that fails to import or lower raises; nothing falls back.
 
 Invalid names raise ``ValueError`` listing the engine's supported backends.
 The legacy ``use_kernel=True`` flag maps onto ``backend="tpu"`` when
@@ -85,14 +86,15 @@ def _store_mod():
 
 
 @functools.partial(jax.jit, static_argnames=("metric",))
-def _gather_score_frontier(q, dev_db, ids, metric: Metric = TANIMOTO):
+def _gather_score_frontier(q, db_lines, ids, metric: Metric = TANIMOTO):
     """Jitted single-query gather-distance launch for the insert-frontier
     scorer. Module-level so the compile cache is keyed purely on shapes
-    (plus the static metric): with the capacity-stable cached device db,
+    (plus the static metric): with the capacity-stable cached device lines,
     repeated frontier widths across insert batches (and engines) replay
     compiled launches instead of re-tracing the Pallas call per frontier."""
     from ..kernels import ops as kops
-    return kops.gather_tanimoto(q[None], dev_db, ids[None], metric=metric)[0]
+    return kops.gather_tanimoto(q[None], db_lines, ids[None],
+                                metric=metric)[0]
 
 
 @jax.jit
@@ -1340,7 +1342,7 @@ class HNSWEngine(SearchEngine):
         self._dirty_pos = 0        # consumed prefix of index.dirty_log
         self._dirty_epoch = 0      # dirty_log epoch the prefix belongs to
         self._upper_version = 0    # index.upper_version the graph carries
-        self._insert_db_cache = None  # (device db (cap, W), rows filled)
+        self._insert_db_cache = None  # (device db lines, rows filled)
         self._refresh_graph()
 
     @property
@@ -1363,7 +1365,8 @@ class HNSWEngine(SearchEngine):
                     and g.db.shape[0] == cap:
                 continue
             self._shard_graphs[s] = hn.place_graph(
-                hn.to_device_graph(idx, capacity=cap, layout=self.layout),
+                hn.to_device_graph(idx, capacity=cap, layout=self.layout,
+                                   kernel=self.backend == "tpu"),
                 self._shard_devices[s])
             self._shard_dirty[s] = False
 
@@ -1380,7 +1383,8 @@ class HNSWEngine(SearchEngine):
                 or self._graph_n > idx.n
                 or self._dirty_epoch != idx.dirty_epoch):
             self._graph = hn.to_device_graph(idx, capacity=cap,
-                                             layout=self.layout)
+                                             layout=self.layout,
+                                             kernel=self.backend == "tpu")
         else:
             # incremental refresh: scatter only the rows the insert batches
             # touched (new fingerprints + dirty base-adjacency rows and, on
@@ -1390,20 +1394,32 @@ class HNSWEngine(SearchEngine):
             # fresh to_device_graph.
             rows = sorted(set(log[self._dirty_pos:])
                           | set(range(self._graph_n, idx.n)))
-            db_dev, cnt_dev = g.db, g.db_popcount
-            base_dev, nbr_fps, nbr_cnt = g.base_adj, g.nbr_fps, g.nbr_cnt
+            new = dict(db=g.db, db_popcount=g.db_popcount,
+                       base_adj=g.base_adj, nbr_fps=g.nbr_fps,
+                       nbr_cnt=g.nbr_cnt, db_lines=g.db_lines,
+                       nbr_slabs=g.nbr_slabs)
             if rows:
+                from ..kernels import ops as kops
                 rows = np.asarray(rows, dtype=np.int64)
                 adj_rows = idx.base_adj[rows]
-                db_dev = db_dev.at[rows].set(jnp.asarray(idx.db[rows]))
-                cnt_dev = cnt_dev.at[rows].set(
-                    jnp.asarray(idx.db_popcount[rows].astype(np.int32)))
-                base_dev = base_dev.at[rows].set(jnp.asarray(adj_rows))
+
+                def put(field, at, value):
+                    new[field] = new[field].at[at].set(jnp.asarray(value))
+                put("db", rows, idx.db[rows])
+                put("db_popcount", rows,
+                    idx.db_popcount[rows].astype(np.int32))
+                put("base_adj", rows, adj_rows)
+                if g.db_lines is not None:
+                    lids = kops.line_ids(idx.db.shape[1], rows)
+                    put("db_lines", lids, kops.db_lines_at(idx.db, lids))
                 if self.layout == "blocked":
                     fps_np, cnt_np = hn._blocked_rows(
                         idx.db, idx.db_popcount, adj_rows)
-                    nbr_fps = nbr_fps.at[rows].set(jnp.asarray(fps_np))
-                    nbr_cnt = nbr_cnt.at[rows].set(jnp.asarray(cnt_np))
+                    if g.nbr_slabs is not None:
+                        put("nbr_slabs", rows, kops.nbr_slabs(fps_np))
+                    else:
+                        put("nbr_fps", rows, fps_np)
+                        put("nbr_cnt", rows, cnt_np)
             # upper layers only change when a batch inserted a level>0 node
             # (index.upper_version) — level-0-only batches skip the O(cap)
             # densify+upload entirely
@@ -1411,10 +1427,8 @@ class HNSWEngine(SearchEngine):
             if self._upper_version != idx.upper_version:
                 upper_dev = jnp.asarray(hn._dense_upper(idx, cap))
             self._graph = hn.HNSWDeviceGraph(
-                db=db_dev, db_popcount=cnt_dev, base_adj=base_dev,
                 upper_adj=upper_dev, entry_point=jnp.int32(idx.entry_point),
-                max_level=max(idx.max_level, 0),
-                nbr_fps=nbr_fps, nbr_cnt=nbr_cnt)
+                max_level=max(idx.max_level, 0), **new)
         self._graph_n = idx.n
         self._dirty_pos = len(idx.dirty_log) if idx.dirty_log is not None \
             else 0
@@ -1426,24 +1440,27 @@ class HNSWEngine(SearchEngine):
         """Insert-frontier scorer routing neighbour batches through the
         Pallas ``gather_tanimoto`` kernel (ROADMAP "device-side
         construction": the graph walk stays host-side, the distance stage
-        runs on device). The device database is cached capacity-padded
-        across insert batches — new rows are appended in place (one scatter
-        per batch) and a full upload only happens when the capacity doubles,
-        instead of the old full-db re-upload per batch. Existing rows are
-        immutable, so the cache key is just (capacity, rows filled).
+        runs on device). The device database is cached capacity-padded, in
+        the kernel's line layout, across insert batches — the lines holding
+        new rows are rewritten in place (one scatter per batch) and a full
+        upload only happens when the capacity doubles, so no frontier launch
+        relays the database out. Existing rows are immutable, so the cache
+        is just (lines, rows filled).
         ``db_cnt`` is part of the scorer-factory protocol but unused here —
         the kernel recomputes row popcounts in-register."""
         del db_cnt
+        from ..kernels import ops as kops
         n, w = db.shape
         cached = self._insert_db_cache
-        if (cached is not None and cached[0].shape[0] >= n
-                and cached[0].shape[1] == w):
+        if cached is not None and kops.line_ids(w, n - 1) < len(cached[0]):
             dev, filled = cached
             if n > filled:
-                dev = dev.at[filled:n].set(jnp.asarray(db[filled:n]))
+                lids = kops.line_ids(w, np.arange(filled, n))
+                dev = dev.at[lids].set(jnp.asarray(kops.db_lines_at(db, lids)))
         else:
             cap = _store_mod().next_pow2(n)
-            dev = jnp.zeros((cap, w), jnp.uint32).at[:n].set(jnp.asarray(db))
+            dev = jnp.asarray(kops.db_lines_at(
+                db, kops.line_ids(w, np.arange(cap))))
         self._insert_db_cache = (dev, n)
 
         metric = self.metric
@@ -1483,30 +1500,27 @@ class HNSWEngine(SearchEngine):
         key = (k, ef, beam, max_level, use_kernel, layout, metric)
 
         def build():
-            def run(q, db, db_cnt, base_adj, upper_adj, ep, nbr_fps, nbr_cnt):
-                g = hn.HNSWDeviceGraph(db=db, db_popcount=db_cnt,
-                                       base_adj=base_adj, upper_adj=upper_adj,
-                                       entry_point=ep, max_level=max_level,
-                                       nbr_fps=nbr_fps, nbr_cnt=nbr_cnt)
+            def run(q, arrays):
+                g = arrays._replace(max_level=max_level)
                 score_fn = None
                 expand_fn = None
                 if use_kernel:
                     from ..kernels import ops as kops
 
                     def score_fn(qs, qc, ids):
-                        return kops.gather_tanimoto(qs, db, ids, q_cnt=qc,
-                                                    metric=metric)
+                        return kops.gather_tanimoto(qs, g.db_lines, ids,
+                                                    q_cnt=qc, metric=metric)
                 if layout == "blocked":
                     if use_kernel:
                         def expand_fn(qs, qc, pop, flat, worst, kk):
                             return kops.expand_tanimoto_sorted(
-                                qs, nbr_fps, pop, flat, worst, kk,
+                                qs, g.nbr_slabs, pop, flat, worst, kk,
                                 q_cnt=qc, metric=metric)
                     else:
                         def expand_fn(qs, qc, pop, flat, worst, kk):
                             return hn.expand_scores_jnp(
-                                qs, qc, nbr_fps, nbr_cnt, pop, flat, worst,
-                                kk, metric=metric)
+                                qs, qc, g.nbr_fps, g.nbr_cnt, pop, flat,
+                                worst, kk, metric=metric)
                 return hn.search_hnsw(g, q, k, ef, max_iters=max_iters,
                                       beam=beam, score_fn=score_fn,
                                       expand_fn=expand_fn, metric=metric)
@@ -1552,9 +1566,7 @@ class HNSWEngine(SearchEngine):
         for s, g in enumerate(self._shard_graphs):
             fn = self._device_search(k, ef, beam, g.max_level)
             q_s = jax.device_put(q_dev, self._shard_devices[s])
-            ids, sims, tstats = fn(q_s, g.db, g.db_popcount, g.base_adj,
-                                   g.upper_adj, g.entry_point, g.nbr_fps,
-                                   g.nbr_cnt)
+            ids, sims, tstats = fn(q_s, g._replace(max_level=None))
             runs_i.append(jax.device_put(ids, dev0))
             runs_s.append(jax.device_put(sims, dev0))
             shard_stats.append(tstats)
@@ -1586,8 +1598,7 @@ class HNSWEngine(SearchEngine):
         g = self._graph
         fn = self._device_search(k, ef or self.ef_search, beam or self.beam,
                                  g.max_level)
-        return fn, (jnp.asarray(queries), g.db, g.db_popcount, g.base_adj,
-                    g.upper_adj, g.entry_point, g.nbr_fps, g.nbr_cnt)
+        return fn, (jnp.asarray(queries), g._replace(max_level=None))
 
     def search(self, queries, k: int, ef: int | None = None,
                beam: int | None = None):

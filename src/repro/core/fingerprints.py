@@ -168,8 +168,12 @@ def exact_ratio(num, den):
     rounding midpoint, so its rounding is the IEEE one."""
     n = num.astype(jnp.float32)
     d = den.astype(jnp.float32)
-    q = n / d
+    return _correct(n / d, n, d)
 
+
+def _correct(q, n, d):
+    """The IEEE quotient ``n / d`` (f32 holding integers, as in
+    :func:`exact_ratio`) from an approximation ``q`` a few ulp off it."""
     def top8(x):     # x with its significand cut to the leading 8 bits
         bits = jax.lax.bitcast_convert_type(x, jnp.int32) & -(1 << 16)
         return jax.lax.bitcast_convert_type(bits, jnp.float32)

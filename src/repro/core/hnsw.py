@@ -499,6 +499,12 @@ class HNSWDeviceGraph(NamedTuple):
     whole expansion is a single contiguous ``2M*W``-word HBM stream for the
     fused expand kernel, at the HBM cost of one extra ``2M*W``-word copy of
     the base layer per node.
+
+    A graph for the Pallas kernels (``kernel=True``) also holds the database
+    in the gather kernel's line layout (``db_lines``) and, on the blocked
+    layout, the neighbour blocks in the expand kernel's slab layout
+    (``nbr_slabs``) in place of ``nbr_fps``/``nbr_cnt`` — built once here,
+    so no traversal relays them out per call.
     """
     db: jax.Array             # (N, W) uint32
     db_popcount: jax.Array    # (N,) int32
@@ -506,8 +512,10 @@ class HNSWDeviceGraph(NamedTuple):
     upper_adj: jax.Array      # (L, N, M) int32 — dense per-level adjacency (-1 pad)
     entry_point: jax.Array    # () int32
     max_level: int
-    nbr_fps: jax.Array | None = None   # (N, 2M, W) uint32 — blocked layout only
-    nbr_cnt: jax.Array | None = None   # (N, 2M) int32
+    nbr_fps: jax.Array | None = None   # (N, 2M, W) uint32 — blocked, jnp
+    nbr_cnt: jax.Array | None = None   # (N, 2M) int32 — blocked, jnp
+    db_lines: jax.Array | None = None  # ops.db_lines(db) — kernel only
+    nbr_slabs: jax.Array | None = None  # ops.nbr_slabs(nbr_fps) — kernel, blocked
 
 
 LAYOUTS = ("rows", "blocked")
@@ -535,13 +543,15 @@ def _blocked_rows(db: np.ndarray, db_cnt: np.ndarray, adj: np.ndarray):
 
 
 def to_device_graph(index: HNSWIndex, capacity: int | None = None,
-                    layout: str = "rows") -> HNSWDeviceGraph:
+                    layout: str = "rows",
+                    kernel: bool = False) -> HNSWDeviceGraph:
     """Densify the index for the device engine. ``capacity`` (>= n) pads the
     node dimension — pad rows are zero fingerprints with no edges, so they
     are unreachable and the traversal is unaffected. Engines pad to a power
     of two so online inserts below the capacity reuse compiled traversals.
-    ``layout="blocked"`` also builds the neighbour-blocked base-layer copy
-    (:class:`HNSWDeviceGraph` docstring)."""
+    ``layout="blocked"`` also builds the neighbour-blocked base-layer copy,
+    and ``kernel=True`` the Pallas kernels' layouts of the database and of
+    that copy (:class:`HNSWDeviceGraph` docstring)."""
     if layout not in LAYOUTS:
         raise ValueError(f"layout must be one of {LAYOUTS}, got {layout!r}")
     L = max(index.max_level, 0)
@@ -554,16 +564,23 @@ def to_device_graph(index: HNSWIndex, capacity: int | None = None,
     cnt[:n] = index.db_popcount
     base = np.full((cap, index.base_adj.shape[1]), -1, dtype=np.int32)
     base[:n] = index.base_adj
-    nbr_fps = nbr_cnt = None
+    nbr_fps = nbr_cnt = db_lines = nbr_slabs = None
+    if kernel:
+        from ..kernels import ops as kops
+        db_lines = jnp.asarray(kops.db_lines(db))
     if layout == "blocked":
         nbr_fps_np, nbr_cnt_np = _blocked_rows(db, cnt, base)
-        nbr_fps = jnp.asarray(nbr_fps_np)
-        nbr_cnt = jnp.asarray(nbr_cnt_np)
+        if kernel:
+            nbr_slabs = jnp.asarray(kops.nbr_slabs(nbr_fps_np))
+        else:
+            nbr_fps = jnp.asarray(nbr_fps_np)
+            nbr_cnt = jnp.asarray(nbr_cnt_np)
     return HNSWDeviceGraph(
         db=jnp.asarray(db), db_popcount=jnp.asarray(cnt),
         base_adj=jnp.asarray(base), upper_adj=jnp.asarray(upper),
         entry_point=jnp.int32(index.entry_point), max_level=L,
-        nbr_fps=nbr_fps, nbr_cnt=nbr_cnt)
+        nbr_fps=nbr_fps, nbr_cnt=nbr_cnt, db_lines=db_lines,
+        nbr_slabs=nbr_slabs)
 
 
 def _sims(q: jax.Array, q_cnt: jax.Array, g: HNSWDeviceGraph, ids: jax.Array,

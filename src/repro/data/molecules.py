@@ -49,7 +49,12 @@ def _bit_probs(cfg) -> np.ndarray:
 
 
 #: libraries up to this size draw every molecule from one random stream in
-#: one pass; larger ones draw chunks of ``CHUNK_ROWS // 8`` rows from
+#: one pass, row for row the library this generator has always produced:
+#: every test library, and every committed ``experiments/`` row of at most
+#: this size, was made from such a stream and stays reproducible. Larger
+#: ones (a different library from the one-pass stream's, so the 1,000,000-
+#: row point of ``BENCH_tiered.json`` predates them) draw chunks of
+#: ``CHUNK_ROWS // 8`` rows from
 #: independent child streams, split across worker threads (numpy releases
 #: the GIL in the heavy kernels). Each worker reuses one pair of
 #: (chunk, length) float64 buffers for all its chunks: the host holds

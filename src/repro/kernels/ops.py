@@ -15,12 +15,12 @@ become ``(1, N)`` rows, and the gather/expand kernels read fingerprints as
 from __future__ import annotations
 
 import functools
+import importlib.util
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..core import bitbound as bb
 from ..core.fingerprints import Metric, TANIMOTO, popcount
 from . import expand as ke
 from . import gather as kg
@@ -29,16 +29,28 @@ from .tanimoto_topk import LANES, SUBLANES, round_up
 
 
 def _interpret() -> bool:
-    """Kernels compile through Mosaic on TPU. On CPU — how the tests run —
-    they run in the Pallas interpreter. Any other backend is an error: a
-    device that is not a TPU must never be served by the interpreter."""
-    backend = jax.default_backend()
+    """Whether the kernels run in the Pallas interpreter (see
+    :func:`interpret_on`) for this process's backend and platform choice."""
+    return interpret_on(jax.default_backend(), jax.config.jax_platforms,
+                        importlib.util.find_spec("libtpu") is not None)
+
+
+def interpret_on(backend: str, platforms: str | None,
+                 tpu_runtime: bool) -> bool:
+    """Kernels compile through Mosaic on TPU. On CPU they run in the Pallas
+    interpreter, but only when CPU is what was asked for: ``platforms``
+    (``JAX_PLATFORMS``) puts ``cpu`` first, as the tests do, or is unset on
+    a host with no TPU runtime. A host with a TPU runtime whose TPU failed
+    to start also reports the CPU backend; that, and any other backend, is
+    an error — a TPU engine must never be served by the interpreter."""
     if backend == "tpu":
         return False
-    if backend == "cpu":
+    first = (platforms or "").split(",")[0]
+    if backend == "cpu" and (first == "cpu" or not (first or tpu_runtime)):
         return True
-    raise RuntimeError(f"Pallas kernels need a TPU (or the CPU interpreter "
-                       f"for tests); the default JAX backend is {backend!r}")
+    raise RuntimeError(f"Pallas kernels need a TPU (or the CPU interpreter, "
+                       f"with JAX_PLATFORMS=cpu); the default JAX backend is "
+                       f"{backend!r} with JAX_PLATFORMS={platforms!r}")
 
 
 def _pick_tile(n: int, tile_n: int | None) -> int:
@@ -95,27 +107,6 @@ def tanimoto_topk(queries: jax.Array, db: jax.Array, k: int,
     tile = min(_pick_tile(db.shape[0], tile_n), round_up(db.shape[0], LANES))
     return _tanimoto_topk_impl(queries, db, db_popcount, k, qb, tile,
                                metric if metric is not None else TANIMOTO)
-
-
-def bitbound_topk(queries: jax.Array, db_sorted: jax.Array,
-                  counts_sorted: jax.Array, k: int, cutoff: float,
-                  max_tiles: int | None = None, tile_n: int | None = None,
-                  metric: Metric | None = None):
-    """BitBound-windowed fused KNN over a popcount-sorted DB.
-
-    The per-query popcount window (:func:`core.bitbound.bound_range_np`, the
-    one window every engine uses) selects a contiguous row range of the
-    sorted database, which the row-window kernel scans. Returns ids into
-    the *sorted* database (caller maps through the BitBoundIndex order),
-    and similarity values. Entries that fall outside every window come back
-    as id -1 / val -inf."""
-    metric = metric if metric is not None else TANIMOTO
-    counts_np = np.asarray(counts_sorted)
-    lo, hi = bb.bound_range_np(counts_np,
-                               np.asarray(popcount(jnp.asarray(queries))),
-                               cutoff, metric=metric)
-    return window_topk(queries, db_sorted, counts_np, lo, hi, k,
-                       max_tiles=max_tiles, tile_n=tile_n, metric=metric)
 
 
 @functools.partial(jax.jit, static_argnames=("k", "max_tiles", "tile_n", "n_valid", "metric"))
@@ -188,65 +179,112 @@ def _line_layout(queries, q_cnt, q_pad: int):
             _pad_to(q_cnt.astype(jnp.int32), q_pad).reshape(q_pad, 1))
 
 
-def gather_tanimoto(queries: jax.Array, db: jax.Array, ids: jax.Array,
+def db_lines(db):
+    """The gather kernel's database operand: (N, W) prints as
+    (ceil(N / per_line), 128) lines of ``per_line = 128 // wp`` prints, each
+    padded to ``wp = line_width(W)`` words. The owner of a device database
+    builds this once (the HNSW device graph, the insert scorer's cache), so
+    no kernel launch relays the database out. Numpy in, numpy out; jax in,
+    jax out."""
+    xp = np if isinstance(db, np.ndarray) else jnp
+    n, w = db.shape
+    wp = kg.line_width(w)
+    rows = round_up(n, LANES // wp)
+    return xp.pad(db, ((0, rows - n), (0, wp - w))).reshape(-1, LANES)
+
+
+def db_lines_at(db: np.ndarray, line_ids: np.ndarray) -> np.ndarray:
+    """Lines ``line_ids`` of ``db_lines(db)``, on the host, with rows past
+    the end of ``db`` zero (a capacity-padded copy's pad rows): what an
+    incremental refresh of a device copy scatters."""
+    per_line = LANES // kg.line_width(db.shape[1])
+    src = (np.asarray(line_ids)[:, None] * per_line
+           + np.arange(per_line)).reshape(-1)
+    rows = np.zeros((src.size, db.shape[1]), np.uint32)
+    ok = src < db.shape[0]
+    rows[ok] = db[src[ok]]
+    return db_lines(rows)
+
+
+def line_ids(w: int, rows):
+    """Id of the :func:`db_lines` line that holds row ``rows`` of a
+    ``w``-word print database, or the sorted distinct ids for an array of
+    rows."""
+    ids = np.asarray(rows) // (LANES // kg.line_width(w))
+    return np.unique(ids) if ids.ndim else int(ids)
+
+
+def nbr_slabs(nbr_fps):
+    """The expand kernel's neighbour operand: (N, 2M, W) blocks as
+    (N, S, 128) slabs of lines (:func:`db_lines` per node, 2M padded so
+    ``S`` is a multiple of 8). Numpy in, numpy out; jax in, jax out."""
+    xp = np if isinstance(nbr_fps, np.ndarray) else jnp
+    n, m2, w = nbr_fps.shape
+    wp = kg.line_width(w)
+    per_line = LANES // wp
+    m2p = round_up(m2, SUBLANES * per_line)            # slab rows % 8 == 0
+    return xp.pad(nbr_fps, ((0, 0), (0, m2p - m2), (0, wp - w))
+                  ).reshape(n, m2p // per_line, LANES)
+
+
+def gather_tanimoto(queries: jax.Array, lines: jax.Array, ids: jax.Array,
                     q_cnt: jax.Array | None = None,
                     metric: Metric | None = None) -> jax.Array:
     """Fine-grained gather-distance stage: per-query candidate ids -> sims.
 
-    queries (Q, W) u32 (W <= 128 words), db (N, W) u32, ids (Q, E) i32 ->
-    (Q, E) f32. Entries with id ``-1`` come back as ``-inf``; ids past the
-    end score the last row. Jit-compatible — the HNSW traversal calls this
-    from inside its ``lax.while_loop``, scoring one whole beam expansion
-    (B·2M neighbour ids) per kernel launch.
+    queries (Q, W) u32 (W <= 128 words), lines the database in
+    :func:`db_lines` layout, ids (Q, E) i32 -> (Q, E) f32. Entries with id
+    ``-1`` come back as ``-inf``; ids past the end score the last row the
+    lines hold. Jit-compatible — the HNSW traversal calls this from inside
+    its ``lax.while_loop``, scoring one whole beam expansion (B·2M
+    neighbour ids) per kernel launch.
     """
     queries = jnp.asarray(queries)
-    db = jnp.asarray(db)
+    lines = jnp.asarray(lines)
     ids = jnp.asarray(ids, dtype=jnp.int32)
     if q_cnt is None:
         q_cnt = popcount(queries)
     q_n, e_n = ids.shape
-    n, w = db.shape
     qb, q_pad = _query_block(q_n)
     wp, q_lines, qc = _line_layout(queries, q_cnt, q_pad)
-    per_line = LANES // wp
-    db_lines = _pad_to(db, round_up(n, per_line), wp).reshape(-1, LANES)
+    n = lines.shape[0] * (LANES // wp)
     ids = jnp.where(ids >= 0, jnp.minimum(ids, n - 1), -1)
     ids = _pad_to(ids, q_pad, round_up(e_n, LANES), value=-1)
     s = kg.gather_tanimoto_scores(
-        q_lines, qc, db_lines, ids, wp=wp, qb=qb, interpret=_interpret(),
+        q_lines, qc, lines, ids, wp=wp, qb=qb, interpret=_interpret(),
         metric=metric if metric is not None else TANIMOTO)
     return s[:q_n, :e_n]
 
 
-def expand_tanimoto_sorted(queries: jax.Array, nbr_fps: jax.Array,
+def expand_tanimoto_sorted(queries: jax.Array, slabs: jax.Array,
                            pop_ids: jax.Array, flat_ids: jax.Array,
                            worst: jax.Array, kk: int,
                            q_cnt: jax.Array | None = None,
                            metric: Metric | None = None):
     """Fused beam-expansion stage over the neighbour-blocked layout.
 
-    queries (Q, W) u32 (W <= 128 words), nbr_fps (N, 2M, W) u32 (zero rows
-    for -1 slots), pop_ids (Q, beam) i32, flat_ids (Q, beam*2M) i32, worst
-    (Q,) f32 -> (scores (Q, kk) desc, ids (Q, kk)). One contiguous block DMA
-    per popped node (``beam`` streams per query-iteration vs the row
-    kernel's ``beam*2M`` fetches), scores sorted in-kernel so the traversal
-    merges a single run. Neighbour popcounts are recounted from ``nbr_fps``.
+    queries (Q, W) u32 (W <= 128 words), slabs the (N, 2M, W) neighbour
+    blocks (zero rows for -1 slots) in :func:`nbr_slabs` layout, pop_ids
+    (Q, beam) i32, flat_ids (Q, beam*2M) i32, worst (Q,) f32 ->
+    (scores (Q, kk) desc, ids (Q, kk)). One contiguous slab DMA per popped
+    node (``beam`` streams per query-iteration vs the row kernel's
+    ``beam*2M`` fetches), scores sorted in-kernel so the traversal merges a
+    single run. Neighbour popcounts are recounted from the slabs.
     Jit-compatible — the HNSW ``lax.while_loop`` launches it once per
     iteration.
     """
     queries = jnp.asarray(queries)
-    nbr_fps = jnp.asarray(nbr_fps)
+    slabs = jnp.asarray(slabs)
     if q_cnt is None:
         q_cnt = popcount(queries)
     q_n = queries.shape[0]
-    n, m2, _ = nbr_fps.shape
     beam = pop_ids.shape[1]
+    m2 = flat_ids.shape[1] // beam
     qb, q_pad = _query_block(q_n)
     wp, q_lines, qc = _line_layout(queries, q_cnt, q_pad)
     per_line = LANES // wp
-    m2p = round_up(m2, SUBLANES * per_line)            # slab rows % 8 == 0
-    nbr_lines = jnp.pad(nbr_fps, ((0, 0), (0, m2p - m2), (0, wp - nbr_fps.shape[2]))
-                        ).reshape(n, m2p // per_line, LANES)
+    m2p = slabs.shape[1] * per_line
+    assert m2p == round_up(m2, SUBLANES * per_line), (slabs.shape, m2, wp)
     slots = jnp.pad(jnp.asarray(flat_ids, jnp.int32).reshape(q_n, beam, m2),
                     ((0, 0), (0, 0), (0, m2p - m2)), constant_values=-1)
     slots = _pad_to(slots.reshape(q_n, beam * m2p), q_pad,
@@ -254,7 +292,7 @@ def expand_tanimoto_sorted(queries: jax.Array, nbr_fps: jax.Array,
     pops = _pad_to(jnp.asarray(pop_ids, jnp.int32), q_pad, value=-1)
     worst = _pad_to(jnp.asarray(worst, jnp.float32), q_pad).reshape(q_pad, 1)
     s, i = ke.expand_sorted_scores(
-        q_lines, qc, nbr_lines, pops, slots, worst, kk, wp=wp, qb=qb,
+        q_lines, qc, slabs, pops, slots, worst, kk, wp=wp, qb=qb,
         interpret=_interpret(),
         metric=metric if metric is not None else TANIMOTO)
     return s[:q_n, :kk], i[:q_n, :kk]

@@ -38,8 +38,9 @@ def serve(engine: str = "sharded-brute", n_db: int = 100_000, k: int = 20,
           log=print):
     """``backend`` selects the engine execution path (shared contract, see
     ``core/engine.py``): "numpy" (host reference), "tpu" (device-resident
-    Pallas pipeline; Mosaic on TPU, the Pallas interpreter on CPU, refused
-    on any other backend) or "jnp" (device path without
+    Pallas pipeline; Mosaic on TPU, the Pallas interpreter when CPU was
+    asked for, refused otherwise — ``kernels/ops.py::interpret_on``) or
+    "jnp" (device path without
     Pallas). Applies to the ``bitbound-folding`` (two-stage scan) and
     ``hnsw`` (batched graph traversal) engines. ``hnsw_layout`` picks the
     traversal's fine-grained distance layout ("rows" row-gather /

@@ -44,7 +44,8 @@ def test_bound_range_contains_all_hits(small_db, queries):
     idx = bb.build_index(jnp.asarray(small_db))
     cutoff = 0.6
     for q in jnp.asarray(queries)[:4]:
-        lo, hi = bb.bound_range(idx, popcount(q), cutoff)
+        lo, hi = bb.bound_range_np(np.asarray(idx.counts),
+                                   np.asarray(popcount(q)), cutoff)
         lo, hi = int(lo), int(hi)
         inter = np.bitwise_count(np.asarray(q)[None] & np.asarray(idx.db)).sum(-1)
         union = int(popcount(q)) + np.asarray(idx.counts) - inter

@@ -120,7 +120,8 @@ def test_gather_compiles(one_chip, mosaic, q_n):
     e = 4 * 2 * CHEMBL_LIKE.hnsw_m
     _compile(ops.gather_tanimoto,
              _spec(one_chip, (q_n, W), jnp.uint32),
-             _spec(one_chip, (N, W), jnp.uint32),
+             _spec(one_chip, ops.db_lines(np.zeros((N, W), np.uint32)).shape,
+                   jnp.uint32),
              _spec(one_chip, (q_n, e), jnp.int32))
 
 
@@ -131,7 +132,9 @@ def test_expand_compiles(one_chip, mosaic, q_n):
     _compile(lambda q, nbr, pop, flat, worst: ops.expand_tanimoto_sorted(
                  q, nbr, pop, flat, worst, CHEMBL_LIKE.hnsw_ef_search),
              _spec(one_chip, (q_n, W), jnp.uint32),
-             _spec(one_chip, (HNSW_N, m2, W), jnp.uint32),
+             _spec(one_chip, ops.nbr_slabs(np.zeros((HNSW_N, m2, W),
+                                                    np.uint32)).shape,
+                   jnp.uint32),
              _spec(one_chip, (q_n, beam), jnp.int32),
              _spec(one_chip, (q_n, beam * m2), jnp.int32),
              _spec(one_chip, (q_n,), jnp.float32))
@@ -195,3 +198,34 @@ def test_bitbound_engine_pipeline_fits(one_chip, mosaic):
                    _spec(one_chip, (CAP,), jnp.int32),
                    _spec(one_chip, (CAP,), jnp.int32)))
 
+
+
+@pytest.mark.parametrize("layout", ["rows", "blocked"])
+def test_hnsw_engine_pipeline_fits(one_chip, mosaic, layout):
+    """The served HNSWEngine traversal at Q=64 over a graph of the full
+    library's capacity. The kernels read the graph's own line and slab
+    layouts, so no launch relays the database out: the blocked layout's
+    2M-neighbour slabs (8 GiB at this size) fit the chip only because no
+    per-call copy of them is made."""
+    from repro.core import HNSWEngine
+    small = np.random.default_rng(0).integers(0, 2**32, (300, W),
+                                              dtype=np.uint32)
+    eng = HNSWEngine(small, m=CHEMBL_LIKE.hnsw_m, ef_construction=20,
+                     ef_search=CHEMBL_LIKE.hnsw_ef_search, backend="tpu",
+                     layout=layout)
+    fn, (q, graph) = eng.device_pipeline(small[:64], K)
+    n0 = graph.db_popcount.shape[0]
+
+    def at_capacity(x):                  # node axis n0 -> CAP, lines n0/4
+        shape = list(x.shape)
+        if x is graph.db_lines:
+            shape[0] = CAP // 4
+        elif x is graph.upper_adj:
+            shape[1] = CAP
+        elif x.ndim:
+            assert shape[0] == n0, (shape, n0)
+            shape[0] = CAP
+        return _spec(one_chip, tuple(shape), x.dtype)
+
+    _fits(_compile(fn, _spec(one_chip, q.shape, q.dtype),
+                   jax.tree.map(at_capacity, graph)))

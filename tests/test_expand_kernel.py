@@ -38,7 +38,8 @@ def _run_all(db, nbr, cnt, pop, flat, worst, kk):
     args = (jnp.asarray(db[:pop.shape[0]]), jnp.asarray(nbr),
             jnp.asarray(cnt), jnp.asarray(pop), jnp.asarray(flat),
             jnp.asarray(worst))
-    ks, ki = ops.expand_tanimoto_sorted(args[0], args[1], *args[3:], kk)
+    ks, ki = ops.expand_tanimoto_sorted(args[0], ops.nbr_slabs(args[1]),
+                                        *args[3:], kk)
     rs, ri = ref.expand_sorted_ref(*args, kk)
     q = args[0]
     ts, ti = expand_scores_jnp(q, popcount(q), *args[1:], kk)
@@ -145,13 +146,13 @@ def test_expand_inside_jitted_loop():
     must trace there with loop-carried pop/flat ids."""
     db, nbr, cnt, pop, flat, worst = _case(150, 2, 6, 2, seed=9)
     q = jnp.asarray(db[:2])
-    nbr_j = jnp.asarray(nbr)
+    slabs = ops.nbr_slabs(jnp.asarray(nbr))
     worst_j = jnp.asarray(worst)
 
     def f(pop0, flat0):
         def body(carry):
             i, p, fl, acc = carry
-            s, _ = ops.expand_tanimoto_sorted(q, nbr_j, p, fl, worst_j, 6)
+            s, _ = ops.expand_tanimoto_sorted(q, slabs, p, fl, worst_j, 6)
             acc = acc + jnp.where(jnp.isfinite(s), s, 0.0).sum()
             return i + 1, (p + 1) % 150, fl, acc
 

@@ -23,7 +23,7 @@ def test_gather_matches_oracle(n, q, e, seed):
     ids = rng.integers(0, n, size=(q, e)).astype(np.int32)
     # sprinkle invalid ids (masked/visited/padded neighbours)
     ids[rng.random(ids.shape) < 0.3] = -1
-    got = ops.gather_tanimoto(db[:q], db, jnp.asarray(ids))
+    got = ops.gather_tanimoto(db[:q], ops.db_lines(db), jnp.asarray(ids))
     want = ref.gather_tanimoto_ref(db[:q], db, jnp.asarray(ids))
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-6)
 
@@ -33,7 +33,8 @@ def test_gather_all_invalid_row():
     db = _db(300)
     ids = np.full((2, 6), -1, np.int32)
     ids[1, 0] = 7
-    got = np.asarray(ops.gather_tanimoto(db[:2], db, jnp.asarray(ids)))
+    got = np.asarray(ops.gather_tanimoto(db[:2], ops.db_lines(db),
+                                         jnp.asarray(ids)))
     assert not np.isfinite(got[0]).any()
     assert np.isfinite(got[1, 0]) and not np.isfinite(got[1, 1:]).any()
 
@@ -41,7 +42,8 @@ def test_gather_all_invalid_row():
 def test_gather_self_id_scores_one():
     db = _db(300)
     ids = np.arange(4, dtype=np.int32)[:, None]
-    got = np.asarray(ops.gather_tanimoto(db[:4], db, jnp.asarray(ids)))
+    got = np.asarray(ops.gather_tanimoto(db[:4], ops.db_lines(db),
+                                         jnp.asarray(ids)))
     np.testing.assert_allclose(got[:, 0], 1.0, rtol=1e-6)
 
 
@@ -50,11 +52,12 @@ def test_gather_inside_jitted_loop():
     kernel must trace there (ids are loop-carried traced values)."""
     db = _db(200)
     q = db[:3]
+    lines = ops.db_lines(db)
 
     def f(ids0):
         def body(carry):
             i, ids, acc = carry
-            s = ops.gather_tanimoto(q, db, ids)
+            s = ops.gather_tanimoto(q, lines, ids)
             acc = acc + jnp.where(jnp.isfinite(s), s, 0.0).sum()
             return i + 1, (ids + 1) % 200, acc
 

@@ -14,6 +14,7 @@ from repro.core import BruteForceEngine, BitBoundFoldingEngine, HNSWEngine
 from repro.core import hnsw as hn
 from repro.data.molecules import (SyntheticConfig, queries_from_db,
                                   synthetic_fingerprints)
+from repro.kernels import ops
 
 
 @pytest.fixture(scope="module")
@@ -92,9 +93,9 @@ def test_hnsw_insert_parity(data, backend, layout):
         # identical to a from-scratch to_device_graph densify+upload
         g_inc = eng._graph
         g_new = hn.to_device_graph(eng.index, capacity=g_inc.db.shape[0],
-                                   layout=layout)
+                                   layout=layout, kernel=backend == "tpu")
         for field in ("db", "db_popcount", "base_adj", "upper_adj",
-                      "nbr_fps", "nbr_cnt"):
+                      "nbr_fps", "nbr_cnt", "db_lines", "nbr_slabs"):
             a, b = getattr(g_inc, field), getattr(g_new, field)
             if a is None:
                 assert b is None, field
@@ -205,22 +206,23 @@ def test_hnsw_dirty_log_bounded(data):
 
 def test_hnsw_tpu_insert_scorer_db_cache(data):
     """ISSUE 4 satellite: the tpu insert-frontier scorer appends new rows
-    into a cached capacity-padded device db instead of re-uploading the
-    full database every insert batch."""
+    into a cached capacity-padded device db (in the gather kernel's line
+    layout) instead of re-uploading the full database every insert batch."""
     base, extra, _, q = data
     eng = HNSWEngine(base[:200], m=4, ef_construction=12, ef_search=16,
                      seed=3, backend="tpu")
     assert eng._insert_db_cache is None
     eng.insert(extra[:4])
     dev, filled = eng._insert_db_cache
-    assert filled == 204 and dev.shape[0] >= 204
+    w = base.shape[1]
+    assert filled == 204 and ops.line_ids(w, 203) < dev.shape[0]
     cap0 = dev.shape[0]
     eng.insert(extra[4:8])
     dev2, filled2 = eng._insert_db_cache
     assert filled2 == 208 and dev2.shape[0] == cap0   # appended in place
-    # cached rows are exactly the index's fingerprints
-    np.testing.assert_array_equal(np.asarray(dev2[:208]),
-                                  np.asarray(eng.index.db))
+    # cached lines hold exactly the index's fingerprints, zero past them
+    np.testing.assert_array_equal(
+        np.asarray(dev2), ops.db_lines_at(eng.index.db, np.arange(cap0)))
     reb = HNSWEngine(np.concatenate([base[:200], extra[:8]]), m=4,
                      ef_construction=12, ef_search=16, seed=3, backend="tpu")
     _assert_equal(eng, reb, q, 5, "hnsw/tpu scorer cache")

@@ -5,12 +5,24 @@ import numpy as np
 import pytest
 
 from repro.core import bitbound as bb
+from repro.core.fingerprints import popcount
 from repro.data.molecules import SyntheticConfig, synthetic_fingerprints, queries_from_db
 from repro.kernels import ops, ref
 
 
 def _db(n, seed=0, length=1024):
     return synthetic_fingerprints(SyntheticConfig(n=n, seed=seed, length=length))
+
+
+def _bitbound_topk(queries, db_sorted, counts_sorted, k, cutoff,
+                   max_tiles=None, tile_n=None):
+    """BitBound-windowed fused KNN over a popcount-sorted DB: the Eq.2 rows
+    every engine uses (``bound_range_np``), scanned by the row-window
+    kernel. Ids index the sorted DB; empty slots are id -1 / val -inf."""
+    counts = np.asarray(counts_sorted)
+    lo, hi = bb.bound_range_np(counts, np.asarray(popcount(queries)), cutoff)
+    return ops.window_topk(queries, db_sorted, counts, lo, hi, k,
+                           max_tiles=max_tiles, tile_n=tile_n)
 
 
 @pytest.mark.parametrize("n,q,k,tile", [
@@ -48,8 +60,8 @@ def test_bitbound_kernel_matches_oracle(cutoff, tile):
     db = _db(3000, seed=1)
     qs = jnp.asarray(queries_from_db(db, 4))
     idx = bb.build_index(jnp.asarray(db))
-    ids, vals = ops.bitbound_topk(qs, idx.db, idx.counts, k=15, cutoff=cutoff,
-                                  tile_n=tile)
+    ids, vals = _bitbound_topk(qs, idx.db, idx.counts, k=15, cutoff=cutoff,
+                               tile_n=tile)
     rids, rvals = ref.bitbound_topk_ref(qs, idx.db, idx.counts, k=15,
                                         cutoff=cutoff)
     np.testing.assert_allclose(np.asarray(vals), np.asarray(rvals), rtol=1e-6)
@@ -62,8 +74,8 @@ def test_bitbound_kernel_restricted_window_grid():
     db = _db(4096, seed=2)
     qs = jnp.asarray(queries_from_db(db, 3))
     idx = bb.build_index(jnp.asarray(db))
-    ids, vals = ops.bitbound_topk(qs, idx.db, idx.counts, k=10, cutoff=0.8,
-                                  tile_n=256, max_tiles=8)
+    ids, vals = _bitbound_topk(qs, idx.db, idx.counts, k=10, cutoff=0.8,
+                               tile_n=256, max_tiles=8)
     _, rvals = ref.bitbound_topk_ref(qs, idx.db, idx.counts, k=10, cutoff=0.8)
     np.testing.assert_allclose(np.asarray(vals), np.asarray(rvals), rtol=1e-6)
 
@@ -79,8 +91,8 @@ def test_bitbound_kernel_empty_window():
     q[0, 0] = 0b11    # popcount 2 -> window is popcount {2} only
     assert not (np.asarray(idx.counts) == 2).any()
     qs = jnp.asarray(q)
-    ids, vals = ops.bitbound_topk(qs, idx.db, idx.counts, k=8, cutoff=0.9,
-                                  tile_n=128)
+    ids, vals = _bitbound_topk(qs, idx.db, idx.counts, k=8, cutoff=0.9,
+                               tile_n=128)
     rids, rvals = ref.bitbound_topk_ref(qs, idx.db, idx.counts, k=8,
                                         cutoff=0.9)
     assert (np.asarray(ids) == -1).all()
@@ -102,8 +114,8 @@ def test_bitbound_kernel_window_smaller_than_k():
     from repro.core import pack_bits
     qs = jnp.asarray(pack_bits(q_bits))
     k = int(freq.min()) + 10
-    ids, vals = ops.bitbound_topk(qs, idx.db, idx.counts, k=k, cutoff=0.999,
-                                  tile_n=256)
+    ids, vals = _bitbound_topk(qs, idx.db, idx.counts, k=k, cutoff=0.999,
+                               tile_n=256)
     rids, rvals = ref.bitbound_topk_ref(qs, idx.db, idx.counts, k=k,
                                         cutoff=0.999)
     np.testing.assert_allclose(np.asarray(vals), np.asarray(rvals), rtol=1e-6)
@@ -118,8 +130,8 @@ def test_bitbound_kernel_all_zero_query():
     db[:3] = 0    # make the zero-count window non-empty
     idx = bb.build_index(jnp.asarray(db))
     qs = jnp.zeros((2, db.shape[1]), dtype=jnp.uint32)
-    ids, vals = ops.bitbound_topk(qs, idx.db, idx.counts, k=5, cutoff=0.8,
-                                  tile_n=128)
+    ids, vals = _bitbound_topk(qs, idx.db, idx.counts, k=5, cutoff=0.8,
+                               tile_n=128)
     rids, rvals = ref.bitbound_topk_ref(qs, idx.db, idx.counts, k=5,
                                         cutoff=0.8)
     assert not np.isnan(np.asarray(vals)).any()
@@ -134,8 +146,8 @@ def test_bitbound_kernel_non_tile_aligned_n():
     db = _db(3001, seed=8)
     idx = bb.build_index(jnp.asarray(db))
     qs = jnp.asarray(queries_from_db(db, 4))
-    ids, vals = ops.bitbound_topk(qs, idx.db, idx.counts, k=12, cutoff=0.5,
-                                  tile_n=256)
+    ids, vals = _bitbound_topk(qs, idx.db, idx.counts, k=12, cutoff=0.5,
+                               tile_n=256)
     rids, rvals = ref.bitbound_topk_ref(qs, idx.db, idx.counts, k=12,
                                         cutoff=0.5)
     np.testing.assert_allclose(np.asarray(vals), np.asarray(rvals), rtol=1e-6)
@@ -275,8 +287,8 @@ def test_bitbound_topk_ties_bitwise():
     db = _tied_db(2500, seed=13)
     idx = bb.build_index(jnp.asarray(db))
     qs = jnp.asarray(queries_from_db(db, 10, seed=5))
-    ids, vals = ops.bitbound_topk(qs, idx.db, idx.counts, k=25, cutoff=0.6,
-                                  tile_n=128)
+    ids, vals = _bitbound_topk(qs, idx.db, idx.counts, k=25, cutoff=0.6,
+                               tile_n=128)
     rids, rvals = ref.bitbound_topk_ref(qs, idx.db, idx.counts, k=25,
                                         cutoff=0.6)
     np.testing.assert_array_equal(np.asarray(vals), np.asarray(rvals))
@@ -291,6 +303,43 @@ def test_gather_layout_cases_bitwise():
     ids = rng.integers(-1, 700, (11, 37)).astype(np.int32)
     ids[:, 3] = ids[:, 4]
     ids[0, 0] = 900
-    got = ops.gather_tanimoto(db[:11], db, jnp.asarray(ids))
+    got = ops.gather_tanimoto(db[:11], ops.db_lines(db), jnp.asarray(ids))
     want = ref.gather_tanimoto_ref(db[:11], db, jnp.asarray(ids))
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+@pytest.mark.parametrize("n,w", [(700, 32), (9, 32), (301, 7), (5, 128)])
+def test_line_layouts_hold_each_print_once(n, w):
+    """db_lines/nbr_slabs: each print sits in its own wp-word group, zero
+    padded; numpy and jax inputs give the same lines."""
+    rng = np.random.default_rng(n)
+    db = rng.integers(0, 2**32, (n, w), dtype=np.uint32)
+    lines = ops.db_lines(db)
+    assert isinstance(lines, np.ndarray) and lines.shape[1] == 128
+    wp = 1 << (w - 1).bit_length()          # words per print in a line
+    back = lines.reshape(-1, wp)
+    np.testing.assert_array_equal(back[:n, :w], db)
+    assert not back[:n, w:].any() and not back[n:].any()
+    np.testing.assert_array_equal(np.asarray(ops.db_lines(jnp.asarray(db))),
+                                  lines)
+    nbr = db[rng.integers(0, n, (3, 10))]
+    slabs = ops.nbr_slabs(nbr)
+    assert slabs.shape[0] == 3 and slabs.shape[1] % 8 == 0
+    np.testing.assert_array_equal(slabs.reshape(3, -1, wp)[:, :10, :w], nbr)
+
+
+@pytest.mark.parametrize("backend,platforms,tpu_runtime,want", [
+    ("tpu", None, True, False),
+    ("cpu", "cpu", True, True),          # the tests: CPU asked for
+    ("cpu", None, False, True),          # a host with no TPU runtime
+    ("cpu", None, True, None),           # the TPU failed to start
+    ("cpu", "tpu,cpu", True, None),      # fell back past the TPU
+    ("gpu", None, False, None),
+])
+def test_interpret_only_where_cpu_was_asked_for(backend, platforms,
+                                                tpu_runtime, want):
+    if want is None:
+        with pytest.raises(RuntimeError, match="need a TPU"):
+            ops.interpret_on(backend, platforms, tpu_runtime)
+    else:
+        assert ops.interpret_on(backend, platforms, tpu_runtime) is want

@@ -26,6 +26,7 @@ triple to a score at trace time. This suite pins:
 * variable widths: odd word counts (fp_bits off the 128-lane grid)
   through every engine, and ``fp_bits`` mismatches raising up front.
 """
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -37,6 +38,7 @@ except ImportError:  # collection-safe fallback (see tests/_propcheck.py)
 
 from repro.core import BitBoundFoldingEngine, BruteForceEngine, HNSWEngine
 from repro.core import bitbound as bb
+from repro.core import fingerprints as fp
 from repro.core import hnsw as hn
 from repro.core.fingerprints import (Metric, TANIMOTO, TVERSKY_SCALE,
                                      metric_from_counts,
@@ -89,6 +91,27 @@ def _closed_form(metric, a, b, c):
         den = c + metric.alpha * (a - c) + metric.beta * (b - c)
     with np.errstate(invalid="ignore", divide="ignore"):
         return np.where(den > 0, c / den, 0.0)
+
+
+@pytest.mark.parametrize("ulps", [-2, -1, 1, 2])
+def test_exact_ratio_correction_restores_ieee_quotient(ulps):
+    """``exact_ratio``'s residual step returns the IEEE quotient when the
+    divide it corrects lands ``ulps`` off it, for every pair
+    1 <= num <= den <= 2048 (every Tanimoto and Dice score of 1024-bit
+    prints). The CPU divide is already IEEE, so the step is fed the off
+    quotient directly; bits must match numpy's ``num / den``."""
+    den, num = np.tril_indices(2049)
+    keep = num >= 1
+    num = num[keep].astype(np.float32)
+    den = den[keep].astype(np.float32)
+    want = num / den
+    off = want
+    for _ in range(abs(ulps)):
+        off = np.nextafter(off, np.float32(np.copysign(np.inf, ulps)))
+    assert (off != want).all()
+    got = jax.jit(fp._correct)(off, num, den)
+    np.testing.assert_array_equal(np.asarray(got).view(np.uint32),
+                                  want.view(np.uint32))
 
 
 def _assert_results_match_oracle(metric, ids, vals, oracle, k, cutoff=None):
