@@ -1,14 +1,13 @@
-"""CI gate for the observability artifacts (ISSUE 8 satellite).
+"""CI gate for the metrics registry's export (ISSUE 8 satellite).
 
-Thin CLI over :mod:`repro.obs.schema`: validates a Chrome trace-event JSON
-export and/or a metrics-registry JSONL export, printing every schema error
-and exiting non-zero if any artifact fails. CI runs it right after the
-traced `search_serve` smoke so a silently-broken exporter (missing family,
-malformed bucket counts, span that stopped firing) fails the build instead
-of shipping an empty dashboard.
+Thin CLI over :mod:`repro.obs.schema`: validates a metrics-registry JSONL
+export, printing every schema error and exiting non-zero if it fails. CI
+runs it right after the `search_serve` smoke so a silently-broken exporter
+(missing family, malformed bucket counts) fails the build instead of
+shipping an empty dashboard. Spans are the profiler's trace and need no
+check here.
 
     PYTHONPATH=src python benchmarks/check_obs_schema.py \
-        --trace /tmp/trace.json --require-span tier.device_put \
         --metrics /tmp/metrics.jsonl
 """
 from __future__ import annotations
@@ -17,19 +16,14 @@ import argparse
 import sys
 
 from repro.obs.schema import (FRONTEND_METRIC_FAMILIES,
-                              REQUIRED_METRIC_FAMILIES, validate_trace_file,
+                              REQUIRED_METRIC_FAMILIES,
                               validate_metrics_jsonl)
 
 
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__)
-    ap.add_argument("--trace", default=None,
-                    help="Chrome trace-event JSON to validate")
-    ap.add_argument("--metrics", default=None,
+    ap.add_argument("--metrics", required=True,
                     help="metrics-registry JSONL export to validate")
-    ap.add_argument("--require-span", action="append", default=[],
-                    help="span name that must appear in the trace "
-                         "(repeatable), e.g. tier.device_put")
     ap.add_argument("--require-family", action="append", default=None,
                     help="metric family that must appear in the JSONL "
                          "(repeatable; default: the serving floor "
@@ -39,28 +33,16 @@ def main(argv=None) -> int:
                          "families plus "
                          f"{', '.join(FRONTEND_METRIC_FAMILIES)}")
     args = ap.parse_args(argv)
-    if not args.trace and not args.metrics:
-        ap.error("nothing to validate: pass --trace and/or --metrics")
-
-    errors: list[str] = []
-    if args.trace:
-        errs = validate_trace_file(args.trace,
-                                   require_spans=tuple(args.require_span))
-        errors += [f"[trace] {e}" for e in errs]
-        print(f"[check-obs] trace {args.trace}: "
-              f"{'OK' if not errs else f'{len(errs)} error(s)'}")
-    if args.metrics:
-        fams = (tuple(args.require_family)
-                if args.require_family is not None else None)
-        if args.require_frontend:
-            fams = (REQUIRED_METRIC_FAMILIES + FRONTEND_METRIC_FAMILIES
-                    + (fams or ()))
-        errs = validate_metrics_jsonl(args.metrics, require_families=fams)
-        errors += [f"[metrics] {e}" for e in errs]
-        print(f"[check-obs] metrics {args.metrics}: "
-              f"{'OK' if not errs else f'{len(errs)} error(s)'}")
+    fams = (tuple(args.require_family)
+            if args.require_family is not None else None)
+    if args.require_frontend:
+        fams = (REQUIRED_METRIC_FAMILIES + FRONTEND_METRIC_FAMILIES
+                + (fams or ()))
+    errors = validate_metrics_jsonl(args.metrics, require_families=fams)
+    print(f"[check-obs] metrics {args.metrics}: "
+          f"{'OK' if not errors else f'{len(errors)} error(s)'}")
     for e in errors:
-        print(f"[check-obs] {e}", file=sys.stderr)
+        print(f"[check-obs] [metrics] {e}", file=sys.stderr)
     return 1 if errors else 0
 
 

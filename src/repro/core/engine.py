@@ -54,6 +54,14 @@ engines; engines whose per-query work is input-independent compute it in
 closed form. Per-batch traversal telemetry beyond that single number lives
 in the engine's ``stats`` dict (see :attr:`HNSWEngine.stats`).
 
+Spans (``obs/trace.py``): the exhaustive engines split a device-path
+batch into ``engine.plan`` (host work before the launch), ``engine.dispatch``
+(the launch of the jitted pipeline, which returns before the device is
+done) and ``engine.fetch`` (every device->host read of the batch: ids,
+scores and the ``scanned`` count, in one ``jax.device_get``);
+``engine.sync`` is the store->device refresh after inserts or a
+compaction. No span waits on the device.
+
 The engine x backend x layout matrix is summarised in README.md; data
 layouts and the request path are documented in docs/ARCHITECTURE.md.
 """
@@ -309,6 +317,13 @@ class BruteForceEngine(SearchEngine):
 
     def _sync(self) -> None:
         st = self.store
+        if (self._sync_gen == st.generation
+                and self._sync_delta == st.delta_version):
+            return
+        with _TR.span("engine.sync", n_delta=int(st.n_delta)):
+            self._refresh(st)
+
+    def _refresh(self, st) -> None:
         if self._sync_gen != st.generation:
             self._sync_gen = st.generation
             if self.residency == "tiered":
@@ -416,35 +431,26 @@ class BruteForceEngine(SearchEngine):
         nq = q.shape[0]
         run_vals = jnp.full((nq, k), -jnp.inf, jnp.float32)
         run_ids = jnp.full((nq, k), -1, jnp.int32)
-        # tracing (ISSUE 8): each host->HBM transfer is a flow span on the
-        # "h2d-stream" track from dispatch to observed-ready; with tracing
-        # on, each chunk's scan additionally syncs so its span carries real
-        # device time — chunk i's scan span then visibly overlaps chunk
-        # i+1's device_put span in Perfetto (dispatched before the scan).
-        traced = _TR.enabled
+        # spans: each host->HBM transfer is a "tier.device_put" span from
+        # its dispatch to the moment the scan needs it; chunk i+1's opens
+        # before chunk i's scan is launched. The scan's device time is in
+        # the device trace; its span times the launch.
         t0 = time.perf_counter()
         stall = 0.0
-        put_h = _TR.begin("tier.device_put", track="h2d-stream", chunk=0)
+        put_h = _TR.begin("tier.device_put", chunk=0)
         staged = jax.device_put(db_np[:r])
         for c in range(n_chunks):
             cur, cur_h = staged, put_h
             if c + 1 < n_chunks:
-                put_h = _TR.begin("tier.device_put", track="h2d-stream",
-                                  chunk=c + 1)
+                put_h = _TR.begin("tier.device_put", chunk=c + 1)
                 staged = jax.device_put(db_np[(c + 1) * r:(c + 2) * r])
-            ts = time.perf_counter()
-            jax.block_until_ready(cur)
-            te = time.perf_counter()
+            with _TR.span("tier.stall", chunk=c):
+                ts = time.perf_counter()
+                jax.block_until_ready(cur)
+                te = time.perf_counter()
             stall += te - ts
             cur_h.end()
-            if traced:
-                _TR.emit("tier.stall", ts, te, chunk=c)
-                with _TR.span("tier.scan_chunk", chunk=c, rows=r):
-                    ids_c, vals_c = sfn(q, cur)
-                    run_vals, run_ids = mfn(run_vals, run_ids, vals_c, ids_c,
-                                            jnp.int32(c * r))
-                    jax.block_until_ready(run_vals)  # tracing-only sync
-            else:
+            with _TR.span("tier.scan_chunk", chunk=c, rows=r):
                 ids_c, vals_c = sfn(q, cur)
                 run_vals, run_ids = mfn(run_vals, run_ids, vals_c, ids_c,
                                         jnp.int32(c * r))
@@ -466,24 +472,29 @@ class BruteForceEngine(SearchEngine):
         return fn, (jnp.asarray(queries), self.db, self.db_cnt)
 
     def search(self, queries, k: int):
-        self._sync()
-        q = jnp.asarray(queries)
-        if self.residency == "tiered":
-            ids, vals = self._tiered_main_topk(q, k)
-        else:
-            fn, args = self.device_pipeline(q, k)
-            ids, vals = fn(*args)
-        if self._delta_dev is not None:
-            ddb, dcnt, bucket = self._delta_dev
-            dfn = self._cached(("delta", int(k), bucket),
-                               self._delta_builder(k, bucket))
-            dids, dvals = dfn(q, ddb, dcnt, jnp.int32(self.store.n_delta))
-            gids = jnp.where(dids >= 0,
-                             dids + jnp.int32(self.store.n_main), -1)
-            vals, ids = _merge_main_delta(vals, ids.astype(jnp.int32),
-                                          dvals, gids.astype(jnp.int32),
-                                          jnp.int32(self.store.n_main))
-        return np.asarray(ids), np.asarray(vals)
+        with _TR.span("engine.plan"):
+            self._sync()
+            q = jnp.asarray(queries)
+        dd = self._delta_dev
+        with _TR.span("engine.dispatch", delta_bucket=dd[2] if dd else 0):
+            if self.residency == "tiered":
+                ids, vals = self._tiered_main_topk(q, k)
+            else:
+                fn, args = self.device_pipeline(q, k)
+                ids, vals = fn(*args)
+            if dd is not None:
+                ddb, dcnt, bucket = dd
+                dfn = self._cached(("delta", int(k), bucket),
+                                   self._delta_builder(k, bucket))
+                dids, dvals = dfn(q, ddb, dcnt,
+                                  jnp.int32(self.store.n_delta))
+                gids = jnp.where(dids >= 0,
+                                 dids + jnp.int32(self.store.n_main), -1)
+                vals, ids = _merge_main_delta(vals, ids.astype(jnp.int32),
+                                              dvals, gids.astype(jnp.int32),
+                                              jnp.int32(self.store.n_main))
+        with _TR.span("engine.fetch"):
+            return jax.device_get((ids, vals))
 
     def _apply_insert(self, fps):
         return self.store.insert(fps)   # compaction handled by the store
@@ -606,6 +617,13 @@ class BitBoundFoldingEngine(SearchEngine):
 
     def _sync(self) -> None:
         st = self.store
+        if (self._sync_gen == st.generation
+                and self._sync_delta == st.delta_version):
+            return
+        with _TR.span("engine.sync", n_delta=int(st.n_delta)):
+            self._refresh(st)
+
+    def _refresh(self, st) -> None:
         if self._sync_gen != st.generation:
             self._sync_gen = st.generation
             if self.residency == "tiered":
@@ -653,8 +671,11 @@ class BitBoundFoldingEngine(SearchEngine):
     def search(self, queries, k: int):
         """Top-k per query via the configured backend -> (ids, sims) numpy."""
         if self.backend in ("jnp", "tpu"):
-            ids, sims, _ = self.search_tpu(queries, k)
-            return np.asarray(ids), np.asarray(sims)
+            ids, sims, scanned = self.search_tpu(queries, k)
+            with _TR.span("engine.fetch"):
+                ids, sims, scanned = jax.device_get((ids, sims, scanned))
+            self._record_batch(scanned, len(ids))
+            return ids, sims
         return self.search_numpy(queries, k)
 
     def _apply_insert(self, fps):
@@ -1001,9 +1022,9 @@ class BitBoundFoldingEngine(SearchEngine):
         wait on the staged buffer is timed as the stall telemetry."""
         sm = _store_mod()
         nq = queries.shape[0]
-        safe_np = np.asarray(safe_m)
-        gids_np = np.asarray(gids)
-        valid_np = np.asarray(valid)
+        with _TR.span("engine.fetch", what="candidates"):
+            safe_np, gids_np, valid_np, is_d_np, d_slot_np = jax.device_get(
+                (safe_m, gids, valid, is_d, d_slot))
         n_cand = safe_np.shape[1]
         C = max(1, min(sm.next_pow2(max(self.tier_chunk, 1)),
                        sm.next_pow2(n_cand)))
@@ -1011,8 +1032,6 @@ class BitBoundFoldingEngine(SearchEngine):
         rfn = self._cached(("tierrescore", int(k), C),
                            lambda: self._tier_rescore_builder(k, C))
         if is_d is not None:
-            is_d_np = np.asarray(is_d)
-            d_slot_np = np.asarray(d_slot)
             d_db = self.store.delta_db
             nd = max(d_db.shape[0], 1)
 
@@ -1038,39 +1057,29 @@ class BitBoundFoldingEngine(SearchEngine):
 
         run_vals = jnp.full((nq, k), -jnp.inf, jnp.float32)
         run_ids = jnp.full((nq, k), -1, jnp.int32)
-        # tracing (ISSUE 8): same span scheme as the brute tiered scan —
-        # "tier.device_put" flow spans on the h2d-stream track (dispatch ->
-        # observed-ready), per-chunk "tier.host_gather" / "tier.rescore"
-        # stack spans, with a tracing-only sync so rescore spans carry real
-        # device time and chunk i+1's transfer visibly overlaps chunk i.
-        traced = _TR.enabled
+        # spans: the brute tiered scan's scheme, with a "tier.host_gather"
+        # span per chunk gathered on the host
         stall = 0.0
         t_all = time.perf_counter()
-        put_h = _TR.begin("tier.device_put", track="h2d-stream", chunk=0)
+        put_h = _TR.begin("tier.device_put", chunk=0)
         with _TR.span("tier.host_gather", chunk=0):
             first = host_chunk(0)
         staged = jax.device_put(first)
         for c in range(n_chunks):
             cur, cur_h = staged, put_h
             if c + 1 < n_chunks:
-                put_h = _TR.begin("tier.device_put", track="h2d-stream",
-                                  chunk=c + 1)
+                put_h = _TR.begin("tier.device_put", chunk=c + 1)
                 with _TR.span("tier.host_gather", chunk=c + 1):
                     nxt = host_chunk(c + 1)
                 staged = jax.device_put(nxt)
-            ts = time.perf_counter()
-            jax.block_until_ready(cur)
-            te = time.perf_counter()
+            with _TR.span("tier.stall", chunk=c):
+                ts = time.perf_counter()
+                jax.block_until_ready(cur)
+                te = time.perf_counter()
             stall += te - ts
             cur_h.end()
             rows_c, v_c, g_c = cur
-            if traced:
-                _TR.emit("tier.stall", ts, te, chunk=c)
-                with _TR.span("tier.rescore", chunk=c, cols=C):
-                    run_vals, run_ids = rfn(queries, rows_c, v_c, g_c,
-                                            run_vals, run_ids)
-                    jax.block_until_ready(run_vals)  # tracing-only sync
-            else:
+            with _TR.span("tier.rescore", chunk=c, cols=C):
                 run_vals, run_ids = rfn(queries, rows_c, v_c, g_c,
                                         run_vals, run_ids)
         jax.block_until_ready(run_vals)
@@ -1082,8 +1091,8 @@ class BitBoundFoldingEngine(SearchEngine):
             * (4 * words + 5),
             tiered_stall_s=stall, tiered_scan_s=total,
             tiered_stall_fraction=(stall / total) if total > 0 else 0.0)
-        vals_np = np.asarray(run_vals)
-        ids_np = np.asarray(run_ids)
+        with _TR.span("engine.fetch", what="results"):
+            vals_np, ids_np = jax.device_get((run_vals, run_ids))
         ok = np.isfinite(vals_np)
         ids = np.where(ok, ids_np, -1)
         sims = np.where(ok, vals_np, 0.0).astype(np.float32)
@@ -1120,9 +1129,8 @@ class BitBoundFoldingEngine(SearchEngine):
     def device_pipeline(self, queries, k: int):
         """The jitted device-resident pipeline for this batch and its
         arguments: ``fn(*args) -> (ids, sims, scanned)`` device arrays."""
-        queries = jnp.asarray(queries)
         plan = self._batch_plan(np.asarray(queries))
-        return self._resident_call(queries, k, *plan)
+        return self._resident_call(jnp.asarray(queries), k, *plan)
 
     def _resident_call(self, queries, k, lo, hi, bucket, delta_bucket,
                        ok_np):
@@ -1139,31 +1147,35 @@ class BitBoundFoldingEngine(SearchEngine):
         return fn, args
 
     def search_tpu(self, queries, k: int):
-        """Fixed-shape device path -> ``(ids, sims, scanned)`` jax arrays.
+        """Fixed-shape device path -> ``(ids, sims, scanned)``: jax arrays
+        on the device-resident path (nothing read back; :meth:`search`
+        fetches them), host values on the tiered one.
 
         Host work is only window metadata (two searchsorteds + the delta
         popcount mask per batch and the power-of-two grid bucket); the folded
         scans, merge, gather, rescore and top-k all run inside one jitted
-        function per ``(bucket, k, delta bucket, capacity)``.
+        function per ``(bucket, k, delta bucket, capacity)``. ``queries``
+        are read on the host for the plan, so pass them as numpy.
         """
-        queries = jnp.asarray(queries)
         q_np = np.asarray(queries)
-        lo, hi, bucket, delta_bucket, ok_np = self._batch_plan(q_np)
+        with _TR.span("engine.plan"):
+            lo, hi, bucket, delta_bucket, ok_np = self._batch_plan(q_np)
         # m == 1 never gathers full-resolution rows (folded == full, stage-1
         # scores are already exact), so the device pipeline serves tiered
         # mode as-is with `self.full is None` — the traced function simply
         # never touches that argument.
         if self.residency == "tiered" and self.m > 1:
             dd = self._delta_dev
-            lo_j = jnp.asarray(lo, jnp.int32)
-            hi_j = jnp.asarray(hi, jnp.int32)
             cfn = self._cached(
                 ("tiercand", bucket, int(k), delta_bucket,
                  self._device_meta()["capacity"]),
                 lambda: self._build_tiered_candidates(bucket, k,
                                                       delta_bucket))
-            with _TR.span("bitbound.stage1", bucket=int(bucket),
-                          tiered=True):
+            with _TR.span("engine.dispatch", bucket=int(bucket),
+                          delta_bucket=int(delta_bucket), tiered=True):
+                queries = jnp.asarray(q_np)
+                lo_j = jnp.asarray(lo, jnp.int32)
+                hi_j = jnp.asarray(hi, jnp.int32)
                 if dd is None:
                     safe_m, gids, valid = cfn(queries, lo_j, hi_j,
                                               self.folded, self.folded_cnt,
@@ -1180,15 +1192,12 @@ class BitBoundFoldingEngine(SearchEngine):
             ids, sims = self._tiered_rescore(queries, k, safe_m, gids,
                                              valid, is_d, d_slot)
             scanned = int(np.maximum(hi - lo, 0).sum()) + extra
-            self._record_batch(scanned, q_np.shape[0])
             return ids, sims, scanned
-        fn, args = self._resident_call(queries, k, lo, hi, bucket,
-                                       delta_bucket, ok_np)
-        with _TR.span("bitbound.pipeline", bucket=int(bucket),
+        with _TR.span("engine.dispatch", bucket=int(bucket),
                       delta_bucket=int(delta_bucket)):
-            ids, sims, scanned = fn(*args)
-        self._record_batch(scanned, queries.shape[0])
-        return ids, sims, scanned
+            fn, args = self._resident_call(jnp.asarray(q_np), k, lo, hi,
+                                           bucket, delta_bucket, ok_np)
+            return fn(*args)
 
 
 @dataclass

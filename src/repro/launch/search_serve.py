@@ -12,11 +12,17 @@ Two serving shapes:
   per-request latency / QPS / compaction telemetry. This is the paper's
   FPGA host loop (stream queries, append compounds without stalling the
   scan) mapped onto the TPU engines.
+
+``--trace-out DIR`` runs either shape under a ``jax.profiler`` session:
+the program's spans and the device's operations on one timeline, as
+``DIR/plugins/profile/<run>/*.xplane.pb`` plus a ``perfetto_trace.json.gz``
+that opens in https://ui.perfetto.dev.
 """
 from __future__ import annotations
 
 import argparse
 import time
+from contextlib import nullcontext
 
 import jax
 import jax.numpy as jnp
@@ -146,8 +152,7 @@ def serve_frontend(engines=("brute", "bitbound-folding"), n_db: int = 20_000,
                    replicas: int = 2, durable_dir: str | None = None,
                    snapshot_every: int = 0, resume: bool = False,
                    metric: str | None = None, fp_bits: int | None = None,
-                   metrics_out: str | None = None,
-                   trace_out: str | None = None, log=print):
+                   metrics_out: str | None = None, log=print):
     """Drive the concurrent serving tier (ISSUE 9): the same mixed
     insert+query workload as :func:`serve_service`, but through a
     :class:`repro.serve.frontend.SearchFrontend` — bounded admission,
@@ -156,12 +161,8 @@ def serve_frontend(engines=("brute", "bitbound-folding"), n_db: int = 20_000,
     durable (the on-disk layout matches the single service, so either can
     recover the other's directory); ``resume`` warm-restarts every replica
     from the latest snapshot + WAL tail. Returns the frontend summary."""
-    from ..obs.trace import TRACER
     from ..serve.frontend import FrontendConfig, SearchFrontend
 
-    if trace_out:
-        TRACER.clear()
-        TRACER.configure(enabled=True)
     length = int(fp_bits) if fp_bits else 1024
     db = synthetic_fingerprints(SyntheticConfig(n=n_db, length=length))
     pool = synthetic_fingerprints(
@@ -224,12 +225,6 @@ def serve_frontend(engines=("brute", "bitbound-folding"), n_db: int = 20_000,
         log(f"[search-serve] metrics -> {metrics_out} "
             f"(+ {metrics_out}.prom)")
     fe.close()
-    if trace_out:
-        TRACER.export_chrome(trace_out)
-        log(f"[search-serve] trace -> {trace_out} "
-            f"({len(TRACER.events)} events; open in "
-            f"https://ui.perfetto.dev)")
-        TRACER.configure(enabled=False)
     return s
 
 
@@ -244,7 +239,6 @@ def serve_service(engines=("brute", "bitbound-folding"), n_db: int = 20_000,
                   tier_chunk: int | None = None,
                   metric: str | None = None, fp_bits: int | None = None,
                   metrics_out: str | None = None,
-                  trace_out: str | None = None,
                   log=print):
     """Drive a :class:`SearchService` with a mixed insert+query workload and
     report the serving telemetry. Returns the service summary dict.
@@ -257,18 +251,11 @@ def serve_service(engines=("brute", "bitbound-folding"), n_db: int = 20_000,
     synthetic database (EXPERIMENTS.md §Durability runbook).
 
     ``metrics_out`` exports the service metrics registry as JSONL (plus a
-    Prometheus text twin at ``<path>.prom``); ``trace_out`` enables the
-    process-wide span tracer and writes Chrome trace-event JSON — open it in
-    Perfetto to see queue wait, batch formation, per-engine search, tiered
-    double-buffer chunk streams and WAL fsyncs (EXPERIMENTS.md
-    §Observability runbook). ``tier_chunk_rows`` / ``tier_chunk`` shrink the
-    tiered streaming chunks to force multi-chunk captures."""
-    from ..obs.trace import TRACER
+    Prometheus text twin at ``<path>.prom``). ``tier_chunk_rows`` /
+    ``tier_chunk`` shrink the tiered streaming chunks to force multi-chunk
+    captures (EXPERIMENTS.md §Observability runbook)."""
     from ..serve.service import SearchService
 
-    if trace_out:
-        TRACER.clear()
-        TRACER.configure(enabled=True)
     length = int(fp_bits) if fp_bits else 1024
     db = synthetic_fingerprints(SyntheticConfig(n=n_db, length=length))
     pool = synthetic_fingerprints(
@@ -343,12 +330,6 @@ def serve_service(engines=("brute", "bitbound-folding"), n_db: int = 20_000,
             f.write(svc.metrics.render_prometheus())
         log(f"[search-serve] metrics -> {metrics_out} "
             f"(+ {metrics_out}.prom)")
-    if trace_out:
-        TRACER.export_chrome(trace_out)
-        log(f"[search-serve] trace -> {trace_out} "
-            f"({len(TRACER.events)} events, {TRACER.dropped_events} dropped;"
-            f" open in https://ui.perfetto.dev)")
-        TRACER.configure(enabled=False)
     return s
 
 
@@ -420,10 +401,21 @@ def main():
                     help="service mode: export the metrics registry as JSONL "
                          "here (a Prometheus text twin lands at <path>.prom)")
     ap.add_argument("--trace-out", default=None,
-                    help="service mode: enable span tracing and write Chrome "
-                         "trace-event JSON here (view in Perfetto)")
+                    help="run under a jax.profiler session writing to this "
+                         "directory: program spans and device operations, "
+                         "with a Perfetto file (ui.perfetto.dev)")
     args = ap.parse_args()
     use_compile_cache()
+    from ..obs.trace import session
+    with (session(args.trace_out, perfetto=True) if args.trace_out
+          else nullcontext()):
+        _run(args)
+    if args.trace_out:
+        print(f"[search-serve] trace -> {args.trace_out} (open "
+              f"perfetto_trace.json.gz in https://ui.perfetto.dev)")
+
+
+def _run(args) -> None:
     if args.engine == "service" and args.replicas > 1:
         serve_frontend(engines=tuple(args.service_engines.split(",")),
                        n_db=args.n_db, k=args.k, n_ops=args.ops,
@@ -434,8 +426,7 @@ def main():
                        snapshot_every=args.snapshot_every,
                        resume=args.resume,
                        metric=args.metric, fp_bits=args.fp_bits,
-                       metrics_out=args.metrics_out,
-                       trace_out=args.trace_out)
+                       metrics_out=args.metrics_out)
     elif args.engine == "service":
         serve_service(engines=tuple(args.service_engines.split(",")),
                       n_db=args.n_db, k=args.k, n_ops=args.ops,
@@ -448,8 +439,7 @@ def main():
                       tier_chunk_rows=args.tier_chunk_rows,
                       tier_chunk=args.tier_chunk,
                       metric=args.metric, fp_bits=args.fp_bits,
-                      metrics_out=args.metrics_out,
-                      trace_out=args.trace_out)
+                      metrics_out=args.metrics_out)
     else:
         serve(args.engine, n_db=args.n_db, k=args.k,
               n_queries=args.n_queries, use_kernel=args.use_kernel,

@@ -4,15 +4,16 @@
   gauges / log-bucketed histograms with Prometheus text exposition and
   JSONL export (:class:`MetricsRegistry`; ``NULL_METRICS`` no-op twin).
 * :mod:`repro.obs.trace` — process-wide span tracer (:data:`TRACER`)
-  exporting Chrome trace-event JSON viewable in Perfetto; disabled spans
-  are allocation-free singletons.
-* :mod:`repro.obs.schema` — validators for both artifact formats
+  recording ``jax.profiler`` annotations, so program spans share the
+  device trace's clock; with no profiler session a span is a shared
+  do-nothing singleton.
+* :mod:`repro.obs.schema` — validator of the registry's JSONL export
   (CLI: ``benchmarks/check_obs_schema.py``).
 
-This package deliberately imports nothing from the rest of ``repro`` so
-every layer (kernels, engines, serving, WAL) can instrument itself without
-import cycles. docs/ARCHITECTURE.md §Observability has the span taxonomy
-and overhead budget.
+This package deliberately imports nothing from the rest of ``repro``, and
+jax only on first use, so every layer (kernels, engines, serving, WAL) can
+instrument itself without import cycles. docs/ARCHITECTURE.md
+§Observability has the span taxonomy and overhead budget.
 """
 from .metrics import MetricsRegistry, NullMetrics, NULL_METRICS  # noqa: F401
 from .trace import Tracer, TRACER, NULL_SPAN, NULL_HANDLE  # noqa: F401

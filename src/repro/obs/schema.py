@@ -1,14 +1,11 @@
-"""Schema validation for the observability artifacts (ISSUE 8 satellite).
+"""Schema validation for the metrics registry's JSONL export.
 
-Two validators, both returning a (possibly empty) list of human-readable
-error strings — empty means valid:
-
-* :func:`validate_trace` — Chrome trace-event JSON as exported by
-  :meth:`repro.obs.trace.Tracer.export_chrome` (and accepted by Perfetto).
-* :func:`validate_metrics_jsonl` — the registry's JSONL export, including
-  the required-family floor (:data:`REQUIRED_METRIC_FAMILIES`): a serving
-  run that silently stopped exporting request latencies must fail CI, not
-  produce an empty dashboard.
+:func:`validate_metrics_jsonl` returns a (possibly empty) list of
+human-readable error strings — empty means valid — and enforces the
+required-family floor (:data:`REQUIRED_METRIC_FAMILIES`): a serving run
+that silently stopped exporting request latencies must fail CI, not
+produce an empty dashboard. Spans need no schema of their own: they are
+the profiler's trace (``repro.obs.trace``).
 
 ``benchmarks/check_obs_schema.py`` is the CLI wrapper CI runs.
 """
@@ -37,65 +34,6 @@ FRONTEND_METRIC_FAMILIES = (
     "frontend_replica_live",
     "frontend_request_latency_ms",
 )
-
-#: Chrome trace-event phases we emit / accept
-TRACE_PHASES = {"X", "M", "B", "E", "b", "e", "i", "C"}
-
-
-def validate_trace(obj, *, require_spans: tuple = ()) -> list[str]:
-    """Validate a parsed Chrome trace (dict with ``traceEvents`` or a bare
-    event list). ``require_spans`` additionally demands at least one "X"
-    event per named span (e.g. ``("tier.device_put",)`` for the tiered
-    double-buffer capture)."""
-    errors: list[str] = []
-    if isinstance(obj, dict):
-        events = obj.get("traceEvents")
-        if not isinstance(events, list):
-            return ["top-level dict has no traceEvents list"]
-    elif isinstance(obj, list):
-        events = obj
-    else:
-        return [f"trace must be a dict or list, got {type(obj).__name__}"]
-    if not events:
-        errors.append("trace has no events")
-    seen = set()
-    for i, ev in enumerate(events):
-        where = f"event[{i}]"
-        if not isinstance(ev, dict):
-            errors.append(f"{where}: not an object")
-            continue
-        for key in ("name", "ph", "pid", "tid", "ts"):
-            if key not in ev:
-                errors.append(f"{where}: missing {key!r}")
-        name, ph = ev.get("name"), ev.get("ph")
-        if not isinstance(name, str) or not name:
-            errors.append(f"{where}: name must be a non-empty string")
-        if ph not in TRACE_PHASES:
-            errors.append(f"{where}: unknown phase {ph!r}")
-        for key in ("ts", "dur"):
-            if key in ev and not isinstance(ev[key], (int, float)):
-                errors.append(f"{where}: {key} not numeric")
-        if ph == "X":
-            if "dur" not in ev:
-                errors.append(f"{where}: complete event missing dur")
-            elif isinstance(ev["dur"], (int, float)) and ev["dur"] < 0:
-                errors.append(f"{where}: negative dur")
-            seen.add(name)
-            parent = (ev.get("args") or {}).get("parent")
-            if parent is not None and not isinstance(parent, str):
-                errors.append(f"{where}: args.parent not a string")
-    for name in require_spans:
-        if name not in seen:
-            errors.append(f"required span {name!r} not present in trace")
-    return errors
-
-
-def validate_trace_file(path, *, require_spans: tuple = ()) -> list[str]:
-    try:
-        obj = json.loads(Path(path).read_text())
-    except (OSError, json.JSONDecodeError) as e:
-        return [f"{path}: unreadable trace JSON ({e})"]
-    return validate_trace(obj, require_spans=require_spans)
 
 
 def _validate_metric_row(row: dict, where: str) -> list[str]:

@@ -1,48 +1,51 @@
-"""Structured trace spans -> Chrome trace-event JSON (ISSUE 8 tentpole).
+"""Program spans on the JAX profiler's clock.
 
-One process-wide :data:`TRACER` instruments the request path (service queue
-wait, batch formation, per-engine flush, stage-1 scans, tiered double-buffer
-chunk streams, HNSW traversals, WAL fsyncs, snapshot writes). Spans are
-recorded as Chrome trace-event **complete** events (``"ph": "X"``) and the
-export opens directly in Perfetto (https://ui.perfetto.dev) or
-``chrome://tracing``.
+One process-wide :data:`TRACER` names the work of each layer of the served
+path (front end, replica, service, engine, WAL, snapshots) as
+``jax.profiler.TraceAnnotation`` spans. A span is recorded when, and only
+when, a profiler session is on (``jax.profiler.start_trace`` or
+:func:`session`): it lands on the ``/host:`` plane of the same trace that
+holds the device's operations, on one clock, so a stretch in which the
+device ran nothing can be laid against the host code that held it. There
+is no recorder, buffer, clock or exporter here; the session is the only
+switch.
 
-Three recording shapes:
+Two recording shapes:
 
-* ``with TRACER.span(name, **args):`` — stack span on the calling thread's
-  track; nesting renders automatically (ts/dur containment) and the parent
-  span's name is linked in ``args.parent``. ``span.set(**kv)`` merges
-  result args (e.g. traversal stats) before the span closes.
-* ``h = TRACER.begin(name, track=...); ...; h.end(**kv)`` — **flow** span
-  with explicit lifetime on a named synthetic track, for work whose end is
-  observed later than (and on a different logical timeline from) the code
-  that started it — the tiered double-buffer's host->HBM ``device_put``
-  transfers land here, so chunk i+1's transfer visibly overlaps chunk i's
-  rescore span in Perfetto.
-* ``TRACER.emit(name, t0, t1, **args)`` — after-the-fact span from two
-  ``time.perf_counter()`` readings (queue-wait attribution).
+* ``with TRACER.span(name, **args) as s:`` — a span on the calling thread;
+  ``s.set(**kv)`` adds metadata before it closes.
+* ``h = TRACER.begin(name, **args); ...; h.end(**kv)`` — a span whose end
+  is observed later than its start and need not nest with the spans opened
+  in between (the tiered path's host->HBM transfers). Opened and closed on
+  one thread.
 
-**Disabled cost is the design constraint**: ``span()`` / ``begin()`` return
-the module-level ``NULL_SPAN`` / ``NULL_HANDLE`` singletons when tracing is
-off — no span object is allocated, no clock is read, nothing is appended
-(pinned by ``tests/test_obs.py::test_disabled_span_fast_path``). Hot loops
-may additionally gate arg construction on ``TRACER.enabled``.
+Metadata (a request's ``rid``, a dispatched ``batch``, bucket sizes) is
+attached only while a session is on; a span that covers several requests
+carries their ids space-separated, so one request's spans share its id.
+With no session, ``span()`` and ``begin()`` return shared do-nothing
+singletons (one ``is_enabled()`` read, no allocation), and call sites that
+build costly arguments gate them on :attr:`Tracer.enabled`. A span never
+waits on the device.
 
-The event buffer is bounded (``max_events``, drops counted in
-``dropped_events``) so a forgotten-enabled tracer cannot grow without
-bound.
+jax is imported on first use, so importing this module costs nothing.
 """
 from __future__ import annotations
 
-import json
-import os
-import threading
-import time
-from pathlib import Path
+from contextlib import contextmanager
+
+_ANNOTATION = None
+
+
+def _annotation():
+    global _ANNOTATION
+    if _ANNOTATION is None:
+        from jax.profiler import TraceAnnotation
+        _ANNOTATION = TraceAnnotation
+    return _ANNOTATION
 
 
 class _NullSpan:
-    """Shared do-nothing span: the disabled-tracing fast path."""
+    """Shared do-nothing span: the no-session fast path."""
     __slots__ = ()
 
     def __enter__(self):
@@ -53,169 +56,80 @@ class _NullSpan:
 
     def set(self, **kv):
         return self
-
-
-class _NullHandle:
-    __slots__ = ()
 
     def end(self, **kv):
         pass
 
 
 NULL_SPAN = _NullSpan()
-NULL_HANDLE = _NullHandle()
+#: what ``begin()`` returns with no session: the same do-nothing span
+NULL_HANDLE = NULL_SPAN
 
 
 class _Span:
-    __slots__ = ("_tr", "name", "args", "_t0")
+    __slots__ = ("_ann",)
 
-    def __init__(self, tr, name, args):
-        self._tr = tr
-        self.name = name
-        self.args = args
-
-    def set(self, **kv):
-        self.args.update(kv)
-        return self
+    def __init__(self, name: str, args: dict):
+        self._ann = _annotation()(name, **args)
 
     def __enter__(self):
-        stack = self._tr._stack()
-        if stack:
-            self.args.setdefault("parent", stack[-1])
-        stack.append(self.name)
-        self._t0 = time.perf_counter()
+        self._ann.__enter__()
         return self
 
     def __exit__(self, *exc):
-        t1 = time.perf_counter()
-        stack = self._tr._stack()
-        if stack and stack[-1] == self.name:
-            stack.pop()
-        self._tr._record(self.name, self._t0, t1,
-                         threading.get_ident() & 0x7FFFFFFF, self.args)
+        self._ann.__exit__(*exc)
         return False
 
-
-class _Handle:
-    """Open flow span on a synthetic track; closed by :meth:`end`."""
-    __slots__ = ("_tr", "name", "args", "_t0", "_tid")
-
-    def __init__(self, tr, name, tid, args):
-        self._tr = tr
-        self.name = name
-        self.args = args
-        self._tid = tid
-        self._t0 = time.perf_counter()
+    def set(self, **kv):
+        self._ann.set_metadata(**kv)
+        return self
 
     def end(self, **kv):
+        """Close a span opened by :meth:`Tracer.begin`."""
         if kv:
-            self.args.update(kv)
-        self._tr._record(self.name, self._t0, time.perf_counter(),
-                         self._tid, self.args)
+            self.set(**kv)
+        self.__exit__(None, None, None)
 
 
 class Tracer:
-    """Bounded in-memory Chrome trace-event recorder."""
+    """Names program work on the profiler's timeline."""
 
-    def __init__(self, enabled: bool = False, max_events: int = 1_000_000):
-        self.enabled = enabled
-        self.max_events = max_events
-        self.events: list[dict] = []
-        self.dropped_events = 0
-        self._epoch = time.perf_counter()
-        self._local = threading.local()
-        self._tracks: dict[str, int] = {}
-        self._lock = threading.Lock()
+    @property
+    def enabled(self) -> bool:
+        """Whether a profiler session is recording now."""
+        return _annotation().is_enabled()
 
-    def configure(self, enabled: bool | None = None,
-                  max_events: int | None = None) -> "Tracer":
-        if max_events is not None:
-            self.max_events = int(max_events)
-        if enabled is not None:
-            self.enabled = bool(enabled)
-        return self
-
-    def clear(self) -> None:
-        with self._lock:
-            self.events = []
-            self.dropped_events = 0
-            self._tracks = {}
-            self._epoch = time.perf_counter()
-
-    def _stack(self) -> list:
-        s = getattr(self._local, "stack", None)
-        if s is None:
-            s = self._local.stack = []
-        return s
-
-    def _record(self, name, t0, t1, tid, args) -> None:
-        if len(self.events) >= self.max_events:
-            self.dropped_events += 1
-            return
-        self.events.append({
-            "name": name, "ph": "X", "pid": os.getpid(), "tid": tid,
-            "ts": (t0 - self._epoch) * 1e6,
-            "dur": max((t1 - t0) * 1e6, 0.0),
-            "args": args,
-        })
-
-    # -- recording API ------------------------------------------------------
     def span(self, name: str, **args):
-        """Context-manager span on the calling thread's track (or the
-        no-alloc ``NULL_SPAN`` when disabled)."""
-        if not self.enabled:
+        """Context-manager span on the calling thread (``NULL_SPAN`` when
+        no session is on)."""
+        if not _annotation().is_enabled():
             return NULL_SPAN
-        return _Span(self, name, args)
+        return _Span(name, args)
 
-    def begin(self, name: str, track: str | None = None, **args):
-        """Open a flow span now; the returned handle's ``.end()`` closes it.
-        ``track`` names a synthetic timeline (e.g. ``"h2d-stream"``) so
-        concurrent host-side and transfer work render as separate rows."""
-        if not self.enabled:
+    def begin(self, name: str, **args):
+        """Open a span now; the returned handle's ``.end()`` closes it
+        (``NULL_HANDLE`` when no session is on)."""
+        if not _annotation().is_enabled():
             return NULL_HANDLE
-        tid = (self.track(track) if track is not None
-               else threading.get_ident() & 0x7FFFFFFF)
-        return _Handle(self, name, tid, args)
-
-    def emit(self, name: str, t0: float, t1: float,
-             track: str | None = None, **args) -> None:
-        """Record a span from two ``time.perf_counter()`` readings."""
-        if not self.enabled:
-            return
-        tid = (self.track(track) if track is not None
-               else threading.get_ident() & 0x7FFFFFFF)
-        self._record(name, t0, t1, tid, args)
-
-    def track(self, name: str) -> int:
-        """Synthetic track id for ``name`` (thread_name metadata emitted
-        once so Perfetto labels the row)."""
-        tid = self._tracks.get(name)
-        if tid is None:
-            with self._lock:
-                tid = self._tracks.get(name)
-                if tid is None:
-                    tid = 0x40000000 + len(self._tracks)
-                    self._tracks[name] = tid
-                    self.events.append({
-                        "name": "thread_name", "ph": "M", "pid": os.getpid(),
-                        "tid": tid, "ts": 0,
-                        "args": {"name": name}})
-        return tid
-
-    # -- export -------------------------------------------------------------
-    def to_chrome(self) -> dict:
-        return {"traceEvents": list(self.events),
-                "displayTimeUnit": "ms",
-                "otherData": {"dropped_events": self.dropped_events}}
-
-    def export_chrome(self, path) -> int:
-        """Write the Chrome trace-event JSON; returns the event count."""
-        path = Path(path)
-        path.parent.mkdir(parents=True, exist_ok=True)
-        path.write_text(json.dumps(self.to_chrome()))
-        return len(self.events)
+        return _Span(name, args).__enter__()
 
 
-#: process-wide tracer every instrumentation point records into; disabled
-#: (and therefore allocation-free) unless a driver turns it on
+@contextmanager
+def session(out_dir, perfetto: bool = False):
+    """A profiler session writing to ``out_dir``: the program's spans, the
+    runtime's and the device's operations, without an event per Python
+    call. ``perfetto`` also writes ``perfetto_trace.json.gz`` beside the
+    ``.xplane.pb``, for https://ui.perfetto.dev."""
+    import jax
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(out_dir), create_perfetto_trace=perfetto,
+                             profiler_options=opts)
+    try:
+        yield
+    finally:
+        jax.profiler.stop_trace()
+
+
+#: process-wide tracer every instrumentation point records through
 TRACER = Tracer()

@@ -202,6 +202,7 @@ class SearchFrontend:
         self._admit_q: list[_FrontReq] = []
         self._inflight = 0
         self._next_rid = 0
+        self._next_batch = 0
         self._insert_lock = threading.Lock()
         self._snap_lock = threading.Lock()
         self._rehydrating: set[int] = set()
@@ -360,12 +361,10 @@ class SearchFrontend:
         if deadline_ms is not None and deadline_ms < 0:
             deadline_ms = self.fcfg.default_deadline_ms
         now = self.clock()
-        with _TR.span("frontend.admit", engine=engine):
+        with _TR.span("frontend.admit", engine=engine) as span:
             with self._admit_lock:
                 if self._inflight >= self.fcfg.high_water:
                     self._m_shed.inc(reason="overload")
-                    with _TR.span("frontend.shed", reason="overload"):
-                        pass
                     raise Overloaded(
                         f"{self._inflight} requests in flight "
                         f"(high_water {self.fcfg.high_water})")
@@ -378,6 +377,7 @@ class SearchFrontend:
                 self._next_rid += 1
                 self._admit_q.append(req)
                 self._inflight += 1
+            span.set(rid=req.rid)
             self._m_admitted.inc(engine=engine)
         self._wake.set()
         return req.future
@@ -411,21 +411,24 @@ class SearchFrontend:
             self._tick()
 
     def _tick(self) -> None:
-        with self._admit_lock:
-            reqs, self._admit_q = self._admit_q, []
-        try:
-            if reqs:
-                self._dispatch(reqs)
-        except Exception as e:             # noqa: BLE001 — fail, don't lose
-            self._abandon_batch(reqs, e)
-        # maintenance faults must never kill the dispatcher (the serving
-        # loop); they surface through metrics/state on the next pass
-        for step in (self._monitor_health, self._degradation_tick,
-                     self._schedule_maintenance):
+        with _TR.span("frontend.tick"):
+            with self._admit_lock:
+                reqs, self._admit_q = self._admit_q, []
             try:
-                step()
-            except Exception as e:         # noqa: BLE001 — keep serving
-                self._last_maintenance_error = e
+                if reqs:
+                    self._dispatch(reqs)
+            except Exception as e:         # noqa: BLE001 — fail, don't lose
+                self._abandon_batch(reqs, e)
+            # maintenance faults must never kill the dispatcher (the
+            # serving loop); they surface through metrics/state on the next
+            # pass
+            with _TR.span("frontend.maintenance"):
+                for step in (self._monitor_health, self._degradation_tick,
+                             self._schedule_maintenance):
+                    try:
+                        step()
+                    except Exception as e:  # noqa: BLE001 — keep serving
+                        self._last_maintenance_error = e
 
     def _dispatch(self, reqs: list[_FrontReq]) -> None:
         now = self.clock()
@@ -434,9 +437,6 @@ class SearchFrontend:
             if r.deadline is not None and now > r.deadline:
                 # never scored: shed pre-dispatch
                 self._m_expired.inc(stage="dispatch")
-                with _TR.span("frontend.shed", reason="deadline",
-                              rid=r.rid):
-                    pass
                 self._complete(r, exc=DeadlineExceeded(
                     f"request {r.rid} expired "
                     f"{(now - r.deadline) * 1e3:.1f}ms before dispatch"))
@@ -452,11 +452,16 @@ class SearchFrontend:
             return
         target = min(live, key=lambda rep: rep.queue_depth())
         level = self._level
+        self._next_batch += 1
+        # the batch's ids, on its dispatch and on the replica's run of it
+        ids = (dict(batch=self._next_batch,
+                    rid=" ".join(str(r.rid) for r in ready))
+               if _TR.enabled else {})
         with _TR.span("frontend.dispatch", replica=target.index,
-                      n_requests=len(ready), level=level):
+                      n_requests=len(ready), level=level, **ids):
             target.call(partial(self._run_batch, reqs=ready, level=level),
                         label="batch",
-                        abandon=partial(self._abandon_batch, ready))
+                        abandon=partial(self._abandon_batch, ready), **ids)
         for rep in self.replicas:
             self._m_depth.set(rep.queue_depth(), replica=rep.index)
         self._m_inflight.set(self._inflight)

@@ -30,6 +30,7 @@ import time
 from dataclasses import dataclass, field
 from typing import Callable
 
+from ..obs.trace import TRACER as _TR
 from .service import SearchService
 
 
@@ -88,6 +89,8 @@ class _Task:
     label: str = "task"
     future: Future = field(default_factory=Future)
     abandon: Callable[[BaseException], None] | None = None
+    #: span metadata of the worker's run (a batch's ``batch`` and ``rid``)
+    trace: dict = field(default_factory=dict)
 
     def fail(self, exc: BaseException) -> None:
         if self.abandon is not None:
@@ -118,9 +121,11 @@ class Replica:
         self._thread.start()
 
     # -- scheduling ----------------------------------------------------------
-    def call(self, fn, label: str = "task", abandon=None) -> Future:
-        """Enqueue ``fn(service)``; returns the result future."""
-        task = _Task(fn, label=label, abandon=abandon)
+    def call(self, fn, label: str = "task", abandon=None,
+             **trace) -> Future:
+        """Enqueue ``fn(service)``; returns the result future. ``trace``
+        goes on the ``replica.task`` span of its run."""
+        task = _Task(fn, label=label, abandon=abandon, trace=trace)
         self.put(task)
         return task.future
 
@@ -188,7 +193,9 @@ class Replica:
                 continue
             self._busy_since = self.clock()
             try:
-                task.future.set_result(task.fn(self.svc))
+                with _TR.span("replica.task", label=task.label,
+                              **task.trace):
+                    task.future.set_result(task.fn(self.svc))
             except BaseException as e:     # noqa: BLE001 — fault isolation
                 # a failing task poisons the replica (the service may be in
                 # a partially-applied state — divergence risk); the front

@@ -24,10 +24,12 @@ that host for the TPU engines:
   compaction counts (:meth:`summary`). Since ISSUE 8 the backing store is a
   :class:`repro.obs.metrics.MetricsRegistry` (:attr:`metrics`) — bounded
   log-bucketed latency histograms and labeled counters/gauges with
-  Prometheus/JSONL exposition — plus structured trace spans through
-  ``repro.obs.trace.TRACER`` (queue wait, batch formation, per-engine
-  search, WAL append, snapshot writes; Chrome trace-event export for
-  Perfetto). ``latencies_ms`` / ``batches`` remain as *bounded* recent
+  Prometheus/JSONL exposition — plus spans through
+  ``repro.obs.trace.TRACER`` on the profiler's timeline (``service.batch``
+  per (engine, k) group, ``service.pad`` for the bucket padding and the
+  concatenation of queries and results, ``service.engine_search`` per
+  engine call, ``service.insert`` with its WAL append, snapshot writes).
+  ``latencies_ms`` / ``batches`` remain as *bounded* recent
   windows (``TELEMETRY_WINDOW``) so sustained load cannot grow host memory;
   ``summary()`` keys are unchanged and always present (``None`` percentiles
   on a write-only run).
@@ -314,66 +316,68 @@ class SearchService:
         groups: dict[tuple, list[_Request]] = {}
         for r in pending:
             groups.setdefault((r.engine, r.k), []).append(r)
-        # queue-wait spans use the service clock; only a real wall clock
-        # shares a timeline with the tracer's perf_counter epoch
-        real_clock = self.clock is time.perf_counter
         for (ename, k), reqs in groups.items():
-            eng = self.engines[ename]
-            qs = np.concatenate([r.queries for r in reqs])
-            n, w = qs.shape
-            ids_parts, sims_parts = [], []
-            t0 = self.clock()
-            for r in reqs:
-                self._m_queue_wait.observe((t0 - r.t_submit) * 1e3,
-                                           engine=ename)
-                if _TR.enabled and real_clock:
-                    _TR.emit("service.queue_wait", r.t_submit, t0,
-                             track="queue", rid=r.rid, engine=ename)
             with _TR.span("service.batch", engine=ename, k=int(k),
-                          n_queries=int(n), n_requests=len(reqs)):
-                off = 0
-                while off < n:
-                    chunk = qs[off:off + self.config.max_batch]
-                    bucket = next_pow2(chunk.shape[0])
-                    padded = np.zeros((bucket, w), dtype=np.uint32)
-                    padded[:chunk.shape[0]] = chunk
-                    with _TR.span("service.engine_search", engine=ename,
-                                  bucket=int(bucket)):
-                        ids, sims = eng.search(padded, k)
-                    ids_parts.append(np.asarray(ids)[:chunk.shape[0]])
-                    sims_parts.append(np.asarray(sims)[:chunk.shape[0]])
-                    self.batches.append({"engine": ename, "k": k,
-                                         "bucket": int(bucket),
-                                         "n": int(chunk.shape[0])})
-                    self._batch_buckets[int(bucket)] += 1
-                    self._m_batches.inc(engine=ename, bucket=int(bucket))
-                    sc = eng.scanned(bucket)
-                    self.scanned_total[ename] += sc
-                    self._m_scanned.inc(sc, engine=ename)
-                    self._fold_engine_stats(ename, eng)
-                    off += chunk.shape[0]
-            dt = self.clock() - t0
-            self.search_time += dt
-            self._m_batch_ms.observe(dt * 1e3, engine=ename)
-            ids = np.concatenate(ids_parts)
-            sims = np.concatenate(sims_parts)
-            t_done = self.clock()
-            off = 0
-            for r in reqs:
-                nr = r.queries.shape[0]
-                done[r.rid] = (ids[off:off + nr], sims[off:off + nr])
-                off += nr
-                self.latencies_ms.append((t_done - r.t_submit) * 1e3)
-                self._m_req_lat.observe((t_done - r.t_submit) * 1e3,
-                                        engine=ename)
-                self.n_queries += nr
-                self._m_queries.inc(nr, engine=ename)
+                          n_queries=sum(r.queries.shape[0] for r in reqs),
+                          n_requests=len(reqs)):
+                self._flush_group(ename, k, reqs, done)
         self._results.update(done)
         # FIFO-evict beyond the buffer bound: callers that consume flush()'s
         # return and never result() must not leak arrays forever
         while len(self._results) > self.RESULT_BUFFER:
             self._results.pop(next(iter(self._results)))
         return done
+
+    def _flush_group(self, ename: str, k: int, reqs: list, done: dict):
+        """One (engine, k) group: concatenate, pad each chunk to its
+        bucket, search, split the results back per request."""
+        eng = self.engines[ename]
+        with _TR.span("service.pad"):
+            qs = np.concatenate([r.queries for r in reqs])
+        n, w = qs.shape
+        ids_parts, sims_parts = [], []
+        t0 = self.clock()
+        for r in reqs:
+            self._m_queue_wait.observe((t0 - r.t_submit) * 1e3, engine=ename)
+        off = 0
+        while off < n:
+            chunk = qs[off:off + self.config.max_batch]
+            bucket = next_pow2(chunk.shape[0])
+            with _TR.span("service.pad", bucket=int(bucket)):
+                padded = np.zeros((bucket, w), dtype=np.uint32)
+                padded[:chunk.shape[0]] = chunk
+            with _TR.span("service.engine_search", engine=ename,
+                          bucket=int(bucket)):
+                ids, sims = eng.search(padded, k)
+            ids_parts.append(np.asarray(ids)[:chunk.shape[0]])
+            sims_parts.append(np.asarray(sims)[:chunk.shape[0]])
+            self.batches.append({"engine": ename, "k": k,
+                                 "bucket": int(bucket),
+                                 "n": int(chunk.shape[0])})
+            self._batch_buckets[int(bucket)] += 1
+            self._m_batches.inc(engine=ename, bucket=int(bucket))
+            sc = eng.scanned(bucket)
+            self.scanned_total[ename] += sc
+            self._m_scanned.inc(sc, engine=ename)
+            self._fold_engine_stats(ename, eng)
+            off += chunk.shape[0]
+        dt = self.clock() - t0
+        self.search_time += dt
+        self._m_batch_ms.observe(dt * 1e3, engine=ename)
+        with _TR.span("service.pad"):
+            ids = np.concatenate(ids_parts)
+            sims = np.concatenate(sims_parts)
+        t_done = self.clock()
+        off = 0
+        for r in reqs:
+            nr = r.queries.shape[0]
+            done[r.rid] = (ids[off:off + nr], sims[off:off + nr])
+            off += nr
+            self.latencies_ms.append((t_done - r.t_submit) * 1e3)
+            self._m_req_lat.observe((t_done - r.t_submit) * 1e3,
+                                    engine=ename)
+            self.n_queries += nr
+            self._m_queries.inc(nr, engine=ename)
 
     def result(self, rid: int):
         """Redeem a completed request (pops it from the result buffer).

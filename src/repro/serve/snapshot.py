@@ -220,7 +220,7 @@ def service_state(svc):
     arrays, engines_meta = {}, {}
     # the COW extraction runs on the serving thread even for background
     # snapshots — its span is the synchronous cost the request path pays
-    with _TR.span("snapshot.extract", engines=list(svc.engines)):
+    with _TR.span("snapshot.extract", engines=" ".join(svc.engines)):
         for name, eng in svc.engines.items():
             a, m_ = engine_state(eng)
             arrays.update({f"{name}/{k}": v for k, v in a.items()})

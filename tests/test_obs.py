@@ -1,18 +1,23 @@
 """Observability primitives (ISSUE 8): metrics registry semantics
 (counters/gauges/log-bucketed histograms, quantile error bound, thread
 safety, Prometheus + JSONL exposition round-tripping through the schema
-validators) and trace spans (nesting/parent linkage, Chrome export shape,
-bounded buffer, and the allocation-free disabled fast path)."""
+validator) and trace spans recorded into a ``jax.profiler`` session
+(nesting and metadata, non-nested handles, the session as the only
+switch, the Perfetto file, and the allocation-free fast path without a
+session)."""
+import glob
 import json
 import threading
+import time
 
 import pytest
 
+from _spans import record
+from repro.obs import trace as obs_trace
 from repro.obs.metrics import (GROWTH, MetricsRegistry, NULL_METRICS,
                                NullMetrics)
-from repro.obs.schema import (validate_metrics_jsonl, validate_trace,
-                              validate_trace_file)
-from repro.obs.trace import NULL_HANDLE, NULL_SPAN, Tracer
+from repro.obs.schema import validate_metrics_jsonl
+from repro.obs.trace import NULL_HANDLE, NULL_SPAN, Tracer, session
 
 
 # -- registry ---------------------------------------------------------------
@@ -147,68 +152,106 @@ def test_null_metrics_surface():
 
 # -- tracer -----------------------------------------------------------------
 
-def test_disabled_span_fast_path():
-    tr = Tracer(enabled=False)
-    # acceptance: no span object is allocated when tracing is off — every
-    # call returns the module-level singletons and records nothing
+def test_disabled_span_fast_path(tmp_path, monkeypatch):
+    tr = Tracer()
+    assert tr.enabled is False
+    # acceptance: no span object is allocated without a profiler session —
+    # every call returns the module-level singletons and records nothing
     assert tr.span("a") is NULL_SPAN
     assert tr.span("b", key="val") is tr.span("c")
-    assert tr.begin("d", track="t") is NULL_HANDLE
+    assert tr.begin("d") is NULL_HANDLE
+
+    def boom(*a, **kw):
+        raise AssertionError("a span was built without a session")
+    monkeypatch.setattr(obs_trace, "_Span", boom)
     with tr.span("e") as s:
         s.set(answer=42)
     tr.begin("f").end(done=True)
-    tr.emit("g", 0.0, 1.0)
-    assert tr.events == [] and tr.dropped_events == 0
-
-
-def test_span_nesting_and_parent_linkage():
-    tr = Tracer(enabled=True)
-    with tr.span("outer", engine="brute"):
-        with tr.span("inner") as s:
-            s.set(rows=8)
-    by_name = {e["name"]: e for e in tr.events}
-    assert by_name["inner"]["args"]["parent"] == "outer"
-    assert by_name["inner"]["args"]["rows"] == 8
-    assert "parent" not in by_name["outer"]["args"]
-    # inner is contained in outer on the timeline
-    o, i = by_name["outer"], by_name["inner"]
-    assert o["ts"] <= i["ts"]
-    assert i["ts"] + i["dur"] <= o["ts"] + o["dur"] + 1e-6
-    assert validate_trace(tr.to_chrome()) == []
-
-
-def test_flow_handles_and_tracks():
-    tr = Tracer(enabled=True)
-    h = tr.begin("transfer", track="h2d-stream", chunk=0)
-    h.end(bytes=4096)
-    tr.emit("stall", 0.0, 0.001, track="h2d-stream", chunk=0)
-    names = [e["name"] for e in tr.events]
-    assert "thread_name" in names            # track metadata emitted once
-    meta = [e for e in tr.events if e["ph"] == "M"]
-    assert len(meta) == 1 and meta[0]["args"]["name"] == "h2d-stream"
-    tids = {e["tid"] for e in tr.events if e["ph"] == "X"}
-    assert tids == {meta[0]["tid"]}          # both spans on the named track
-    assert validate_trace(tr.to_chrome()) == []
-
-
-def test_event_buffer_bounded():
-    tr = Tracer(enabled=True, max_events=4)
-    for i in range(10):
-        with tr.span(f"s{i}"):
+    monkeypatch.undo()
+    with record(tmp_path) as rec:
+        with tr.span("marker"):
             pass
-    assert len(tr.events) == 4
-    assert tr.dropped_events == 6
+    assert {"a", "b", "c", "d", "e", "f"}.isdisjoint(rec.names())
+    assert len(rec.named("marker")) == 1
+
+
+def test_span_nesting_and_parent_linkage(tmp_path):
+    tr = Tracer()
+    with record(tmp_path) as rec:
+        with tr.span("outer", engine="brute"):
+            with tr.span("inner") as s:
+                time.sleep(0.001)
+                s.set(rows=8)
+    (outer,), (inner,) = rec.named("outer"), rec.named("inner")
+    # nesting is containment on one thread's line of the profiler trace
+    assert outer.contains(inner) and not inner.contains(outer)
+    assert inner.end - inner.start >= 1_000_000
+    assert outer.args == {"engine": "brute"}
+    assert inner.args == {"rows": 8}
+
+
+def test_flow_handles_and_tracks(tmp_path):
+    """A handle's span need not nest: chunk 1's transfer opens before chunk
+    0's ends and outlives it, on the thread's own line, with metadata
+    added at its end."""
+    tr = Tracer()
+    with record(tmp_path) as rec:
+        h0 = tr.begin("transfer", chunk=0)
+        h1 = tr.begin("transfer", chunk=1)
+        time.sleep(0.001)
+        h0.end(bytes=4096)
+        with tr.span("scan", chunk=0):
+            time.sleep(0.001)
+        h1.end()
+    spans = {s.args["chunk"]: s for s in rec.named("transfer")}
+    scan, = rec.named("scan")
+    assert spans[0].args["bytes"] == 4096
+    assert spans[0].overlaps(spans[1]) and not spans[1].contains(spans[0])
+    assert spans[1].contains(scan) and not spans[0].overlaps(scan)
+    assert spans[0].line == spans[1].line == scan.line
+
+
+def test_tracer_enabled_follows_the_session(tmp_path):
+    """The profiler session is the only switch: no configure step, no
+    buffer, nothing recorded before the session or after it."""
+    tr = Tracer()
+    assert not hasattr(tr, "configure") and not hasattr(tr, "events")
+    with tr.span("before"):
+        pass
+    seen = []
+    with record(tmp_path) as rec:
+        seen.append(tr.enabled)
+        with tr.span("during"):
+            pass
+    seen.append(tr.enabled)
+    with tr.span("after"):
+        pass
+    assert seen == [True, False]
+    assert rec.names() & {"before", "during", "after"} == {"during"}
 
 
 def test_chrome_export_file(tmp_path):
-    tr = Tracer(enabled=True)
-    with tr.span("service.batch", engine="brute"):
-        pass
-    path = tmp_path / "trace.json"
-    n = tr.export_chrome(path)
-    assert n == 1
-    assert validate_trace_file(path, require_spans=("service.batch",)) == []
-    assert validate_trace_file(path, require_spans=("missing.span",)) \
-        == ["required span 'missing.span' not present in trace"]
-    obj = json.loads(path.read_text())
-    assert obj["displayTimeUnit"] == "ms"
+    """``session(perfetto=True)``, the operator's ``--trace-out``, writes
+    the profiler trace with a Perfetto file beside it."""
+    with session(tmp_path, perfetto=True):
+        with obs_trace.TRACER.span("service.batch", engine="brute"):
+            pass
+    assert glob.glob(f"{tmp_path}/**/perfetto_trace.json.gz", recursive=True)
+    from _spans import read
+    batch, = [s for s in read(tmp_path) if s.name == "service.batch"]
+    assert batch.args == {"engine": "brute"}
+
+
+def test_batch_ids_round_trip(tmp_path):
+    """Ids go in as metadata and come back as the reduction reads them: a
+    span over several requests carries their rids space-separated, one
+    request's span its rid alone."""
+    tr = Tracer()
+    with record(tmp_path) as rec:
+        with tr.span("frontend.dispatch", batch=3, rid="4 5 6"):
+            pass
+        with tr.span("frontend.dispatch", batch=4, rid=str(7)):
+            pass
+    got = sorted((s.args["batch"], str(s.args["rid"]).split())
+                 for s in rec.named("frontend.dispatch"))
+    assert got == [(3, ["4", "5", "6"]), (4, ["7"])]

@@ -2,17 +2,26 @@
 populated consistently with the legacy telemetry (summary() keys stable),
 write-only runs report explicit null percentiles, the recent-window views
 are bounded under sustained load, tiered residency populates the per-stage
-stream gauges/spans (and device residency does not), and a captured tiered
-trace shows the double-buffer overlap — a chunk's host->HBM transfer span
-concurrent with the previous chunk's compute span."""
+stream gauges/spans (and device residency does not), and a profiler trace
+shows the double-buffer overlap — a chunk's host->HBM transfer span
+concurrent with the previous chunk's compute span — the served path's span
+tree from the front end to the engine with one request id, and the
+engines' device->host reads all inside ``engine.fetch``."""
+import time
+
+import jax
 import numpy as np
 import pytest
+from jax._src import array as jax_array
 
+from _spans import record
+from repro.core import BitBoundFoldingEngine, BruteForceEngine
 from repro.data.molecules import (SyntheticConfig, queries_from_db,
                                   synthetic_fingerprints)
-from repro.obs.schema import validate_trace
+from repro.obs import trace as obs_trace
 from repro.obs.trace import TRACER
 from repro.serve import SearchService
+from repro.serve.frontend import FrontendConfig, SearchFrontend
 
 K = 8
 
@@ -23,17 +32,6 @@ def data():
     extra = synthetic_fingerprints(SyntheticConfig(n=64, seed=5))
     q = queries_from_db(db, 16, seed=2)
     return db, extra, q
-
-
-@pytest.fixture(autouse=True)
-def _tracer_off():
-    """Every test starts and ends with the process-wide tracer disabled and
-    empty (it is a module-level singleton)."""
-    TRACER.configure(enabled=False)
-    TRACER.clear()
-    yield
-    TRACER.configure(enabled=False)
-    TRACER.clear()
 
 
 def test_registry_matches_legacy_telemetry(data):
@@ -145,59 +143,165 @@ def test_device_residency_leaves_tier_gauges_empty(data):
     assert all(r["name"] != "service_tiered_chunks" for r in m.collect())
 
 
-def test_tiered_trace_shows_double_buffer_overlap(data):
+def test_tiered_trace_shows_double_buffer_overlap(data, tmp_path):
     db, _, q = data
-    TRACER.configure(enabled=True)
     svc = _tiered_service(db)
-    svc.submit(q[:4], engine="brute")
-    svc.flush()
-    events = [e for e in TRACER.events if e["ph"] == "X"]
-    assert validate_trace(TRACER.to_chrome()) == []
-    puts = [e for e in events if e["name"] == "tier.device_put"]
-    scans = [e for e in events if e["name"] == "tier.scan_chunk"]
+    with record(tmp_path) as rec:
+        svc.submit(q[:4], engine="brute")
+        svc.flush()
+    puts = rec.named("tier.device_put")
+    scans = rec.named("tier.scan_chunk")
     assert len(puts) == 8 and len(scans) == 8
+    assert len(rec.named("tier.stall")) == 8
     # acceptance: chunk i+1's host->HBM transfer span overlaps chunk i's
     # compute span on the timeline (the double buffer actually pipelines)
-    def overlaps(a, b):
-        return (a["ts"] < b["ts"] + b["dur"]
-                and b["ts"] < a["ts"] + a["dur"])
-    scan_by_chunk = {e["args"]["chunk"]: e for e in scans}
-    put_by_chunk = {e["args"]["chunk"]: e for e in puts}
+    scan_by_chunk = {e.args["chunk"]: e for e in scans}
+    put_by_chunk = {e.args["chunk"]: e for e in puts}
     overlapped = [c for c in range(7)
-                  if overlaps(put_by_chunk[c + 1], scan_by_chunk[c])]
+                  if put_by_chunk[c + 1].overlaps(scan_by_chunk[c])]
     assert overlapped, "no transfer span overlapped the previous compute span"
-    # the service-level request path is present and linked
-    names = {e["name"] for e in events}
-    assert {"service.batch", "service.engine_search",
-            "service.queue_wait"} <= names
-    search_spans = [e for e in events if e["name"] == "service.engine_search"]
-    assert all(e["args"].get("parent") == "service.batch"
-               for e in search_spans)
+    # the service-level request path is present and nested
+    assert {"service.batch", "service.engine_search", "service.pad",
+            "engine.plan", "engine.dispatch", "engine.fetch"} <= rec.names()
+    assert "service.queue_wait" not in rec.names()
+    batch, = rec.named("service.batch")
+    search, = rec.named("service.engine_search")
+    assert batch.contains(search)
+    assert all(search.contains(e) for e in scans)
 
 
-def test_disabled_tracer_records_nothing_through_service(data):
+def test_disabled_tracer_records_nothing_through_service(data, monkeypatch):
     db, extra, q = data
     assert TRACER.enabled is False
+
+    def boom(*a, **kw):
+        raise AssertionError("a span was built without a profiler session")
+    # without a session not one span object is built on the whole path
+    monkeypatch.setattr(obs_trace, "_Span", boom)
     svc = _tiered_service(db)
     svc.insert(extra[:4])
     svc.submit(q[:4], engine="brute")
-    svc.flush()
-    assert TRACER.events == [] and TRACER.dropped_events == 0
+    done = svc.flush()
+    assert len(done) == 1
 
 
 def test_wal_and_snapshot_spans(data, tmp_path):
     db, extra, q = data
-    TRACER.configure(enabled=True)
-    svc = SearchService(db, engines=("brute",), backend="jnp", k=K,
-                        durable_dir=str(tmp_path))
-    svc.insert(extra[:4])
-    svc.submit(q[:2], engine="brute")
-    svc.flush()
-    svc.snapshot()
-    svc.close()
-    names = {e["name"] for e in TRACER.events if e["ph"] == "X"}
+    with record(tmp_path / "trace") as rec:
+        svc = SearchService(db, engines=("brute",), backend="jnp", k=K,
+                            durable_dir=str(tmp_path / "durable"))
+        svc.insert(extra[:4])
+        svc.submit(q[:2], engine="brute")
+        svc.flush()
+        svc.snapshot()
+        svc.close()
     assert {"wal.append", "wal.fsync", "service.insert",
-            "snapshot.extract", "snapshot.write"} <= names
-    # WAL append is recorded inside the insert span
-    appends = [e for e in TRACER.events if e["name"] == "wal.append"]
-    assert all(e["args"]["parent"] == "service.insert" for e in appends)
+            "snapshot.extract", "snapshot.write"} <= rec.names()
+    # the WAL append (and its fsync) is recorded inside the insert span
+    insert, = rec.named("service.insert")
+    appends = rec.named("wal.append")
+    assert appends and all(insert.contains(e) for e in appends)
+    assert rec.inside(insert, "wal.fsync")
+    assert rec.named("snapshot.extract")[0].args["engines"] == "brute"
+
+
+ENGINES = ("brute", "bitbound-folding")
+
+
+@pytest.mark.parametrize("engine", ENGINES)
+def test_served_path_span_tree(data, tmp_path, engine):
+    """One ``SearchFrontend.search`` under a profiler session: admission,
+    the dispatcher's tick around its dispatch, the replica's task around
+    the service's batch, around the engine's plan, launch and fetch; the
+    request's id on every span that carries ids."""
+    db, _, q = data
+    fe = SearchFrontend(db, engines=(engine,), backend="jnp", k=K,
+                        frontend=FrontendConfig(default_deadline_ms=None))
+    try:
+        fe.search(q[:2], K)                       # compile outside the trace
+        with record(tmp_path) as rec:
+            ids, sims = fe.search(q[:2], K)
+    finally:
+        fe.close()
+    assert ids.shape == (2, K)
+    admit, = rec.named("frontend.admit")
+    rid = admit.args["rid"]
+    dispatch, = rec.named("frontend.dispatch")
+    tick, = [t for t in rec.named("frontend.tick") if t.contains(dispatch)]
+    assert rec.inside(tick, "frontend.maintenance")
+    task, = [t for t in rec.named("replica.task")
+             if t.args.get("label") == "batch"]
+    for span in (dispatch, task):
+        assert str(span.args["rid"]).split() == [str(rid)]
+    assert dispatch.args["batch"] == task.args["batch"]
+    assert not tick.line == task.line              # dispatcher vs worker
+    batch, = rec.inside(task, "service.batch")
+    search, = rec.inside(batch, "service.engine_search")
+    for name in ("engine.plan", "engine.dispatch", "engine.fetch"):
+        assert len(rec.inside(search, name)) == 1, name
+    plan, = rec.inside(search, "engine.plan")
+    launch, = rec.inside(search, "engine.dispatch")
+    fetch, = rec.inside(search, "engine.fetch")
+    assert plan.end <= launch.start and launch.end <= fetch.start
+    assert rec.inside(batch, "service.pad")
+    assert not {"service.queue_wait", "frontend.shed"} & rec.names()
+
+
+def _probe_reads(monkeypatch):
+    """Every device->host read becomes a ``probe.d2h`` span on the reading
+    thread: ``ArrayImpl._value`` (``int()``, ``jax.device_get``) and
+    ``np.asarray`` / ``np.array`` of a jax array (on the CPU numpy takes
+    those through the buffer protocol, past ``_value``)."""
+    def mark():
+        with jax.profiler.TraceAnnotation("probe.d2h"):
+            time.sleep(1e-5)
+
+    value = jax_array.ArrayImpl._value
+
+    def read_value(self):
+        mark()
+        return value.fget(self)
+    monkeypatch.setattr(jax_array.ArrayImpl, "_value", property(read_value))
+    for fn in ("asarray", "array"):
+        orig = getattr(np, fn)
+
+        def wrapped(a, *args, _orig=orig, **kw):
+            if isinstance(a, jax.Array):
+                mark()
+            return _orig(a, *args, **kw)
+        monkeypatch.setattr(np, fn, wrapped)
+
+
+@pytest.mark.parametrize("engine", ENGINES)
+@pytest.mark.parametrize("with_delta", [False, True])
+def test_engine_fetch_holds_the_batchs_only_device_reads(
+        data, tmp_path, monkeypatch, engine, with_delta):
+    """A served batch reads the device once, inside ``engine.fetch``: the
+    launch never syncs, and the scanned count behind
+    ``engine.stage1_scan_share`` comes back in the same fetch with its
+    value unchanged."""
+    db, extra, q = data
+    if engine == "brute":
+        eng = BruteForceEngine(db, backend="jnp")
+    else:
+        eng = BitBoundFoldingEngine(db, cutoff=0.6, m=2, backend="jnp")
+        ref = BitBoundFoldingEngine(db, cutoff=0.6, m=2)
+    if with_delta:
+        eng.insert(extra[:8])
+        if engine != "brute":
+            ref.insert(extra[:8])
+    qs = np.ascontiguousarray(q[:8])
+    want = eng.search(qs, K)                      # compile outside the trace
+    _probe_reads(monkeypatch)
+    with record(tmp_path) as rec:
+        got = eng.search(qs, K)
+    monkeypatch.undo()
+    np.testing.assert_array_equal(got[0], want[0])
+    probes = rec.named("probe.d2h")
+    fetch, = rec.named("engine.fetch")
+    assert probes, "the probe saw no read at all"
+    assert all(fetch.contains(p) for p in probes), \
+        [p.start for p in probes if not fetch.contains(p)]
+    if engine != "brute":
+        ref.search(qs, K)
+        assert eng.scanned(len(qs)) == ref.scanned(len(qs)) > 0
