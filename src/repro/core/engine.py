@@ -160,6 +160,7 @@ class SearchEngine:
                 f"got {layout!r}")
         self._last_scanned = 0
         self._last_n_queries = 0
+        self._last_tiles = (0, 0)
         self._jit_cache: dict = {}
         self.stats: dict = {}
 
@@ -207,6 +208,13 @@ class SearchEngine:
         if self._last_n_queries == 0:
             return 0
         return round(self._last_scanned * n_queries / self._last_n_queries)
+
+    def stage1_tiles(self) -> tuple[int, int]:
+        """Row-window kernel tiles of the most recent device batch:
+        ``(window, streamed)``, the sum of the per-query window tiles and
+        that of the query-block union tiles the kernel's grid scans.
+        ``(0, 0)`` for engines and backends without the kernel."""
+        return self._last_tiles
 
     @property
     def n_total(self) -> int:
@@ -778,17 +786,25 @@ class BitBoundFoldingEngine(SearchEngine):
         """Shared stage-1 closure (windowed folded scan -> per-query top-k1m
         candidate window rows). Used by both `_build_device_search` and
         `_build_tiered_candidates` — one implementation, so the two
-        residencies stay bit-identical by construction."""
+        residencies stay bit-identical by construction.
+
+        The kernel scans the batch in the plan's popcount order ``perm``,
+        so neighbouring queries share one tile stream; results go back to
+        the callers' order right after it. Every row is its own window's
+        exact top-k, so the order changes no result."""
         state = self._device_meta()
         kops, tile, capacity = state["kops"], state["tile"], state["capacity"]
 
         metric = self.metric
 
-        def stage1_main(qf, folded, folded_cnt, lo_row, hi_row):
+        def stage1_main(qf, folded, folded_cnt, lo_row, hi_row, perm):
             if kops is not None:
-                cand, s1 = kops.window_topk(qf, folded, folded_cnt, lo_row,
-                                            hi_row, k=k1m, max_tiles=bucket,
-                                            tile_n=tile, metric=metric)
+                cand, s1 = kops.window_topk(
+                    qf[perm], folded, folded_cnt, lo_row[perm], hi_row[perm],
+                    k=k1m, max_tiles=bucket, tile_n=tile, metric=metric)
+                inv = jnp.zeros_like(perm).at[perm].set(
+                    jnp.arange(perm.shape[0], dtype=perm.dtype))
+                cand, s1 = cand[inv], s1[inv]
             else:
                 s = batched_metric_scores(qf, folded, metric, folded_cnt)
                 idx = jnp.arange(capacity)[None, :]
@@ -889,10 +905,11 @@ class BitBoundFoldingEngine(SearchEngine):
         if delta_bucket == 0:
             k_out = min(k, k1m)
 
-            def run(queries, lo_row, hi_row, folded, folded_cnt, full,
+            def run(queries, lo_row, hi_row, perm, folded, folded_cnt, full,
                     full_cnt, order):
                 qf = fl.fold_jax(queries, m, scheme)
-                cand, s1 = stage1_main(qf, folded, folded_cnt, lo_row, hi_row)
+                cand, s1 = stage1_main(qf, folded, folded_cnt, lo_row, hi_row,
+                                       perm)
                 valid = cand >= 0
                 safe = jnp.clip(cand, 0, capacity - 1)
                 if m == 1:
@@ -915,10 +932,12 @@ class BitBoundFoldingEngine(SearchEngine):
         k_out = min(k, k1c)
         select = self._make_delta_select(k1m, k1c, delta_bucket)
 
-        def run(queries, lo_row, hi_row, folded, folded_cnt, full, full_cnt,
-                order, d_full, d_folded, d_cnt, d_folded_cnt, d_ok, n_main):
+        def run(queries, lo_row, hi_row, perm, folded, folded_cnt, full,
+                full_cnt, order, d_full, d_folded, d_cnt, d_folded_cnt, d_ok,
+                n_main):
             qf = fl.fold_jax(queries, m, scheme)
-            cand, s1 = stage1_main(qf, folded, folded_cnt, lo_row, hi_row)
+            cand, s1 = stage1_main(qf, folded, folded_cnt, lo_row, hi_row,
+                                   perm)
             sel_s, valid, is_d, d_slot, safe_m, gids = select(
                 qf, cand, s1, full_cnt, order, d_folded, d_cnt,
                 d_folded_cnt, d_ok, n_main)
@@ -956,10 +975,11 @@ class BitBoundFoldingEngine(SearchEngine):
         stage1_main = self._make_stage1(bucket, k1m)
 
         if delta_bucket == 0:
-            def run(queries, lo_row, hi_row, folded, folded_cnt, order):
+            def run(queries, lo_row, hi_row, perm, folded, folded_cnt,
+                    order):
                 qf = fl.fold_jax(queries, m, scheme)
                 cand, s1 = stage1_main(qf, folded, folded_cnt, lo_row,
-                                       hi_row)
+                                       hi_row, perm)
                 valid = cand >= 0
                 safe = jnp.clip(cand, 0, capacity - 1)
                 gids = jnp.where(valid, order[safe], -1)
@@ -970,10 +990,11 @@ class BitBoundFoldingEngine(SearchEngine):
         k1c = min(kr1, k1m + delta_bucket)
         select = self._make_delta_select(k1m, k1c, delta_bucket)
 
-        def run(queries, lo_row, hi_row, folded, folded_cnt, full_cnt,
+        def run(queries, lo_row, hi_row, perm, folded, folded_cnt, full_cnt,
                 order, d_folded, d_cnt, d_folded_cnt, d_ok, n_main):
             qf = fl.fold_jax(queries, m, scheme)
-            cand, s1 = stage1_main(qf, folded, folded_cnt, lo_row, hi_row)
+            cand, s1 = stage1_main(qf, folded, folded_cnt, lo_row, hi_row,
+                                   perm)
             sel_s, valid, is_d, d_slot, safe_m, gids = select(
                 qf, cand, s1, full_cnt, order, d_folded, d_cnt,
                 d_folded_cnt, d_ok, n_main)
@@ -1100,19 +1121,33 @@ class BitBoundFoldingEngine(SearchEngine):
 
     def _batch_plan(self, q_np: np.ndarray):
         """Host-side metadata of one batch: per-query Eq.2 row windows
-        ``[lo, hi)``, the power-of-two grid bucket, the delta bucket and
-        the delta rows' popcount-window mask (None without a delta)."""
+        ``[lo, hi)``, the stable popcount order ``perm`` the row-window
+        kernel scans the batch in, its power-of-two grid bucket, the delta
+        bucket and the delta rows' popcount-window mask (None without a
+        delta).
+
+        In that order each block of ``kops.window_block`` queries streams
+        the union of its windows once; the bucket covers the widest union.
+        The per-query window tiles and the block union tiles are recorded
+        for :meth:`stage1_tiles`."""
         self._sync()
         state = self._device_meta()
         tile, total_tiles = state["tile"], state["total_tiles"]
         a = np.bitwise_count(q_np).sum(-1)
         lo, hi = bb.bound_range_np(self._counts_np, a, self.cutoff,
                                    metric=self.metric)
-        n_tiles = np.where(hi > lo,
-                           (hi + tile - 1) // tile - lo // tile, 0)
-        bucket = bb.bucket_tiles(int(n_tiles.max(initial=0)), total_tiles)
         if state["kops"] is None:
             bucket = total_tiles  # the jnp stage scans full rows, one variant
+            perm = np.arange(len(a), dtype=np.int32)      # unused
+        else:
+            kops = state["kops"]
+            perm = np.argsort(a, kind="stable").astype(np.int32)
+            window = kops.window_blocks(lo, hi, 1, tile)[1]
+            streamed = kops.window_blocks(lo[perm], hi[perm],
+                                          kops.window_block(len(a)), tile)[1]
+            bucket = bb.bucket_tiles(int(streamed.max(initial=0)),
+                                     total_tiles)
+            self._last_tiles = (int(window.sum()), int(streamed.sum()))
         dd = self._delta_dev
         delta_bucket = dd["bucket"] if dd is not None else 0
         ok_np = None
@@ -1124,7 +1159,7 @@ class BitBoundFoldingEngine(SearchEngine):
             ok_np[:, :d_cnt_np.shape[0]] = (
                 (d_cnt_np[None, :] >= lo_cnt[:, None]) &
                 (d_cnt_np[None, :] <= hi_cnt[:, None]))
-        return lo, hi, bucket, delta_bucket, ok_np
+        return lo, hi, perm, bucket, delta_bucket, ok_np
 
     def device_pipeline(self, queries, k: int):
         """The jitted device-resident pipeline for this batch and its
@@ -1132,14 +1167,14 @@ class BitBoundFoldingEngine(SearchEngine):
         plan = self._batch_plan(np.asarray(queries))
         return self._resident_call(jnp.asarray(queries), k, *plan)
 
-    def _resident_call(self, queries, k, lo, hi, bucket, delta_bucket,
+    def _resident_call(self, queries, k, lo, hi, perm, bucket, delta_bucket,
                        ok_np):
         fn = self._cached(
             (bucket, int(k), delta_bucket, self._device_meta()["capacity"]),
             lambda: self._build_device_search(bucket, k, delta_bucket))
         args = (queries, jnp.asarray(lo, jnp.int32), jnp.asarray(hi, jnp.int32),
-                self.folded, self.folded_cnt, self.full, self.full_cnt,
-                self.order)
+                jnp.asarray(perm), self.folded, self.folded_cnt, self.full,
+                self.full_cnt, self.order)
         dd = self._delta_dev
         if dd is not None:
             args += (dd["full"], dd["folded"], dd["cnt"], dd["folded_cnt"],
@@ -1159,7 +1194,8 @@ class BitBoundFoldingEngine(SearchEngine):
         """
         q_np = np.asarray(queries)
         with _TR.span("engine.plan"):
-            lo, hi, bucket, delta_bucket, ok_np = self._batch_plan(q_np)
+            lo, hi, perm, bucket, delta_bucket, ok_np = self._batch_plan(
+                q_np)
         # m == 1 never gathers full-resolution rows (folded == full, stage-1
         # scores are already exact), so the device pipeline serves tiered
         # mode as-is with `self.full is None` — the traced function simply
@@ -1176,17 +1212,19 @@ class BitBoundFoldingEngine(SearchEngine):
                 queries = jnp.asarray(q_np)
                 lo_j = jnp.asarray(lo, jnp.int32)
                 hi_j = jnp.asarray(hi, jnp.int32)
+                perm_j = jnp.asarray(perm)
                 if dd is None:
-                    safe_m, gids, valid = cfn(queries, lo_j, hi_j,
+                    safe_m, gids, valid = cfn(queries, lo_j, hi_j, perm_j,
                                               self.folded, self.folded_cnt,
                                               self.order)
                     is_d = d_slot = None
                     extra = 0
                 else:
                     safe_m, gids, valid, is_d, d_slot = cfn(
-                        queries, lo_j, hi_j, self.folded, self.folded_cnt,
-                        self.full_cnt, self.order, dd["folded"], dd["cnt"],
-                        dd["folded_cnt"], jnp.asarray(ok_np),
+                        queries, lo_j, hi_j, perm_j, self.folded,
+                        self.folded_cnt, self.full_cnt, self.order,
+                        dd["folded"], dd["cnt"], dd["folded_cnt"],
+                        jnp.asarray(ok_np),
                         jnp.int32(self.store.n_main))
                     extra = int(ok_np.sum())
             ids, sims = self._tiered_rescore(queries, k, safe_m, gids,
@@ -1196,7 +1234,8 @@ class BitBoundFoldingEngine(SearchEngine):
         with _TR.span("engine.dispatch", bucket=int(bucket),
                       delta_bucket=int(delta_bucket)):
             fn, args = self._resident_call(jnp.asarray(q_np), k, lo, hi,
-                                           bucket, delta_bucket, ok_np)
+                                           perm, bucket, delta_bucket,
+                                           ok_np)
             return fn(*args)
 
 

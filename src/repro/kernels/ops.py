@@ -109,15 +109,52 @@ def tanimoto_topk(queries: jax.Array, db: jax.Array, k: int,
                                metric if metric is not None else TANIMOTO)
 
 
+#: queries per grid step of the row-window kernel in a large batch: the
+#: fastest of 8, 16, 32 and 64 at 1,024 queries on a TPU v5e (PERF.md §6)
+WINDOW_QB = 32
+
+
+def window_block(q_n: int) -> int:
+    """Queries per block of the row-window kernel for a batch of ``q_n``:
+    :data:`WINDOW_QB`, or the whole batch when it fits in one block."""
+    return max(_query_block(q_n, WINDOW_QB)[0], 1)
+
+
+def window_blocks(lo, hi, qb: int, tile: int, xp=np):
+    """Tile window ``(lo_tile, n_tiles)`` of each block of ``qb``
+    consecutive queries: the tiles covering the union of the block's row
+    windows ``[lo, hi)``, empty windows (``hi <= lo``) ignored, ``(0, 0)``
+    for a block with none. The batch is padded to a block multiple with
+    empty windows. ``xp`` is numpy for the engine's host plan and
+    ``jax.numpy`` in :func:`_window_topk_impl`, so both size blocks
+    alike."""
+    pad = (-lo.shape[0]) % qb
+    live = hi > lo
+    lo_b = xp.pad(xp.where(live, lo, 2**31 - 1), (0, pad),
+                  constant_values=2**31 - 1).reshape(-1, qb).min(axis=1)
+    hi_b = xp.pad(xp.where(live, hi, 0), (0, pad)).reshape(-1, qb).max(axis=1)
+    any_live = hi_b > lo_b
+    lo_t = xp.where(any_live, lo_b // tile, 0)
+    n_t = xp.where(any_live, (hi_b + tile - 1) // tile - lo_t, 0)
+    return lo_t, n_t
+
+
 @functools.partial(jax.jit, static_argnames=("k", "max_tiles", "tile_n", "n_valid", "metric"))
-def _window_topk_impl(queries, db_p, cnt_p, lo_tile, n_tiles, lo_row, hi_row,
-                      k: int, max_tiles: int, tile_n: int, n_valid: int,
+def _window_topk_impl(queries, db_p, cnt_p, lo_row, hi_row, k: int,
+                      max_tiles: int, tile_n: int, n_valid: int,
                       metric: Metric = TANIMOTO):
+    q_n = queries.shape[0]
+    qb = window_block(q_n)
+    q_pad = round_up(q_n, qb)
+    lo_tile, n_tiles = window_blocks(lo_row, hi_row, qb, tile_n, xp=jnp)
+    n_tiles = jnp.clip(n_tiles, 0, max_tiles)
     ids, vals = ktk.windowed_fused_topk(
-        queries, db_p, cnt_p.reshape(1, -1), lo_tile, n_tiles, lo_row, hi_row,
-        k=k, max_tiles=max_tiles, n_valid=n_valid, tile_n=tile_n,
-        interpret=_interpret(), metric=metric)
-    return ids[:, :k], vals[:, :k]
+        _pad_to(queries, q_pad), db_p, cnt_p.reshape(1, -1), lo_tile,
+        n_tiles, _pad_to(lo_row, q_pad).reshape(q_pad, 1),
+        _pad_to(hi_row, q_pad).reshape(q_pad, 1), k=k, max_tiles=max_tiles,
+        n_valid=n_valid, qb=qb, tile_n=tile_n, interpret=_interpret(),
+        metric=metric)
+    return ids[:q_n, :k], vals[:q_n, :k]
 
 
 def window_topk(queries: jax.Array, db: jax.Array, db_cnt: jax.Array,
@@ -132,13 +169,20 @@ def window_topk(queries: jax.Array, db: jax.Array, db_cnt: jax.Array,
     id -1 / val -inf. Jit-compatible (callable from inside an enclosing jit)
     when k/max_tiles/tile_n are static.
 
-    HARD PRECONDITION: ``max_tiles`` (the static grid extent) must cover the
-    largest window in the batch — a window spanning more tiles is silently
-    truncated to its first ``max_tiles`` tiles and rows beyond it are never
-    scored (no error, no marker). The engine guarantees this by bucketing
-    ``max_tiles`` to a power of two >= the batch's max window; other callers
-    must size it the same way (the row bounds are traced values, so this
-    cannot be validated here)."""
+    Each grid step streams one tile for a block of :func:`window_block`
+    consecutive queries over the union of their windows
+    (:func:`window_blocks`), so queries sorted by popcount share their
+    tiles; every query still gets exactly its own window's top-k.
+
+    HARD PRECONDITION: ``max_tiles`` (the static grid extent) must cover
+    the union of each block's windows, in tiles, not only the widest single
+    window — a block spanning more tiles is silently truncated to its first
+    ``max_tiles`` tiles and rows beyond it are never scored (no error, no
+    marker). The default, every tile, is always safe. The engine sorts the
+    batch by popcount and buckets ``max_tiles`` to a power of two >= the
+    widest block union; other callers must size it the same way with
+    :func:`window_blocks` (the row bounds are traced values, so this cannot
+    be validated here)."""
     queries = jnp.asarray(queries)
     db = jnp.asarray(db)
     n = db.shape[0]
@@ -150,16 +194,11 @@ def window_topk(queries: jax.Array, db: jax.Array, db_cnt: jax.Array,
     if max_tiles is None:
         max_tiles = total_tiles
     max_tiles = max(min(max_tiles, total_tiles), 1)
-    lo_row = jnp.asarray(lo_row, dtype=jnp.int32)
-    hi_row = jnp.asarray(hi_row, dtype=jnp.int32)
-    lo_tile = lo_row // tile
-    n_tiles = jnp.where(hi_row > lo_row,
-                        (hi_row + tile - 1) // tile - lo_tile, 0)
-    n_tiles = jnp.clip(n_tiles, 0, max_tiles)
-    ids, vals = _window_topk_impl(queries, db_p, cnt_p, lo_tile, n_tiles,
-                                  lo_row, hi_row, k=k, max_tiles=max_tiles,
-                                  tile_n=tile, n_valid=n,
-                                  metric=metric if metric is not None else TANIMOTO)
+    ids, vals = _window_topk_impl(
+        queries, db_p, cnt_p, jnp.asarray(lo_row, dtype=jnp.int32),
+        jnp.asarray(hi_row, dtype=jnp.int32), k=k, max_tiles=max_tiles,
+        tile_n=tile, n_valid=n,
+        metric=metric if metric is not None else TANIMOTO)
     ids = jnp.where(jnp.isfinite(vals), ids, -1)
     return ids, vals
 

@@ -23,11 +23,12 @@ Design (FPGA -> TPU mapping):
   ``concat(running, tile)``: score descending, ties to the lower id.
 * Running top-k rows are padded to 128 lanes (``round_up(k, LANES)``) and
   sliced outside the kernel; count vectors are ``(1, N)`` lane-dense rows.
-* The windowed variant adds scalar-prefetched per-query tile windows
-  ``(lo_tile, n_tiles)``: the grid is sized for the *worst-case* window and
-  the ``index_map`` offsets DB tile fetches by ``lo_tile[q]`` — the TPU
+* The windowed variant adds scalar-prefetched per-block tile windows
+  ``(lo_tile, n_tiles)``, each covering the union of a query block's row
+  windows: the grid is sized for the *worst-case* block and the
+  ``index_map`` offsets DB tile fetches by ``lo_tile[b]`` — the TPU
   analogue of the FPGA engine fetching only the popcount-bounded address
-  range from HBM. Tiles beyond the query's window are skipped via
+  range from HBM. Tiles beyond the block's window are skipped via
   ``pl.when`` (fetch suppressed by clamping the index map to a single
   repeated tile). BitBound (Eq. 2) search is this kernel over the row range
   that the popcount window selects in the popcount-sorted database.
@@ -268,32 +269,36 @@ def fused_tanimoto_topk(queries: jax.Array, db: jax.Array, db_cnt: jax.Array,
 # row-window fused kernel (BitBound windows; stage 1 of the two-stage engine)
 # ---------------------------------------------------------------------------
 #
-# Scalar-prefetched tile streaming over an explicit per-query row interval
+# Scalar-prefetched tile streaming over explicit per-query row intervals
 # [lo_row, hi_row). On a popcount-sorted database the Eq.2 popcount window
 # IS such an interval, and it is what the folded stage-1 scan needs: the
 # window is defined on *full-resolution* popcounts (the sort key of the
-# database), while the streamed tiles may hold the *folded* prints. Queries
-# have different windows, so each grid step serves one query.
+# database), while the streamed tiles may hold the *folded* prints. A grid
+# step serves a block of ``qb`` queries: it streams one tile of the union
+# of their windows and each row masks the tile to its own window. Eq.2
+# windows are monotone in the query popcount, so on popcount-sorted
+# queries a block's union is barely wider than any one member's window
+# and one tile stream serves the whole block.
 
 def _window_body(lo_t_ref, nt_ref, lo_ref, hi_ref, q_ref, db_ref,
                  dbcnt_ref, ids_ref, vals_ref, top_s, top_i,
                  *, k: int, tile_n: int, max_tiles: int, n_valid: int,
                  metric: Metric = TANIMOTO):
-    qi = pl.program_id(0)
+    b = pl.program_id(0)
     t = pl.program_id(1)
 
     @pl.when(t == 0)
     def _init():
         init_topk(top_s, top_i)
 
-    @pl.when(t < nt_ref[qi])
+    @pl.when(t < nt_ref[b])
     def _scan():
-        q = q_ref[...]                                 # (1, W)
-        inter = tile_inter(q, db_ref[...])             # (1, tile_n)
+        q = q_ref[...]                                 # (qb, W)
+        inter = tile_inter(q, db_ref[...])             # (qb, tile_n)
         s = metric_from_counts(metric, inter, popcount_rows(q),
                                dbcnt_ref[...])
-        idx = (lo_t_ref[qi] + t) * tile_n + _iota((1, tile_n), 1)
-        ok = (idx >= lo_ref[qi]) & (idx < hi_ref[qi]) & (idx < n_valid)
+        idx = (lo_t_ref[b] + t) * tile_n + _iota((1, tile_n), 1)
+        ok = (idx >= lo_ref[...]) & (idx < hi_ref[...]) & (idx < n_valid)
         merge_topk(top_s, top_i, jnp.where(ok, s, NEG), idx, k)
 
     @pl.when(t == max_tiles - 1)
@@ -303,68 +308,74 @@ def _window_body(lo_t_ref, nt_ref, lo_ref, hi_ref, q_ref, db_ref,
 
 
 def windowed_fused_topk(queries: jax.Array, db: jax.Array, db_cnt: jax.Array,
-                        lo_tile: jax.Array, n_tiles_q: jax.Array,
+                        lo_tile: jax.Array, n_tiles_b: jax.Array,
                         lo_row: jax.Array, hi_row: jax.Array, k: int,
-                        max_tiles: int, n_valid: int,
+                        max_tiles: int, n_valid: int, qb: int,
                         tile_n: int = DEFAULT_TILE_N, interpret: bool = False,
                         metric: Metric = TANIMOTO):
-    """Scan only rows [lo_row[q], hi_row[q]) of ``db`` for each query.
+    """Scan only rows [lo_row[q], hi_row[q]) of ``db`` for each query,
+    ``qb`` queries per grid step.
 
-    queries (Q, W) u32; db (N_pad, W) u32 and db_cnt (1, N_pad) i32 padded
-    to a tile multiple; lo_tile, n_tiles_q: (Q,) int32 tile window covering
-    the row interval; lo_row, hi_row: (Q,) int32 exact row bounds (boundary
-    rows of partially covered tiles are masked). ``db`` may be the folded
-    database while the bounds come from the full-resolution popcount sort.
-    Returns ids into the (sorted) DB and values, both (Q, k_pad); empty
-    slots are id -1 / val -inf."""
+    queries (Q, W) u32 with Q a multiple of ``qb`` (``qb`` a multiple of 8,
+    or ``qb == Q``); db (N_pad, W) u32 and db_cnt (1, N_pad) i32 padded to a
+    tile multiple; lo_tile, n_tiles_b: (Q / qb,) int32 tile window of each
+    block, covering the union of its queries' row intervals; lo_row,
+    hi_row: (Q, 1) int32 exact row bounds of each query (rows outside them,
+    in the block's tiles, are masked). ``db`` may be the folded database
+    while the bounds come from the full-resolution popcount sort. Returns
+    ids into the (sorted) DB and values, both (Q, k_pad); empty slots are
+    id -1 / val -inf."""
     q_n, w = queries.shape
     n_pad = db.shape[0]
+    assert q_n % qb == 0, (q_n, qb)
     total_tiles = n_pad // tile_n
     kp = round_up(k, LANES)
 
-    def db_index(q, t, lo_t, nt, lo, hi):
-        blk = jnp.where(t < nt[q], lo_t[q] + t, lo_t[q])
+    def db_index(b, t, lo_t, nt):
+        blk = jnp.where(t < nt[b], lo_t[b] + t, lo_t[b])
         return (jnp.minimum(blk, total_tiles - 1), 0)
 
-    def cnt_index(q, t, lo_t, nt, lo, hi):
-        blk = jnp.where(t < nt[q], lo_t[q] + t, lo_t[q])
+    def cnt_index(b, t, lo_t, nt):
+        blk = jnp.where(t < nt[b], lo_t[b] + t, lo_t[b])
         return (0, jnp.minimum(blk, total_tiles - 1))
 
-    def row_index(q, t, lo_t, nt, lo, hi):
-        return (q, 0, 0)
+    def row_index(b, t, lo_t, nt):
+        return (b, 0)
 
     body = functools.partial(_window_body, k=k, tile_n=tile_n,
                              max_tiles=max_tiles, n_valid=n_valid,
                              metric=metric)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
-        grid=(q_n, max_tiles),
+        num_scalar_prefetch=2,
+        grid=(q_n // qb, max_tiles),
         in_specs=[
-            pl.BlockSpec((None, 1, w), row_index),
-            pl.BlockSpec((tile_n, w), db_index),
-            pl.BlockSpec((1, tile_n), cnt_index),
+            pl.BlockSpec((qb, 1), row_index),                # lo_row
+            pl.BlockSpec((qb, 1), row_index),                # hi_row
+            pl.BlockSpec((qb, w), row_index),                # query block
+            pl.BlockSpec((tile_n, w), db_index),             # DB tile stream
+            pl.BlockSpec((1, tile_n), cnt_index),            # DB popcounts
         ],
         out_specs=[
-            pl.BlockSpec((None, 1, kp), row_index),
-            pl.BlockSpec((None, 1, kp), row_index),
+            pl.BlockSpec((qb, kp), row_index),
+            pl.BlockSpec((qb, kp), row_index),
         ],
         scratch_shapes=[
-            pltpu.VMEM((1, kp), jnp.float32),
-            pltpu.VMEM((1, kp), jnp.int32),
+            pltpu.VMEM((qb, kp), jnp.float32),
+            pltpu.VMEM((qb, kp), jnp.int32),
         ],
     )
     ids, vals = pl.pallas_call(
         body,
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((q_n, 1, kp), jnp.int32),
-            jax.ShapeDtypeStruct((q_n, 1, kp), jnp.float32),
+            jax.ShapeDtypeStruct((q_n, kp), jnp.int32),
+            jax.ShapeDtypeStruct((q_n, kp), jnp.float32),
         ],
         interpret=interpret,
-    )(lo_tile.astype(jnp.int32), n_tiles_q.astype(jnp.int32),
-      lo_row.astype(jnp.int32), hi_row.astype(jnp.int32),
-      queries.reshape(q_n, 1, w), db, db_cnt)
-    return ids.reshape(q_n, kp), vals.reshape(q_n, kp)
+    )(lo_tile.astype(jnp.int32), n_tiles_b.astype(jnp.int32),
+      lo_row.astype(jnp.int32), hi_row.astype(jnp.int32), queries, db,
+      db_cnt)
+    return ids, vals
 
 
 # ---------------------------------------------------------------------------

@@ -22,6 +22,13 @@ REQUIRED_METRIC_FAMILIES = (
     "service_scanned_total",
 )
 
+#: families a service with a BitBound engine on the row-window kernel
+#: (``backend="tpu"``) additionally exports: the kernel's per-query window
+#: tiles against the query-block union tiles its grid streams
+BITBOUND_METRIC_FAMILIES = (
+    "service_stage1_tiles_total",
+)
+
 #: families the concurrent front end (ISSUE 9) must additionally export —
 #: CI's overload smoke passes these via ``--require-family`` (note that
 #: flag *replaces* the service floor, so callers list both sets)

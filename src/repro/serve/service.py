@@ -181,6 +181,11 @@ class SearchService:
             "service_inserts_total", "fingerprint rows inserted")
         self._m_scanned = m.counter(
             "service_scanned_total", "candidates scored", labels=("engine",))
+        self._m_stage1_tiles = m.counter(
+            "service_stage1_tiles_total",
+            "row-window kernel tiles: per-query windows (kind=window), "
+            "query-block unions streamed (kind=streamed)",
+            labels=("engine", "kind"))
         self._m_batches = m.counter(
             "service_batches_total", "engine flush batches",
             labels=("engine", "bucket"))
@@ -359,6 +364,11 @@ class SearchService:
             sc = eng.scanned(bucket)
             self.scanned_total[ename] += sc
             self._m_scanned.inc(sc, engine=ename)
+            window, streamed = eng.stage1_tiles()
+            if streamed:
+                self._m_stage1_tiles.inc(window, engine=ename, kind="window")
+                self._m_stage1_tiles.inc(streamed, engine=ename,
+                                         kind="streamed")
             self._fold_engine_stats(ename, eng)
             off += chunk.shape[0]
         dt = self.clock() - t0

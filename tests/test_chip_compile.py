@@ -94,10 +94,13 @@ def test_bitbound_window_compiles(one_chip, mosaic, q_n):
              _spec(one_chip, (q_n,), jnp.int32))
 
 
-@pytest.mark.parametrize("q_n", [8, 64])
+@pytest.mark.parametrize("q_n", [1, 20, 8, 64, 1024])
 def test_stage1_window_compiles(one_chip, mosaic, q_n):
     """Stage 1 of the BitBound+folding engine: the folded (m=4) capacity-
-    padded main segment, k1 = k*m*log2(2m) candidates, a half-DB window."""
+    padded main segment, k1 = k*m*log2(2m) candidates, a half-DB window
+    (512 tiles of 2048). A batch of up to 32 is one block of its own size
+    (1: a served query; 20: not a multiple of the sublane tile); 1024
+    queries is the screen cell's batch, 32 blocks of 32."""
     m = CHEMBL_LIKE.folding_m
     k1 = fl.kr1_for(K, m)
     wf = fl.folded_words(W, m)
@@ -190,6 +193,7 @@ def test_bitbound_engine_pipeline_fits(one_chip, mosaic):
     fn = eng._build_device_search(total_tiles, K, 0)
     wf = fl.folded_words(W, m)
     _fits(_compile(fn, _spec(one_chip, (64, W), jnp.uint32),
+                   _spec(one_chip, (64,), jnp.int32),
                    _spec(one_chip, (64,), jnp.int32),
                    _spec(one_chip, (64,), jnp.int32),
                    _spec(one_chip, (CAP, wf), jnp.uint32),

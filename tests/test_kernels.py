@@ -70,14 +70,23 @@ def test_bitbound_kernel_matches_oracle(cutoff, tile):
 
 
 def test_bitbound_kernel_restricted_window_grid():
-    """max_tiles below the full DB: still exact when windows fit."""
+    """max_tiles below the full DB: still exact when the block's windows
+    fit. The grid is sized as the engine sizes it, to the power-of-two
+    bucket of the block's union of Eq.2 windows."""
     db = _db(4096, seed=2)
-    qs = jnp.asarray(queries_from_db(db, 3))
     idx = bb.build_index(jnp.asarray(db))
+    counts = np.asarray(idx.counts)
+    qs = idx.db[jnp.asarray([800, 500, 650])]       # unsorted popcounts
+    lo, hi = bb.bound_range_np(counts, np.asarray(popcount(qs)), 0.8)
+    _, n_t = ops.window_blocks(lo, hi, ops.window_block(3), 256)
+    max_tiles = bb.bucket_tiles(n_t.max(), 16)
+    assert max_tiles < 16
     ids, vals = _bitbound_topk(qs, idx.db, idx.counts, k=10, cutoff=0.8,
-                               tile_n=256, max_tiles=8)
-    _, rvals = ref.bitbound_topk_ref(qs, idx.db, idx.counts, k=10, cutoff=0.8)
-    np.testing.assert_allclose(np.asarray(vals), np.asarray(rvals), rtol=1e-6)
+                               tile_n=256, max_tiles=max_tiles)
+    rids, rvals = ref.bitbound_topk_ref(qs, idx.db, idx.counts, k=10,
+                                        cutoff=0.8)
+    np.testing.assert_array_equal(np.asarray(vals), np.asarray(rvals))
+    np.testing.assert_array_equal(np.asarray(ids), np.asarray(rids))
 
 
 # -- BitBound kernel edge cases (interpret mode vs ref.py) -------------------
@@ -170,17 +179,32 @@ def test_window_kernel_matches_oracle():
     np.testing.assert_array_equal(np.asarray(ids) < 0, np.asarray(rids) < 0)
 
 
-def test_window_kernel_restricted_max_tiles():
-    """Static grid smaller than the full DB stays exact when windows fit."""
+@pytest.mark.parametrize("case", ["block", "per_query"])
+def test_window_kernel_restricted_max_tiles(case):
+    """Static grid smaller than the full DB stays exact when each block's
+    windows fit. ``block``: three queries are one block, whose union, rows
+    [0, 1200), is 5 tiles of 256. ``per_query``: disjoint windows spanning
+    16 tiles together; each query alone is a block of one and needs only
+    its own window (at most 5 tiles) to fit."""
     db = _db(4096, seed=9)
     qs = jnp.asarray(queries_from_db(db, 3))
     idx = bb.build_index(jnp.asarray(db))
-    lo = jnp.asarray([0, 1000, 3000], jnp.int32)
-    hi = jnp.asarray([900, 2000, 4096], jnp.int32)
-    ids, vals = ops.window_topk(qs, idx.db, idx.counts, lo, hi, k=7,
-                                tile_n=256, max_tiles=5)
-    _, rvals = ref.window_topk_ref(qs, idx.db, idx.counts, lo, hi, k=7)
-    np.testing.assert_allclose(np.asarray(vals), np.asarray(rvals), rtol=1e-6)
+    if case == "block":
+        lo = jnp.asarray([0, 300, 600], jnp.int32)
+        hi = jnp.asarray([900, 1100, 1200], jnp.int32)
+        ids, vals = ops.window_topk(qs, idx.db, idx.counts, lo, hi, k=7,
+                                    tile_n=256, max_tiles=5)
+    else:
+        lo = jnp.asarray([0, 1000, 3000], jnp.int32)
+        hi = jnp.asarray([900, 2000, 4096], jnp.int32)
+        got = [ops.window_topk(qs[i:i + 1], idx.db, idx.counts,
+                               lo[i:i + 1], hi[i:i + 1], k=7, tile_n=256,
+                               max_tiles=5) for i in range(3)]
+        ids = jnp.concatenate([g[0] for g in got])
+        vals = jnp.concatenate([g[1] for g in got])
+    rids, rvals = ref.window_topk_ref(qs, idx.db, idx.counts, lo, hi, k=7)
+    np.testing.assert_array_equal(np.asarray(vals), np.asarray(rvals))
+    np.testing.assert_array_equal(np.asarray(ids), np.asarray(rids))
 
 
 def test_window_kernel_folded_db_full_bounds():
@@ -267,16 +291,50 @@ def test_fused_topk_layout_cases_bitwise(q, k, qb):
     np.testing.assert_array_equal(np.asarray(ids), np.asarray(rids))
 
 
-@pytest.mark.parametrize("q,k", [(11, 20), (3, 130)])
-def test_window_topk_layout_cases_bitwise(q, k):
+def _layout_windows(case: str, q: int, rng):
+    """Row windows over 2,000 rows for the window-kernel layout cases."""
+    lo = rng.integers(0, 1500, q)
+    hi = np.minimum(lo + rng.integers(0, 2000, q), 2000)
+    if case == "disjoint":     # neighbours alternate between the two ends
+        lo = np.where(np.arange(q) % 2 == 0, 0, 1700) + rng.integers(0, 50, q)
+        hi = lo + rng.integers(1, 250, q)
+    elif case == "empty":      # every third window empty, some with hi < lo
+        hi[::3] = lo[::3] - rng.integers(0, 40, len(lo[::3]))
+    elif case == "wide_union":  # windows of <= 3 tiles whose block spans 16
+        lo = (np.arange(q) * 250) % 1800
+        hi = lo + 200
+    return lo, hi
+
+
+@pytest.mark.parametrize("q,k,case", [
+    pytest.param(11, 20, "random", id="11-20"),
+    pytest.param(3, 130, "random", id="3-130"),
+    (1, 20, "random"),         # one query: a one-row block
+    (5, 20, "random"),         # below the sublane tile: one 5-row block
+    (8, 20, "random"),         # exactly one sublane tile
+    (9, 20, "random"),         # one past it: one 9-row block
+    (20, 20, "random"),        # one 20-row block, not a multiple of 8
+    (32, 20, "random"),        # exactly one full block
+    (40, 20, "random"),        # two 32-row blocks, the second padded
+    (9, 20, "disjoint"),       # unsorted, disjoint windows in one block
+    (40, 20, "empty"),         # empty windows mixed into both blocks
+    (16, 20, "wide_union"),    # block union needs a larger bucket
+])
+def test_window_topk_layout_cases_bitwise(q, k, case):
+    """Bitwise equal to the oracle with the grid sized as the engine sizes
+    it: the power-of-two bucket of the largest block union, in tiles."""
     db = _tied_db(2000, seed=12)
     idx = bb.build_index(jnp.asarray(db))
     qs = jnp.asarray(queries_from_db(db, q, seed=4))
-    rng = np.random.default_rng(q)
-    lo = rng.integers(0, 1500, q)
-    hi = np.minimum(lo + rng.integers(0, 2000, q), 2000)
+    lo, hi = _layout_windows(case, q, np.random.default_rng(q))
+    _, n_t = ops.window_blocks(lo, hi, ops.window_block(q), 128)
+    max_tiles = bb.bucket_tiles(n_t.max(), 16)
+    if case == "wide_union":
+        single = np.where(hi > lo, (hi + 127) // 128 - lo // 128, 0)
+        assert max_tiles > bb.bucket_tiles(single.max(), 16)
     ids, vals = ops.window_topk(qs, idx.db, idx.counts, jnp.asarray(lo),
-                                jnp.asarray(hi), k=k, tile_n=128)
+                                jnp.asarray(hi), k=k, tile_n=128,
+                                max_tiles=max_tiles)
     rids, rvals = ref.window_topk_ref(qs, idx.db, idx.counts,
                                       jnp.asarray(lo), jnp.asarray(hi), k=k)
     np.testing.assert_array_equal(np.asarray(vals), np.asarray(rvals))

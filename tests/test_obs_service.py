@@ -19,6 +19,9 @@ from repro.core import BitBoundFoldingEngine, BruteForceEngine
 from repro.data.molecules import (SyntheticConfig, queries_from_db,
                                   synthetic_fingerprints)
 from repro.obs import trace as obs_trace
+from repro.obs.schema import (BITBOUND_METRIC_FAMILIES,
+                               REQUIRED_METRIC_FAMILIES,
+                               validate_metrics_jsonl)
 from repro.obs.trace import TRACER
 from repro.serve import SearchService
 from repro.serve.frontend import FrontendConfig, SearchFrontend
@@ -141,6 +144,39 @@ def test_device_residency_leaves_tier_gauges_empty(data):
     # no tiered child was ever materialized on the device-resident path
     assert m.family("service_tiered_chunks").value(engine="brute") == 0
     assert all(r["name"] != "service_tiered_chunks" for r in m.collect())
+
+
+def test_stage1_tile_counter(data, tmp_path):
+    """``service_stage1_tiles_total``: the row-window kernel's streamed
+    query-block union tiles never exceed the per-query window tiles they
+    serve, and equal them for a one-query batch; the brute engine, which
+    has no such kernel, adds no child; the export carries the family."""
+    db, _, q = data
+    svc = SearchService(db, engines=("bitbound-folding", "brute"),
+                        backend="tpu", k=K, cutoff=0.6, fold_m=2)
+    fam = svc.metrics.family("service_stage1_tiles_total")
+
+    def tiles():
+        return (fam.value(engine="bitbound-folding", kind="window"),
+                fam.value(engine="bitbound-folding", kind="streamed"))
+
+    svc.submit(q[:1], engine="bitbound-folding")
+    svc.flush()
+    window1, streamed1 = tiles()
+    assert window1 == streamed1 > 0
+    svc.submit(q[:16], engine="bitbound-folding")
+    svc.submit(q[:4], engine="brute")
+    svc.flush()
+    window, streamed = tiles()
+    assert 0 < streamed - streamed1 <= window - window1
+    assert {r["labels"]["engine"] for r in svc.metrics.collect()
+            if r["name"] == "service_stage1_tiles_total"} \
+        == {"bitbound-folding"}
+    out = tmp_path / "metrics.jsonl"
+    svc.metrics.export_jsonl(out)
+    assert validate_metrics_jsonl(
+        out, require_families=REQUIRED_METRIC_FAMILIES
+        + BITBOUND_METRIC_FAMILIES) == []
 
 
 def test_tiered_trace_shows_double_buffer_overlap(data, tmp_path):
