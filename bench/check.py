@@ -11,10 +11,24 @@ numbers compared, each held to the limit the configuration file states:
 - ``ryw_violations``: read-backs, submitted after their insert was acked,
   whose answer does not hold the inserted row (read-your-writes).
 
+A configuration whose engine answers approximately says so by a
+``recall_shortfall`` limit. Its sampled answers are not held to the
+reference's answer but to the reference's exact scores, and ``mismatched``
+gives way to two numbers:
+
+- ``misscored``: sampled answers with a returned score that is not, bit
+  for bit, the reference's score of that query and row, an id that repeats
+  or lies outside the visible rows, scores that rise, or padding (id -1,
+  score 0) anywhere but at the tail;
+- ``recall_shortfall``: 1 - recall@k over the sampled answers. A returned
+  row is a hit when its exact score reaches the query's exact k-th best
+  (ties at the boundary count); the denominator is the sum over answers of
+  min(k, visible rows).
+
 An insert whose ack had not come back when a query was submitted may or may
 not be visible to it: a query is matched against the reference over every
 prefix of the inserts from those acked before it was submitted to those
-called before it completed.
+called before it completed, and the prefix that answers it best counts.
 """
 from __future__ import annotations
 
@@ -84,6 +98,58 @@ def ryw_violations(win: Window, n_fixed: int) -> int:
     return bad
 
 
+def approximate(cfg: dict) -> bool:
+    """Whether the configuration's engine answers approximately."""
+    return "recall_shortfall" in cfg["limits"]
+
+
+def grade(reference, q: np.ndarray, ids, sims,
+          visible: int) -> tuple[bool, int, int]:
+    """One approximate answer over rows ``[0, visible)``: whether it is
+    misscored, its hits, and the hits it could have had."""
+    ids = np.asarray(ids, np.int64)
+    sims = np.asarray(sims, np.float32)
+    k = reference.k
+    possible = min(k, visible)
+    if ids.shape != (k,) or sims.shape != (k,):
+        return True, 0, possible
+    real = ids != -1
+    n_real = int(real.sum())
+    valid = real & (ids >= 0) & (ids < visible)
+    got = ids[valid]
+    exact = reference.score(q, got)
+    bad = (bool(real[n_real:].any())              # padding before a row
+           or bool((sims[~real] != 0).any())
+           or len(got) != n_real                   # an id out of range
+           or len(np.unique(got)) != n_real        # an id twice
+           or bool((np.diff(sims) > 0).any())
+           or not np.array_equal(sims[valid].view(np.uint32),
+                                 exact.view(np.uint32)))
+    if not possible:
+        return bad, 0, 0
+    kth = reference.answer(q, visible)[1][possible - 1]
+    _, first = np.unique(got, return_index=True)
+    return bad, int((exact[first] >= kth).sum()), possible
+
+
+def grade_all(reference, library, fresh, items) -> tuple[int, int, int]:
+    """``misscored``, hits and possible hits over ``items``, each at the
+    visible prefix that answers it best."""
+    def one(i):
+        (src, row), ids, sims, lo, hi, _ = items[i]
+        q = library[row] if src == "lib" else fresh[row]
+        return min((grade(reference, q, ids, sims, visible)
+                    for visible in range(lo, hi + 1)),
+                   key=lambda g: (g[0], 1 - g[1] / g[2] if g[2] else 0.0))
+    got = oracle.map_queries(one, len(items))
+    return tuple(sum(g[i] for g in got) for i in range(3))
+
+
+def shortfall(hits: int, possible: int) -> float:
+    """1 - recall; a sample that could hold no hit shows no recall."""
+    return 1.0 - hits / possible if possible else 1.0
+
+
 def check(Reference, cfg: dict, traffic: dict, win: Window,
           library: np.ndarray, fresh: np.ndarray, n_preload: int,
           seed: int) -> dict:
@@ -94,10 +160,16 @@ def check(Reference, cfg: dict, traffic: dict, win: Window,
     ref = Reference(rows, cfg)
     n_fixed = len(library) + n_preload
     items = _items(win, n_fixed, int(traffic["check_sample"]), seed)
-    out = {"mismatched": compare(ref, library, fresh[n_preload:], items),
-           "lost": sum(op.error == "lost" for op in win.ops)
-           + sum(b["error"] is not None and "Timeout" in b["error"]
-                 for b in win.batches)}
+    if approximate(cfg):
+        bad, hits, possible = grade_all(ref, library, fresh[n_preload:],
+                                        items)
+        out = {"misscored": bad,
+               "recall_shortfall": shortfall(hits, possible)}
+    else:
+        out = {"mismatched": compare(ref, library, fresh[n_preload:], items)}
+    out["lost"] = (sum(op.error == "lost" for op in win.ops)
+                   + sum(b["error"] is not None and "Timeout" in b["error"]
+                         for b in win.batches))
     if win.ops:
         out["ryw_violations"] = ryw_violations(win, n_fixed)
     return {"numbers": out, "checked": len(items),
@@ -124,6 +196,43 @@ def control_mismatches(Reference, cfg: dict, library: np.ndarray,
         return 0 if same(*low.answer(q, visible),
                          *exact.answer(q, visible)) else 1
     return sum(oracle.map_queries(one, len(items)))
+
+
+def degraded(wide, k: int, q: np.ndarray, visible: int):
+    """The exact top-k with its best ceil(k/4) rows swapped for the best
+    rows that score below the k-th, at their true scores: an answer that
+    misses a quarter of the true neighbours (rows tied with the k-th would
+    be hits, so none of them stands in). ``wide`` is the reference at 4k."""
+    c = -(-k // 4)
+    ids, sims = wide.answer(q, visible)
+    kth = sims[min(k, visible) - 1]
+    below = np.flatnonzero((ids != -1) & (sims < kth))[:c]
+    keep = np.r_[np.arange(c, min(k, visible)), below]
+    return oracle.pad_answer(ids[keep], sims[keep], k)
+
+
+def control_approximate(Reference, cfg: dict, library: np.ndarray, items,
+                        precision: str = "bf16") -> dict:
+    """The approximate check's controls over ``items``, (library row,
+    visible) pairs: ``misscored`` with the reference computed in
+    ``precision`` put in the program's place, and ``recall_shortfall`` of
+    the degraded exact answers (``degraded``)."""
+    k = int(cfg["k"])
+    exact = Reference(library, cfg)
+    low = Reference(library, cfg, precision)
+    wide = Reference(library, dict(cfg, k=4 * k))
+
+    def one(i):
+        row, visible = items[i]
+        q = library[row]
+        low_bad = grade(exact, q, *low.answer(q, visible), visible)[0]
+        return (low_bad,) + grade(exact, q, *degraded(wide, k, q, visible),
+                                  visible)
+    got = oracle.map_queries(one, len(items))
+    return {"misscored": sum(g[0] for g in got),
+            "degraded_misscored": sum(g[1] for g in got),
+            "recall_shortfall": shortfall(sum(g[2] for g in got),
+                                          sum(g[3] for g in got))}
 
 
 def finite(x: float) -> float:

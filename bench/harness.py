@@ -18,7 +18,7 @@ import os
 import shutil
 import sys
 import time
-from dataclasses import dataclass, field
+from dataclasses import dataclass, field, fields, replace
 from pathlib import Path
 
 import numpy as np
@@ -168,9 +168,13 @@ def registry_delta(before: dict, after: dict) -> dict:
     return out
 
 
-def build_frontend(cell: Cell, library: np.ndarray):
-    from repro.serve import FrontendConfig, SearchFrontend, ServiceConfig
-    from repro.serve.frontend import DegradeLevel
+def service_config(cell: Cell):
+    """The service's knobs: the fields the configuration and the traffic
+    state, then the configuration's ``engine_params``, an object of
+    ``ServiceConfig`` fields (the engine's own parameters, such as HNSW's
+    M, efConstruction and efSearch). A key that is no such field is an
+    error."""
+    from repro.serve import ServiceConfig
     cfg, tr = cell.config, cell.traffic
     svc = ServiceConfig(
         backend="tpu", metric=cfg.get("metric", "tanimoto"),
@@ -181,6 +185,19 @@ def build_frontend(cell: Cell, library: np.ndarray):
         fold_m=int(cfg.get("fold_m", 1)),
         fold_scheme=int(cfg.get("fold_scheme", 1)),
         residency=cfg["residency"])
+    params = cfg.get("engine_params", {})
+    unknown = sorted(set(params) - {f.name for f in fields(ServiceConfig)})
+    if unknown:
+        raise ValueError(f"engine_params of {cell.config_name}: {unknown} "
+                         "are not ServiceConfig fields")
+    return replace(svc, **params)
+
+
+def build_frontend(cell: Cell, library: np.ndarray):
+    from repro.serve import FrontendConfig, SearchFrontend
+    from repro.serve.frontend import DegradeLevel
+    cfg, tr = cell.config, cell.traffic
+    svc = service_config(cell)
     f = tr["frontend"]
     fcfg = FrontendConfig(
         replicas=1, high_water=int(f["high_water"]),
