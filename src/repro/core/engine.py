@@ -1290,11 +1290,17 @@ class HNSWEngine(SearchEngine):
     extra ``2M*W``-word HBM copy is split N ways (the roofline budget in
     ``benchmarks/roofline.py --gather``).
 
-    Online inserts go through :func:`repro.core.hnsw.insert_hnsw` (batched
-    incremental construction, rng-continuation levels), so an engine that
-    inserted online is graph-identical to one rebuilt from scratch on the
-    concatenated database. The device graph is padded to a power-of-two node
-    capacity: inserts below the capacity reuse every compiled traversal, and
+    The graph is built on the device in rounds of at most ``build_round``
+    rows (:func:`repro.core.hnsw.build_hnsw`); the built adjacency becomes
+    the device graph as it stands, with no second upload, and
+    :attr:`build_stats` holds the build's rows, seconds and rounds. Online
+    inserts go through :func:`repro.core.hnsw.insert_hnsw` (one node at a
+    time on the host, rng-continuation levels), so an engine that inserted
+    online is graph-identical to one built by the same bulk build over the
+    same first rows followed by the same inserts: where the bulk build
+    stopped is part of what defines the graph. The device graph is padded
+    to a power-of-two node capacity: inserts below the capacity reuse
+    every compiled traversal, and
     the post-insert refresh is **incremental** — only new fingerprint rows
     and the base-adjacency / neighbour-block rows the batch touched
     (``index.dirty_log``) are scattered into the device arrays; a full
@@ -1328,6 +1334,9 @@ class HNSWEngine(SearchEngine):
     #: index built under a different metric raises up front
     metric: Metric | str | None = None
     fp_bits: int | None = None
+    #: the bulk build's largest round (rows inserted together); part of
+    #: what defines the graph, as ``seed`` is
+    build_round: int = hn.DEFAULT_BUILD_ROUND
 
     BACKENDS = ("numpy", "jnp", "tpu")
     DEFAULT_BACKEND = "jnp"
@@ -1343,6 +1352,7 @@ class HNSWEngine(SearchEngine):
 
     def __post_init__(self):
         self._init_engine()
+        self.build_stats: dict = {}
         if self.metric is None and self.index is not None:
             self.metric = getattr(self.index, "metric", TANIMOTO)
         if self.metric is None and self.shard_indexes:
@@ -1365,10 +1375,12 @@ class HNSWEngine(SearchEngine):
                     self._check_index_metric(ix)
                 self._shard_indexes = list(self.shard_indexes)
             else:
+                t0 = time.perf_counter()
                 self._shard_indexes = hn.build_hnsw_sharded(
                     np.asarray(self.db), self.shards, m=self.m,
                     ef_construction=self.ef_construction, seed=self.seed,
-                    metric=self.metric)
+                    metric=self.metric, build_round=self.build_round)
+                self._built(self._shard_indexes, time.perf_counter() - t0)
             self._resolve_metric_width(self._shard_indexes[0].db.shape[1])
             # the numpy backend never touches a device — don't init jax
             self._shard_devices = (None if self.backend == "numpy"
@@ -1379,9 +1391,12 @@ class HNSWEngine(SearchEngine):
             self._refresh_shard_graphs()
             return
         if self.index is None:
+            t0 = time.perf_counter()
             self.index = hn.build_hnsw(np.asarray(self.db), m=self.m,
                                        ef_construction=self.ef_construction,
-                                       seed=self.seed, metric=self.metric)
+                                       seed=self.seed, metric=self.metric,
+                                       build_round=self.build_round)
+            self._built([self.index], time.perf_counter() - t0)
         else:
             self._check_index_metric(self.index)
         self._resolve_metric_width(self.index.db.shape[1])
@@ -1392,6 +1407,13 @@ class HNSWEngine(SearchEngine):
         self._upper_version = 0    # index.upper_version the graph carries
         self._insert_db_cache = None  # (device db lines, rows filled)
         self._refresh_graph()
+
+    def _built(self, indexes: list, seconds: float) -> None:
+        self.build_stats = {
+            "rows": sum(ix.bulk_rows for ix in indexes),
+            "seconds": float(seconds),
+            "rounds": sum(len(hn.build_schedule(ix.bulk_rows, ix.build_round))
+                          for ix in indexes)}
 
     @property
     def n_total(self) -> int:
@@ -1404,6 +1426,8 @@ class HNSWEngine(SearchEngine):
         padded capacity doubled; untouched shards keep their device copy."""
         if self.backend == "numpy":
             self._shard_dirty = [False] * self.shards
+            for ix in self._shard_indexes:
+                ix.device_build = None
             return
         np2 = _store_mod().next_pow2
         for s, idx in enumerate(self._shard_indexes):
@@ -1416,12 +1440,14 @@ class HNSWEngine(SearchEngine):
                 hn.to_device_graph(idx, capacity=cap, layout=self.layout,
                                    kernel=self.backend == "tpu"),
                 self._shard_devices[s])
+            idx.device_build = None
             self._shard_dirty[s] = False
 
     def _refresh_graph(self) -> None:
         # the numpy backend never touches the device — don't ship the graph
         if self.backend == "numpy":
             self._graph = None
+            self.index.device_build = None
             return
         idx = self.index
         cap = _store_mod().next_pow2(idx.n)
@@ -1433,6 +1459,7 @@ class HNSWEngine(SearchEngine):
             self._graph = hn.to_device_graph(idx, capacity=cap,
                                              layout=self.layout,
                                              kernel=self.backend == "tpu")
+            idx.device_build = None    # the graph holds what it needs
         else:
             # incremental refresh: scatter only the rows the insert batches
             # touched (new fingerprints + dirty base-adjacency rows and, on
@@ -1548,7 +1575,8 @@ class HNSWEngine(SearchEngine):
         key = (k, ef, beam, max_level, use_kernel, layout, metric)
 
         def build():
-            def run(q, arrays):
+            # the traversal's program is ``jit_hnsw_traverse`` in a trace
+            def hnsw_traverse(q, arrays):
                 g = arrays._replace(max_level=max_level)
                 score_fn = None
                 expand_fn = None
@@ -1572,7 +1600,7 @@ class HNSWEngine(SearchEngine):
                 return hn.search_hnsw(g, q, k, ef, max_iters=max_iters,
                                       beam=beam, score_fn=score_fn,
                                       expand_fn=expand_fn, metric=metric)
-            return jax.jit(run)
+            return jax.jit(hnsw_traverse)
         return self._cached(key, build)
 
     def _search_sharded(self, queries, k: int, ef: int, beam: int):

@@ -1,9 +1,12 @@
 """HNSW over Tanimoto similarity — paper §III-C / §IV-B.
 
-* Graph **construction** is host-side numpy (as in the paper: hnswlib builds
-  on CPU; the FPGA/TPU accelerates *search*). Heuristic neighbour selection
-  (Malkov & Yashunin Alg. 4) with the long-range-link property the paper
-  credits for HNSW's recall.
+* Graph **construction** is batched on the device (:func:`build_hnsw`): the
+  library goes in in rounds of consecutive ids, each round searched against
+  the graph built so far on the same lock-step traversal as search, scored
+  against itself by brute force, linked with a batched Alg. 4 heuristic
+  (Malkov & Yashunin) and its reverse links grouped by target. Online
+  inserts (:func:`insert_hnsw`) stay on the host, one node at a time
+  (:func:`_insert_node`), continuing the bulk-built graph.
 * Graph **search** is the accelerated path: a batched, device-resident
   traversal engine mirroring the paper's FPGA graph engine —
   SEARCH-LAYER-TOP greedy descent (Alg. 1) followed by a lock-step batched
@@ -38,6 +41,7 @@ this are documented in docs/ARCHITECTURE.md.
 """
 from __future__ import annotations
 
+import functools
 import math
 from dataclasses import dataclass, field
 from typing import NamedTuple
@@ -48,6 +52,7 @@ import numpy as np
 
 from .fingerprints import (Metric, TANIMOTO, metric_from_counts,
                            metric_from_counts_np)
+from ..obs.trace import TRACER as _TR
 from .topk import NEG_INF, PQ, merge_sorted, pq_pop_many, pq_worst
 
 
@@ -95,6 +100,12 @@ class HNSWIndex:
     level_adj: list = field(default_factory=list)     # [(n_l, M) int32 global ids]
     level_of: np.ndarray | None = None                # (N,) int8 max level per node
     seed: int = 0                  # level-draw stream; insert_hnsw continues it
+    # the bulk build's largest round (rows inserted together) and the rows
+    # it built: with ``seed`` they define the graph, so a rebuild of the
+    # first ``bulk_rows`` rows with the same round followed by the same
+    # online inserts reproduces it
+    build_round: int = 0
+    bulk_rows: int = 0
     max_level_cap: int = 4
     # similarity the graph was built under; searches must use the same one
     metric: Metric = TANIMOTO
@@ -125,6 +136,9 @@ class HNSWIndex:
     _cnt_cap: np.ndarray | None = field(default=None, repr=False)
     _adj_cap: np.ndarray | None = field(default=None, repr=False)
     _lvl_cap: np.ndarray | None = field(default=None, repr=False)
+    # the bulk build's device arrays (:class:`_BuildGraph`), handed to the
+    # first :func:`to_device_graph` so the built graph is not uploaded again
+    device_build: object | None = field(default=None, repr=False)
 
     @property
     def n(self) -> int:
@@ -193,15 +207,19 @@ def _search_layer_np(index_db, db_cnt, adj, q, entry_points, ef,
     visited = set(int(e) for e in entry_points)
     ep = np.asarray(list(visited), dtype=np.int32)
     sims = scorer(q, ep)
-    # candidates: max-first by similarity; results: bounded by ef
-    cand = list(zip((-sims).tolist(), ep.tolist()))
+    # one order for both queues: (similarity, id), the larger the better —
+    # a node is a key no other node shares, so ties never depend on the
+    # order neighbours arrive in (the device build's searches keep it too)
+    # candidates: best-first; results: bounded by ef, worst first
+    cand = [(-s, -e) for s, e in zip(sims.tolist(), ep.tolist())]
     import heapq
     heapq.heapify(cand)
     results = list(zip(sims.tolist(), ep.tolist()))
-    heapq.heapify(results)  # min-heap over similarity = worst first
+    heapq.heapify(results)
     while cand:
-        neg_s, c = heapq.heappop(cand)
-        if -neg_s < results[0][0] and len(results) >= ef:
+        neg_s, neg_c = heapq.heappop(cand)
+        c = -neg_c
+        if (-neg_s, c) < results[0] and len(results) >= ef:
             break
         if counters is not None:
             counters["iters"] = counters.get("iters", 0) + 1
@@ -215,8 +233,8 @@ def _search_layer_np(index_db, db_cnt, adj, q, entry_points, ef,
         if counters is not None:
             counters["evals"] = counters.get("evals", 0) + len(neigh)
         for e, s in zip(neigh, ns.tolist()):
-            if len(results) < ef or s > results[0][0]:
-                heapq.heappush(cand, (-s, e))
+            if len(results) < ef or (s, e) > results[0]:
+                heapq.heappush(cand, (-s, -e))
                 heapq.heappush(results, (s, e))
                 if len(results) > ef:
                     heapq.heappop(results)
@@ -258,11 +276,11 @@ def _insert_node(db, db_cnt, base_adj, upper, levels, i, m, ef_construction,
     """Insert node ``i`` into the half-built graph (Alg. 1 descent + Alg. 2
     layer searches + Alg. 4 selection, with bidirectional link shrink).
 
-    One shared implementation drives both offline :func:`build_hnsw` and
-    online :func:`insert_hnsw` — graph determinism across the two paths is
-    what makes online engines bit-identical to a rebuild. ``upper`` is the
-    level -> {gid: neighbours} dict list; ``entry_point < 0`` means the graph
-    is still empty. Returns the updated ``(entry_point, ep_level)``.
+    Online :func:`insert_hnsw` runs it one node at a time over the graph
+    the bulk :func:`build_hnsw` left (whose rounds of one row and beam of
+    one retrace it step for step). ``upper`` is the level -> {gid:
+    neighbours} dict list; ``entry_point < 0`` means the graph is still
+    empty. Returns the updated ``(entry_point, ep_level)``.
 
     ``dirty`` (optional list) collects every node whose *base-layer* row is
     written — the inserted node plus its bidirectionally-linked neighbours —
@@ -356,36 +374,89 @@ def _upper_dicts_from_dense(index: HNSWIndex) -> list:
     return upper
 
 
+#: the largest round of the bulk build (rows inserted together), where the
+#: caller names none: the faster of 1,024 and 2,048 at 131,072 rows on a
+#: v5e, at the same recall (PERF.md)
+DEFAULT_BUILD_ROUND = 2048
+#: a round holds at most 1/ROUND_GROWTH as many rows as the graph it joins,
+#: so the first rows go in one at a time and the rounds grow with the graph
+ROUND_GROWTH = 8
+#: the smallest padded round and link pass: rounds pad to 16, 256, 4096
+#: rows, link passes to 256, 1024, 4096 ... targets, each at most the
+#: largest the round size allows
+ROUND_FLOOR = 16
+LINK_FLOOR = 256
+
+
+def build_schedule(n: int, build_round: int) -> list:
+    """The bulk build's rounds over ``n`` rows: consecutive ``(a, b)`` id
+    ranges, each of ``clamp(a // ROUND_GROWTH, 1, build_round)`` rows."""
+    if build_round < 1:
+        raise ValueError(f"build_round must be >= 1, got {build_round}")
+    out, a = [], 0
+    while a < n:
+        b = min(n, a + max(1, min(int(build_round), a // ROUND_GROWTH)))
+        out.append((a, b))
+        a = b
+    return out
+
+
 def build_hnsw(db: np.ndarray, m: int = 16, ef_construction: int = 100,
                seed: int = 0, max_level_cap: int = 4,
-               metric: Metric = TANIMOTO) -> HNSWIndex:
-    """Sequential insert construction (paper builds offline; search is the
-    accelerated path). The per-node insertion is :func:`_insert_node` — the
-    same code online :func:`insert_hnsw` runs, so incremental growth and
-    from-scratch builds produce identical graphs."""
+               metric: Metric = TANIMOTO,
+               build_round: int = DEFAULT_BUILD_ROUND) -> HNSWIndex:
+    """Bulk construction on the device, in rounds (:func:`build_schedule`).
+
+    Round ``[a, b)`` is searched against the graph of ids ``[0, a)``: the
+    greedy descent and the efConstruction search of every level run on the
+    lock-step traversal of :func:`search_hnsw` (:func:`auto_beam` of
+    ``ef_construction`` candidates a step). Each row's
+    candidates are its search results merged with the round's earlier rows
+    at that level, scored by brute force; Alg. 4's heuristic picks ``m`` of
+    them, batched over the round, and the reverse links go in grouped by
+    target (:func:`_build_link`). Levels come from ``seed``'s stream as
+    online inserts draw them, so :func:`insert_hnsw` continues the result.
+    The adjacency stays on the device for the whole build; the host keeps
+    the round schedule and copies the graph back once at the end. At
+    ``build_round=1`` and an ``ef_construction`` under 32 (a beam of one)
+    every round is one row and the build is the sequential Alg. 1/2/4
+    insertion.
+    """
     db = np.asarray(db, dtype=np.uint32)
-    n, _ = db.shape
+    n, w = db.shape
     db_cnt = _np_popcount(db)
     rng = np.random.default_rng(seed)
     levels = _draw_levels(rng, n, m, max_level_cap)
-    base_adj = np.full((n, 2 * m), -1, dtype=np.int32)
-    upper = [dict() for _ in range(max_level_cap + 1)]  # gid -> int32 array
-
-    entry_point, ep_level = -1, 0
-    for i in range(n):
-        entry_point, ep_level = _insert_node(
-            db, db_cnt, base_adj, upper, levels, i, m, ef_construction,
-            entry_point, ep_level, metric=metric)
-
+    beam = auto_beam(ef_construction)
+    rounds = build_schedule(n, build_round)
+    entry_point, ep_level, bg = -1, 0, None
+    with _TR.span("hnsw.build", rows=int(n), rounds=len(rounds)):
+        if n:
+            bg = _BuildGraph.upload(db, db_cnt, m, max_level_cap)
+        for r, (a, b) in enumerate(rounds):
+            with _TR.span("hnsw.build_round", round=r, rows=b - a):
+                bg, entry_point, ep_level = _build_round(
+                    bg, levels, a, b, entry_point, ep_level, m,
+                    ef_construction, beam, metric, build_round)
+        if bg is not None:
+            adj = np.asarray(bg.adj)[:, :n]             # the one host copy
+        else:
+            adj = np.full((max_level_cap + 1, 0, 2 * m), -1, np.int32)
     max_level = int(levels.max(initial=0))
+    upper = [dict() for _ in range(max_level_cap + 1)]
+    for l in range(1, max_level + 1):
+        for g in np.flatnonzero(levels >= l):
+            row = adj[l, g, :m]
+            upper[l][int(g)] = row[row >= 0].copy()
     level_nodes, level_adj = _densify(upper, max_level, m)
     return HNSWIndex(db=db, db_popcount=db_cnt, m=m,
                      ef_construction=ef_construction, entry_point=entry_point,
-                     max_level=max_level, base_adj=base_adj,
+                     max_level=max_level, base_adj=adj[0].copy(),
                      level_nodes=level_nodes, level_adj=level_adj,
                      level_of=levels.astype(np.int8), seed=seed,
+                     build_round=int(build_round), bulk_rows=int(n),
                      max_level_cap=max_level_cap, metric=metric,
-                     upper_dicts=upper, rng=rng)
+                     upper_dicts=upper, rng=rng, device_build=bg)
 
 
 def _ensure_capacity(index: HNSWIndex, n_total: int) -> None:
@@ -420,10 +491,12 @@ def insert_hnsw(index: HNSWIndex, new_fps: np.ndarray,
     """Batched incremental inserts: grow ``index`` in place by ``new_fps``.
 
     Levels continue the seed's rng stream (the persisted Generator,
-    :func:`_draw_levels`) and every node runs the same :func:`_insert_node`
-    the offline build uses, so after any number of insert batches the index
-    is **identical** to ``build_hnsw(concatenated_db)`` — the engine parity
-    contract. Array growth is amortized-doubling (:func:`_ensure_capacity`):
+    :func:`_draw_levels`) and every node runs :func:`_insert_node`, one at
+    a time, so after any number of insert batches the index is
+    **identical** to the same bulk build over the same first rows followed
+    by the same rows inserted in one batch — the engine parity contract
+    (where the bulk build stopped is part of what defines the graph).
+    Array growth is amortized-doubling (:func:`_ensure_capacity`):
     appending a batch costs O(batch), not O(n_total).
 
     ``scorer_factory(db, db_cnt) -> scorer(q, ids) -> sims`` swaps the
@@ -557,6 +630,9 @@ def to_device_graph(index: HNSWIndex, capacity: int | None = None,
     L = max(index.max_level, 0)
     n = index.n
     cap = n if capacity is None else max(int(capacity), n)
+    bg = index.device_build
+    if bg is not None and bg.db.shape[0] == cap and n == index.bulk_rows:
+        return _graph_from_build(bg, index, layout, kernel)
     upper = _dense_upper(index, cap)
     db = np.zeros((cap, index.db.shape[1]), dtype=np.uint32)
     db[:n] = index.db
@@ -583,6 +659,31 @@ def to_device_graph(index: HNSWIndex, capacity: int | None = None,
         nbr_slabs=nbr_slabs)
 
 
+def _graph_from_build(bg, index: HNSWIndex, layout: str,
+                      kernel: bool) -> HNSWDeviceGraph:
+    """:func:`to_device_graph` of a freshly built index from the build's own
+    device arrays: the same graph, with no upload."""
+    L = max(index.max_level, 0)
+    base = bg.adj[0]
+    nbr_fps = nbr_cnt = db_lines = nbr_slabs = None
+    if kernel:
+        from ..kernels import ops as kops
+        db_lines = kops.db_lines(bg.db)
+    if layout == "blocked":
+        safe = jnp.maximum(base, 0)
+        nbr_fps = jnp.where((base >= 0)[:, :, None], bg.db[safe], 0)
+        nbr_cnt = jnp.where(base >= 0, bg.db_popcount[safe], 0)
+        if kernel:
+            nbr_slabs = kops.nbr_slabs(nbr_fps)
+            nbr_fps = nbr_cnt = None
+    return HNSWDeviceGraph(
+        db=bg.db, db_popcount=bg.db_popcount, base_adj=base,
+        upper_adj=bg.adj[1:max(L, 1) + 1, :, :index.m],
+        entry_point=jnp.int32(index.entry_point), max_level=L,
+        nbr_fps=nbr_fps, nbr_cnt=nbr_cnt, db_lines=db_lines,
+        nbr_slabs=nbr_slabs)
+
+
 def _sims(q: jax.Array, q_cnt: jax.Array, g: HNSWDeviceGraph, ids: jax.Array,
           metric: Metric = TANIMOTO) -> jax.Array:
     """Single-query view of :func:`score_ids_jnp` (greedy-descent stage)."""
@@ -597,11 +698,16 @@ def score_ids_jnp(queries: jax.Array, q_cnt: jax.Array, g: HNSWDeviceGraph,
     identical arithmetic (popcount similarity via ``metric_from_counts``,
     -inf for id -1), used by the ``"jnp"`` engine backend.
     """
+    return _score_rows(g.db, g.db_popcount, queries, q_cnt, ids, metric)
+
+
+def _score_rows(db, db_cnt, queries, q_cnt, ids, metric: Metric = TANIMOTO):
+    """:func:`score_ids_jnp` over bare ``db``/``db_cnt`` arrays."""
     safe = jnp.maximum(ids, 0)
-    fps = g.db[safe]                                    # (Q, E, W)
+    fps = db[safe]                                      # (Q, E, W)
     inter = jnp.sum(jax.lax.population_count(
         queries[:, None, :] & fps).astype(jnp.int32), axis=-1)
-    s = metric_from_counts(metric, inter, q_cnt[:, None], g.db_popcount[safe])
+    s = metric_from_counts(metric, inter, q_cnt[:, None], db_cnt[safe])
     return jnp.where(ids >= 0, s, NEG_INF)
 
 
@@ -630,10 +736,16 @@ def expand_scores_jnp(queries: jax.Array, q_cnt: jax.Array,
     return s_srt, jnp.take_along_axis(ids, pos, axis=1)
 
 
-def _greedy_descent(q, q_cnt, g: HNSWDeviceGraph, level: int,
-                    start: jax.Array, metric: Metric = TANIMOTO) -> jax.Array:
-    """SEARCH-LAYER-TOP (Alg. 1) at one (static) upper level from ``start``."""
-    adj = g.upper_adj[level - 1]
+def _greedy(q, q_cnt, db, db_cnt, adj, start, metric: Metric = TANIMOTO,
+            id_ties: bool = False):
+    """SEARCH-LAYER-TOP (Alg. 1): a greedy walk over one upper level's
+    adjacency ``adj`` from ``start`` to a local best; returns ``(node,
+    similarity)``. A neighbour replaces the current node only when
+    strictly more similar, the first such in row order; with ``id_ties``
+    (the build), the best by (similarity, id) as the host search orders."""
+    def sims(ids):
+        return _score_rows(db, db_cnt, q[None], q_cnt[None], ids[None],
+                           metric)[0]
 
     def cond(state):
         cur, cur_sim, moved = state
@@ -642,15 +754,20 @@ def _greedy_descent(q, q_cnt, g: HNSWDeviceGraph, level: int,
     def body(state):
         cur, cur_sim, _ = state
         neigh = adj[cur]                                   # (M,)
-        s = _sims(q, q_cnt, g, neigh, metric=metric)
-        j = jnp.argmax(s)
-        better = s[j] > cur_sim
+        s = sims(neigh)
+        if id_ties:
+            top = jnp.max(s)
+            j = jnp.argmax(jnp.where(s == top, neigh, -1))
+            better = (s[j] > cur_sim) | ((s[j] == cur_sim) & (neigh[j] > cur))
+        else:
+            j = jnp.argmax(s)
+            better = s[j] > cur_sim
         return (jnp.where(better, neigh[j], cur),
                 jnp.where(better, s[j], cur_sim), better)
 
-    s0 = _sims(q, q_cnt, g, start[None], metric=metric)[0]
-    cur, _, _ = jax.lax.while_loop(cond, body, (start, s0, jnp.bool_(True)))
-    return cur
+    s0 = sims(start[None])[0]
+    cur, sim, _ = jax.lax.while_loop(cond, body, (start, s0, jnp.bool_(True)))
+    return cur, sim
 
 
 # Early-termination reasons (TraversalStats.reason values).
@@ -720,16 +837,64 @@ def search_hnsw(g: HNSWDeviceGraph, queries: jax.Array, k: int, ef: int,
     so the queues merge it directly (one launch per iteration).
     """
     ef = max(ef, k)
-    beam = max(1, min(beam, ef))
-    if max_iters is None:
-        max_iters = 4 * ef + 16
     if score_fn is None:
         def score_fn(qs, qc, ids):
             return score_ids_jnp(qs, qc, g, ids, metric=metric)
+    q_cnt = jnp.sum(jax.lax.population_count(queries).astype(jnp.int32), -1)
 
+    # greedy descent through the upper layers (Alg. 1), vmapped per query
+    def descend(q, qc):
+        ep = g.entry_point
+        for level in range(g.max_level, 0, -1):          # static unroll
+            ep = _greedy(q, qc, g.db, g.db_popcount, g.upper_adj[level - 1],
+                         ep, metric)[0]
+        return ep
+
+    ep = jax.vmap(descend)(queries, q_cnt)               # (Q,)
+    ep_sim = score_fn(queries, q_cnt, ep[:, None])[:, 0]
+    res, active, iters, expans = _beam_search(
+        g.base_adj, queries, q_cnt, ep_sim[:, None], ep[:, None], ef, beam,
+        max_iters, score_fn, expand_fn)
+    reason = jnp.where(active, REASON_MAX_ITERS, REASON_CONVERGED)
+    ids = res.payload[:, :k]
+    sims = jnp.where(ids >= 0, res.scores[:, :k], 0.0)
+    return ids, sims, TraversalStats(iters=iters, expansions=expans,
+                                     reason=reason.astype(jnp.int32))
+
+
+def _keyed(s, i):
+    """Sort rows of (scores, ids) by (score, id) descending; ``NEG_INF``
+    entries last."""
+    s, i = jax.lax.sort((-s, -i), dimension=1, num_keys=2)
+    return -s, -i
+
+
+def _above(s, i, ws, wi):
+    """(s, i) > (ws, wi) in the (score, id) order."""
+    return (s > ws) | ((s == ws) & (i > wi))
+
+
+def _beam_search(adj, queries, q_cnt, init_s, init_i, ef: int, beam: int,
+                 max_iters, score_fn, expand_fn=None, id_ties: bool = False):
+    """SEARCH-LAYER (Alg. 2) over one layer's adjacency ``adj (N, D)``, in
+    lock-step for the batch: the loop of :func:`search_hnsw`, from each
+    query's entry list ``init_s``/``init_i (Q, E0)`` (descending, ``-1`` /
+    ``NEG_INF`` padded; an empty list leaves the query idle). Returns the
+    result queue (the best ``ef`` found, descending), the per-query
+    still-active flags, iterations and expansions.
+
+    ``id_ties`` orders both queues by (similarity, id) as the host search
+    does (:func:`_search_layer_np`): a node's key is its own, so no tie
+    depends on the order neighbours arrive in. The build uses it, so a
+    build of one row a round retraces the sequential insertion; the search
+    path keeps the arrival order (``merge_sorted``), which the kernels'
+    runs give."""
+    beam = max(1, min(beam, ef))
+    if max_iters is None:
+        max_iters = 4 * ef + 16
     q_n = queries.shape[0]
-    n = g.db.shape[0]
-    m2 = g.base_adj.shape[1]
+    n = adj.shape[0]
+    m2 = adj.shape[1]
     n_exp = beam * m2                                   # neighbours per launch
     kk = min(n_exp, ef)                                 # sorted-run width
     if expand_fn is None:
@@ -745,28 +910,26 @@ def search_hnsw(g: HNSWDeviceGraph, queries: jax.Array, k: int, ef: int,
             return s_srt, jnp.take_along_axis(fl, pos, axis=1)
 
     vwords = (n + 31) // 32
-    q_cnt = jnp.sum(jax.lax.population_count(queries).astype(jnp.int32), -1)
-
-    # greedy descent through the upper layers (Alg. 1), vmapped per query
-    def descend(q, qc):
-        ep = g.entry_point
-        for level in range(g.max_level, 0, -1):          # static unroll
-            ep = _greedy_descent(q, qc, g, level, ep, metric=metric)
-        return ep
-
-    ep = jax.vmap(descend)(queries, q_cnt)               # (Q,)
-    ep_sim = score_fn(queries, q_cnt, ep[:, None])[:, 0]
+    e0 = init_i.shape[1]
 
     # C (candidates, pop-best) and M (results, evict-worst): batched
     # register-array queues (core/topk.py PQ invariants), one row per query —
     # every queue op below is the vmapped scalar PQ primitive.
-    cand = PQ(jnp.full((q_n, ef), NEG_INF).at[:, 0].set(ep_sim),
-              jnp.full((q_n, ef), -1, jnp.int32).at[:, 0].set(ep))
+    pad = ef - e0
+    cand = PQ(jnp.concatenate(
+                  [init_s, jnp.full((q_n, pad), NEG_INF)], axis=1),
+              jnp.concatenate(
+                  [init_i.astype(jnp.int32),
+                   jnp.full((q_n, pad), -1, jnp.int32)], axis=1))
     res = cand
+    if id_ties:
+        cand = res = PQ(*_keyed(cand.scores, cand.payload))
     rows = jnp.arange(q_n)
     visited = jnp.zeros((q_n, vwords), jnp.uint32)
-    visited = visited.at[rows, ep // 32].set(
-        jnp.uint32(1) << (ep % 32).astype(jnp.uint32))
+    safe0 = jnp.maximum(init_i, 0)
+    visited = visited.at[rows[:, None], safe0 // 32].add(jnp.where(
+        init_i >= 0, jnp.uint32(1) << (safe0 % 32).astype(jnp.uint32),
+        jnp.uint32(0)))
 
     def where_rows(mask, new, old):
         """Per-query select between two batched PQ pytrees."""
@@ -786,19 +949,27 @@ def search_hnsw(g: HNSWDeviceGraph, queries: jax.Array, k: int, ef: int,
     def body(st):
         cand, res, visited, active, iters, expans, it = st
         worst = jax.vmap(pq_worst)(res)                  # eviction threshold
+        worst_i = res.payload[:, -1]
+
+        def kept(s, i):
+            """Not below the worst retained result."""
+            if id_ties:
+                return ~_above(worst[:, None], worst_i[:, None], s, i)
+            return s >= worst[:, None]
         # Alg. 2 termination, per query: stop when the best candidate cannot
         # beat the worst retained result (monotone -> inactive stays inactive)
         go = jnp.logical_and(active, jnp.logical_and(
-            cand.scores[:, 0] > NEG_INF, cand.scores[:, 0] >= worst))
+            cand.scores[:, 0] > NEG_INF,
+            kept(cand.scores[:, :1], cand.payload[:, :1])[:, 0]))
 
         # pop the beam: best B candidates, queue shifts up by B
         pop_s, pop_i, popped = jax.vmap(
             lambda pq: pq_pop_many(pq, beam))(cand)
-        valid_pop = (pop_s > NEG_INF) & (pop_s >= worst[:, None]) & go[:, None]
+        valid_pop = (pop_s > NEG_INF) & kept(pop_s, pop_i) & go[:, None]
         cand = where_rows(go, popped, cand)
 
         # beam expansion: adjacency gather, (Q, beam, 2M)
-        nb = g.base_adj[jnp.maximum(pop_i, 0)]
+        nb = adj[jnp.maximum(pop_i, 0)]
         nb = jnp.where(valid_pop[:, :, None], nb, -1)
 
         # visited check + mark, one beam slot at a time (static unroll): ids
@@ -823,23 +994,310 @@ def search_hnsw(g: HNSWDeviceGraph, queries: jax.Array, k: int, ef: int,
         # fused expansion stage: gather + score + evict-filter + sort for
         # all B*2M neighbours per query in one launch; the sorted run
         # rank-merges into both queues (Fig. 9)
-        s_srt, i_srt = expand_fn(queries, q_cnt, pop_i, flat, worst, kk)
-        vmerge = jax.vmap(
-            lambda pq, ms, mi: PQ(*merge_sorted(pq.scores, pq.payload,
-                                                ms, mi)))
-        res = where_rows(go, vmerge(res, s_srt, i_srt), res)
-        cand = where_rows(go, vmerge(cand, s_srt, i_srt), cand)
+        if id_ties:
+            s = score_fn(queries, q_cnt, flat)
+            keep = _above(s, flat, worst[:, None], worst_i[:, None])
+            s = jnp.where(keep, s, NEG_INF)
+            fl = jnp.where(keep, flat, -1)
+
+            def merged(pq):
+                rs, ri = _keyed(jnp.concatenate([pq.scores, s], 1),
+                                jnp.concatenate([pq.payload, fl], 1))
+                return PQ(rs[:, :ef], ri[:, :ef])
+            res = where_rows(go, merged(res), res)
+            cand = where_rows(go, merged(cand), cand)
+        else:
+            s_srt, i_srt = expand_fn(queries, q_cnt, pop_i, flat, worst, kk)
+            vmerge = jax.vmap(
+                lambda pq, ms, mi: PQ(*merge_sorted(pq.scores, pq.payload,
+                                                    ms, mi)))
+            res = where_rows(go, vmerge(res, s_srt, i_srt), res)
+            cand = where_rows(go, vmerge(cand, s_srt, i_srt), cand)
 
         iters = iters + go.astype(jnp.int32)
         expans = expans + jnp.sum(valid_pop, axis=1).astype(jnp.int32)
         return cand, res, visited, go, iters, expans, it + 1
 
     _, res, _, active, iters, expans, _ = jax.lax.while_loop(cond, body, state)
-    reason = jnp.where(active, REASON_MAX_ITERS, REASON_CONVERGED)
-    ids = res.payload[:, :k]
-    sims = jnp.where(ids >= 0, res.scores[:, :k], 0.0)
-    return ids, sims, TraversalStats(iters=iters, expansions=expans,
-                                     reason=reason.astype(jnp.int32))
+    return res, active, iters, expans
+
+
+# ---------------------------------------------------------------------------
+# bulk build on the device (build_hnsw's rounds)
+# ---------------------------------------------------------------------------
+
+def _bucket(n: int, floor: int, step: int, top: int) -> int:
+    """The padded size of a round or a link pass of ``n``: the smallest of
+    ``floor * step**j`` that holds it, but no more than ``top`` (a power of
+    two at least ``n``), so a build compiles a handful of shapes."""
+    b = floor
+    while b < n:
+        b *= step
+    top = max(top, 1 << max(n - 1, 0).bit_length())
+    return min(b, top)
+
+
+class _BuildGraph(NamedTuple):
+    """The graph under construction, on the device: the library, its
+    popcounts and every level's adjacency in one ``(cap_levels + 1, cap,
+    2M)`` array (level 0 the base layer; an upper level uses its first M
+    columns), ``cap`` the next power of two above the library."""
+    db: jax.Array        # (cap, W) uint32
+    db_popcount: jax.Array  # (cap,) int32
+    adj: jax.Array       # (L + 1, cap, 2M) int32, -1 padded
+
+    @classmethod
+    def upload(cls, db, db_cnt, m: int, max_level_cap: int):
+        n, w = db.shape
+        cap = 1 if n <= 1 else 1 << (n - 1).bit_length()
+        dbp = np.zeros((cap, w), np.uint32)
+        dbp[:n] = db
+        cnt = np.zeros((cap,), np.int32)
+        cnt[:n] = db_cnt
+        return cls(jnp.asarray(dbp), jnp.asarray(cnt),
+                   jnp.full((max_level_cap + 1, cap, 2 * m), -1, jnp.int32))
+
+
+def _padded(ids: np.ndarray, size: int) -> np.ndarray:
+    out = np.full((size,), -1, np.int32)
+    out[:len(ids)] = ids
+    return out
+
+
+@functools.partial(jax.jit, static_argnames=("ef", "metric"))
+def _build_entry(db, cnt, rows, entry_point, ef: int, metric: Metric):
+    """Each round row's entry list: the graph's entry point and its score,
+    then ``-1`` pads."""
+    safe = jnp.maximum(rows, 0)
+    ep = jnp.full((rows.shape[0], 1), entry_point, jnp.int32)
+    s = _score_rows(db, cnt, db[safe], cnt[safe], ep, metric)
+    return (jnp.full((rows.shape[0], ef), NEG_INF).at[:, :1].set(s),
+            jnp.full((rows.shape[0], ef), -1, jnp.int32).at[:, :1].set(ep))
+
+
+@functools.partial(jax.jit, static_argnames=("m", "metric"))
+def _build_descend(adj, lev, db, cnt, rows, e_s, e_i, move, m: int,
+                   metric: Metric):
+    """One upper level's greedy step (Alg. 1) for the round rows whose own
+    level is below it: their single entry (column 0) walks to its local
+    best over the level's M-wide adjacency."""
+    fps = db[jnp.maximum(rows, 0)]
+    qc = cnt[jnp.maximum(rows, 0)]
+    adj_l = adj[lev, :, :m]
+    cur, sim = jax.vmap(lambda q, c, st: _greedy(
+        q, c, db, cnt, adj_l, st, metric, id_ties=True))(fps, qc, e_i[:, 0])
+    return (e_s.at[:, 0].set(jnp.where(move, sim, e_s[:, 0])),
+            e_i.at[:, 0].set(jnp.where(move, cur, e_i[:, 0])))
+
+
+@functools.partial(jax.jit, static_argnames=("ef", "beam", "metric"))
+def _build_search(adj, lev, db, cnt, rows, e_s, e_i, pos, ef: int,
+                  beam: int, metric: Metric):
+    """The efConstruction search of one level for the round rows at
+    positions ``pos`` (``-1`` pads idle), from their entry lists
+    ``e_s``/``e_i``: :func:`_beam_search`, the search path's own loop.
+    Returns the entry lists with those rows' results written in (the next
+    level's entries) and the results."""
+    at = jnp.maximum(pos, 0)
+    sub = jnp.where(pos >= 0, rows[at], -1)
+    safe = jnp.maximum(sub, 0)
+    init_i = jnp.where(sub[:, None] >= 0, e_i[at], -1)
+    init_s = jnp.where(init_i >= 0, e_s[at], NEG_INF)
+
+    def score_fn(qs, qcnt, ids):
+        return _score_rows(db, cnt, qs, qcnt, ids, metric)
+    res, _, _, _ = _beam_search(adj[lev], db[safe], cnt[safe], init_s,
+                                init_i, ef, beam, None, score_fn,
+                                id_ties=True)
+    dest = jnp.where(pos >= 0, pos, rows.shape[0])
+    return (e_s.at[dest].set(res.scores, mode="drop"),
+            e_i.at[dest].set(res.payload, mode="drop"),
+            res.scores, res.payload)
+
+
+def _heuristic(db, cnt, cand_i, cand_s, m, width: int, metric: Metric):
+    """Alg. 4 over a batch of candidate lists, each already in the order the
+    heuristic walks them (descending similarity, the caller's tie rule;
+    ``-1`` entries skipped): a candidate is kept when it is no more similar
+    to any kept one than to the row's own print; lists that keep fewer than
+    ``m`` are filled with the best of the rest. Returns ``(B, width)`` ids
+    (``width >= m``), the kept in walk order then the fill, ``-1`` padded —
+    what :func:`_select_heuristic` returns row by row."""
+    bsz, c = cand_i.shape
+    valid = cand_i >= 0
+    safe = jnp.maximum(cand_i, 0)
+    fps, cc = db[safe], cnt[safe]
+    inter = jnp.sum(jax.lax.population_count(
+        fps[:, :, None, :] & fps[:, None, :, :]).astype(jnp.int32), axis=-1)
+    pair = metric_from_counts(metric, inter, cc[:, :, None], cc[:, None, :])
+
+    def body(j, st):
+        count, sel, rej = st
+        take = valid[:, j] & ~rej[:, j] & (count < m)
+        sel = sel.at[:, j].set(take)
+        rej = rej | (take[:, None] & (pair[:, :, j] > cand_s))
+        return count + take.astype(jnp.int32), sel, rej
+    zero = jnp.zeros((bsz, c), bool)
+    count, sel, _ = jax.lax.fori_loop(
+        0, c, body, (jnp.zeros((bsz,), jnp.int32), zero, zero))
+    rest = valid & ~sel
+    rank = jnp.cumsum(rest, axis=1) - 1
+    fill = rest & (rank < (m - count)[:, None])
+    pos = jnp.where(sel, jnp.cumsum(sel, axis=1) - 1,
+                    jnp.where(fill, count[:, None] + rank, width))
+    out = jnp.full((bsz, width + 1), -1, jnp.int32)
+    out = out.at[jnp.arange(bsz)[:, None], pos].set(cand_i)
+    return out[:, :width]
+
+
+@functools.partial(jax.jit, static_argnames=("m", "metric"),
+                   donate_argnums=(0,))
+def _build_select(adj, lev, db, cnt, rows, cand_s, cand_i, m: int,
+                  metric: Metric):
+    """One level's neighbour selection for the round rows ``rows``
+    (ascending, ``-1`` pads): the search's candidates merged with the
+    earlier rows of the round at this level (brute-force scores), the best
+    ``ef`` in (similarity, id) descending order — the order the host
+    search hands Alg. 4 — then the batched heuristic. Writes each row's
+    selection as its adjacency at the level."""
+    bsz, ef = cand_i.shape
+    safe = jnp.maximum(rows, 0)
+    fps, qc = db[safe], cnt[safe]
+    inter = jnp.sum(jax.lax.population_count(
+        fps[:, None, :] & fps[None, :, :]).astype(jnp.int32), axis=-1)
+    s = metric_from_counts(metric, inter, qc[:, None], qc[None, :])
+    pos = jnp.arange(bsz)
+    earlier = (pos[None, :] < pos[:, None]) & (rows[None, :] >= 0) \
+        & (rows[:, None] >= 0)
+    s = jnp.where(earlier, s, NEG_INF)
+    kk = min(bsz, ef)
+    in_s, at = jax.lax.top_k(s, kk)
+    in_i = jnp.where(in_s > NEG_INF, rows[at], -1)
+    all_s = jnp.concatenate([cand_s, in_s], axis=1)
+    all_i = jnp.concatenate([cand_i, in_i], axis=1)
+    all_s = jnp.where(all_i >= 0, all_s, NEG_INF)
+    srt_s, srt_i = _keyed(all_s, all_i)
+    sel = _heuristic(db, cnt, srt_i[:, :ef], srt_s[:, :ef], m, m, metric)
+    sel = jnp.where(rows[:, None] >= 0, sel, -1)
+    m2 = adj.shape[2]
+    row = jnp.concatenate([sel, jnp.full((bsz, m2 - m), -1, jnp.int32)], 1)
+    dest = jnp.where(rows >= 0, rows, adj.shape[1])
+    return adj.at[lev, dest].set(row, mode="drop"), sel
+
+
+@functools.partial(jax.jit, static_argnames=("metric",),
+                   donate_argnums=(0,))
+def _build_link(adj, lev, db, cnt, targets, new, mmax, metric: Metric):
+    """One pass of reverse links at one level: each target (``-1`` pads)
+    takes its next new neighbours ``new (U, 2M)`` (at most ``mmax`` of
+    them, in source order). They are appended where the row has room;
+    otherwise the row and the new neighbours, in that order, are shrunk to
+    ``mmax`` by the heuristic (stable on ties), as :func:`_insert_node`
+    shrinks a full row. ``mmax`` is 2M on the base layer and M above it."""
+    m2 = adj.shape[2]
+    safe = jnp.maximum(targets, 0)
+    row = adj[lev, safe]
+    comb = jnp.concatenate([row, new], axis=1)
+    comb = jnp.take_along_axis(
+        comb, jnp.argsort(comb < 0, axis=1, stable=True), axis=1)
+    fits = jnp.sum(comb >= 0, axis=1) <= mmax
+    s = _score_rows(db, cnt, db[safe], cnt[safe], comb, metric)
+    order = jnp.argsort(-s, axis=1, stable=True)
+    shrunk = _heuristic(db, cnt, jnp.take_along_axis(comb, order, axis=1),
+                        jnp.take_along_axis(s, order, axis=1), mmax, m2,
+                        metric)
+    out = jnp.where(fits[:, None], comb[:, :m2], shrunk)
+    dest = jnp.where(targets >= 0, targets, adj.shape[1])
+    return adj.at[lev, dest].set(out, mode="drop")
+
+
+def _link_groups(sources: np.ndarray, sel: np.ndarray, width: int,
+                 m2: int, top: int):
+    """Group one level's new links ``sources[r] -> sel[r, :]`` by target,
+    each target's sources in ascending order, as link passes of at most
+    ``width`` sources a target: yields ``(targets (U,), new (U, m2))``,
+    ``U`` padded (:func:`_bucket`, at most ``top``)."""
+    src = np.repeat(sources, sel.shape[1])
+    tgt = sel.ravel()
+    ok = tgt >= 0
+    src, tgt = src[ok], tgt[ok]
+    if not len(tgt):
+        return
+    order = np.lexsort((src, tgt))
+    src, tgt = src[order], tgt[order]
+    first = np.r_[True, tgt[1:] != tgt[:-1]]
+    group = np.cumsum(first) - 1
+    rank = np.arange(len(tgt)) - np.flatnonzero(first)[group]
+    for p in range(int(rank.max()) // width + 1):
+        pick = (rank >= p * width) & (rank < (p + 1) * width)
+        uniq, slot = np.unique(group[pick], return_inverse=True)
+        targets = _padded(tgt[first][uniq],
+                          _bucket(len(uniq), LINK_FLOOR, 4, top))
+        new = np.full((len(targets), m2), -1, np.int32)
+        new[slot, rank[pick] - p * width] = src[pick]
+        yield targets, new
+
+
+def _build_round(bg: _BuildGraph, levels: np.ndarray, a: int, b: int,
+                 entry_point: int, ep_level: int, m: int, ef: int,
+                 beam: int, metric: Metric, build_round: int):
+    """Insert rows ``[a, b)`` into the graph of ``[0, a)`` on the device
+    (:func:`build_hnsw`); returns the graph and the new ``(entry_point,
+    ep_level)``."""
+    ids = np.arange(a, b, dtype=np.int32)
+    lv = levels[a:b]
+    top_rows = 1 << max(int(build_round) - 1, 0).bit_length()
+
+    def padded(x):
+        return _padded(x, _bucket(len(x), ROUND_FLOOR, 16, top_rows))
+    rows = padded(ids)
+    bsz = len(rows)
+    lv_p = np.full((bsz,), -1, np.int32)
+    lv_p[:len(ids)] = lv
+    top = int(lv.max())
+    searched = {}                      # level -> (positions, scores, ids)
+    with _TR.span("hnsw.build_search"):
+        if entry_point >= 0:
+            e_s, e_i = _build_entry(bg.db, bg.db_popcount, rows, entry_point,
+                                    ef=ef, metric=metric)
+            for lev in range(ep_level, -1, -1):
+                if lev > 0:
+                    e_s, e_i = _build_descend(
+                        bg.adj, lev, bg.db, bg.db_popcount, rows, e_s, e_i,
+                        lv_p < lev, m=m, metric=metric)
+                pos = np.flatnonzero(lv_p >= lev)
+                if not len(pos):
+                    continue
+                pos_p = padded(pos)
+                e_s, e_i, r_s, r_i = _build_search(
+                    bg.adj, lev, bg.db, bg.db_popcount, rows, e_s, e_i,
+                    pos_p, ef=ef, beam=beam, metric=metric)
+                searched[lev] = (pos_p, r_s, r_i)
+    sels = {}
+    with _TR.span("hnsw.build_select"):
+        adj = bg.adj
+        for lev in range(top, -1, -1):
+            if lev in searched:
+                pos_p, c_s, c_i = searched[lev]
+            else:                      # above the graph: the round alone
+                pos_p = padded(np.flatnonzero(lv_p >= lev))
+                c_s = np.full((len(pos_p), ef), -np.inf, np.float32)
+                c_i = np.full((len(pos_p), ef), -1, np.int32)
+            sub = np.where(pos_p >= 0, rows[np.maximum(pos_p, 0)], -1)
+            adj, sel = _build_select(adj, lev, bg.db, bg.db_popcount, sub,
+                                     c_s, c_i, m=m, metric=metric)
+            sels[lev] = (sub, sel)
+    with _TR.span("hnsw.build_link"):
+        got = jax.device_get({lev: s for lev, (_, s) in sels.items()})
+        for lev in range(top, -1, -1):
+            mmax = 2 * m if lev == 0 else m
+            for targets, new in _link_groups(sels[lev][0], got[lev], mmax,
+                                             2 * m, top_rows * m):
+                adj = _build_link(adj, lev, bg.db, bg.db_popcount, targets,
+                                  new, mmax, metric=metric)
+    if entry_point < 0 or top > ep_level:
+        entry_point, ep_level = int(ids[int(np.argmax(lv == top))]), top
+    return bg._replace(adj=adj), entry_point, ep_level
 
 
 # ---------------------------------------------------------------------------
@@ -881,12 +1339,14 @@ def globalize_shard_ids(local_ids: jax.Array) -> jax.Array:
 def build_hnsw_sharded(db: np.ndarray, n_shards: int, m: int = 16,
                        ef_construction: int = 100, seed: int = 0,
                        max_level_cap: int = 4,
-                       metric: Metric = TANIMOTO) -> list:
+                       metric: Metric = TANIMOTO,
+                       build_round: int = DEFAULT_BUILD_ROUND) -> list:
     """Build S independent per-shard indexes over the round-robin partition.
 
-    Shard ``s`` is ``build_hnsw(db[s::S], seed=seed + s)`` — with
-    ``n_shards == 1`` this is exactly the unsharded build (same rows, same
-    seed), the base of the 1-shard bit-parity contract. Each shard draws its
+    Shard ``s`` is ``build_hnsw(db[s::S], seed=seed + s)``, with the same
+    ``build_round`` — with ``n_shards == 1`` this is exactly the unsharded
+    build (same rows, same seed), the base of the 1-shard bit-parity
+    contract. Each shard draws its
     levels from its own seed stream so per-shard graphs stay deterministic
     under :func:`insert_hnsw_sharded` growth.
     """
@@ -898,7 +1358,7 @@ def build_hnsw_sharded(db: np.ndarray, n_shards: int, m: int = 16,
                          f"{n_shards} shards")
     return [build_hnsw(db[s::n_shards], m=m, ef_construction=ef_construction,
                        seed=seed + s, max_level_cap=max_level_cap,
-                       metric=metric)
+                       metric=metric, build_round=build_round)
             for s in range(n_shards)]
 
 

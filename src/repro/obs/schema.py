@@ -29,6 +29,17 @@ BITBOUND_METRIC_FAMILIES = (
     "service_stage1_tiles_total",
 )
 
+#: families a service with an HNSW engine additionally exports: the bulk
+#: build (gauges, set once it is done) and the traversal's work per batch
+HNSW_METRIC_FAMILIES = (
+    "service_hnsw_build_rows",
+    "service_hnsw_build_seconds",
+    "service_hnsw_build_rounds",
+    "service_hnsw_neighbour_evals_total",
+    "service_hnsw_iters_total",
+    "service_hnsw_max_iters_hit_total",
+)
+
 #: families the concurrent front end (ISSUE 9) must additionally export —
 #: CI's overload smoke passes these via ``--require-family`` (note that
 #: flag *replaces* the service floor, so callers list both sets)

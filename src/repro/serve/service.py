@@ -56,6 +56,7 @@ from ..checkpoint.fs import DEFAULT_FS, Fs
 from ..core.engine import (BitBoundFoldingEngine, BruteForceEngine,
                            HNSWEngine)
 from ..core.fingerprints import resolve_metric
+from ..core.hnsw import DEFAULT_BUILD_ROUND
 from ..obs.metrics import MetricsRegistry, NULL_METRICS
 from ..obs.trace import TRACER as _TR
 from . import snapshot as snap
@@ -94,6 +95,9 @@ class ServiceConfig:
     hnsw_ef_search: int = 32
     hnsw_layout: str = "rows"    # "blocked" = neighbour-blocked expand stage
     hnsw_shards: int | None = None  # fan-out HNSW over N per-device shards
+    hnsw_build_round: int = DEFAULT_BUILD_ROUND  # the bulk build's largest
+    #   round of rows inserted together; like efConstruction it defines
+    #   the graph
     residency: str = "device"    # "tiered" = host-resident full rows,
     #   double-buffered host->HBM streaming rescore (store-backed engines)
     tier_chunk_rows: int | None = None  # brute tiered: rows per streamed
@@ -167,6 +171,13 @@ class SearchService:
         self.n_inserts = 0
         self.search_time = 0.0
         self.insert_time = 0.0
+        # the build gauges describe the graph, not a window: set again
+        for name, eng in getattr(self, "engines", {}).items():
+            b = getattr(eng, "build_stats", None)
+            if b:
+                self._m_hnsw_build_rows.set(b["rows"], engine=name)
+                self._m_hnsw_build_s.set(b["seconds"], engine=name)
+                self._m_hnsw_build_rounds.set(b["rounds"], engine=name)
 
     def _init_metrics(self) -> None:
         """Declare the service metric families (ISSUE 8). Families are
@@ -214,6 +225,26 @@ class SearchService:
         self._m_tier_stall_frac = m.gauge(
             "service_tiered_stall_fraction",
             "stall fraction of the last tiered search", labels=("engine",))
+        self._m_hnsw_build_rows = m.gauge(
+            "service_hnsw_build_rows", "rows the HNSW bulk build inserted",
+            labels=("engine",))
+        self._m_hnsw_build_s = m.gauge(
+            "service_hnsw_build_seconds", "seconds the HNSW bulk build took",
+            labels=("engine",))
+        self._m_hnsw_build_rounds = m.gauge(
+            "service_hnsw_build_rounds", "rounds of the HNSW bulk build",
+            labels=("engine",))
+        self._m_hnsw_evals = m.counter(
+            "service_hnsw_neighbour_evals_total",
+            "neighbours the HNSW traversal scored", labels=("engine",))
+        self._m_hnsw_iters = m.counter(
+            "service_hnsw_iters_total",
+            "HNSW traversal iterations, summed over queries",
+            labels=("engine",))
+        self._m_hnsw_max_iters = m.counter(
+            "service_hnsw_max_iters_hit_total",
+            "queries whose HNSW traversal stopped at its iteration budget",
+            labels=("engine",))
 
     def _engine_kwargs(self, name: str) -> dict:
         """ServiceConfig -> engine constructor knobs (shared by fresh builds
@@ -243,7 +274,8 @@ class SearchService:
                         ef_search=cfg.hnsw_ef_search, seed=cfg.seed,
                         backend=cfg.backend, layout=cfg.hnsw_layout,
                         shards=cfg.hnsw_shards, metric=cfg.metric,
-                        fp_bits=cfg.fp_bits)
+                        fp_bits=cfg.fp_bits,
+                        build_round=cfg.hnsw_build_round)
         raise ValueError(
             f"unknown engine {name!r}; expected one of {ENGINE_NAMES}")
 
@@ -661,6 +693,11 @@ class SearchService:
         st = getattr(eng, "stats", None)
         if not st:
             return
+        if isinstance(eng, HNSWEngine):
+            self._m_hnsw_evals.inc(st.get("neighbour_evals", 0), engine=ename)
+            self._m_hnsw_iters.inc(st.get("iters", 0), engine=ename)
+            self._m_hnsw_max_iters.inc(st.get("max_iters_hit", 0),
+                                       engine=ename)
         if st.get("residency") == "tiered":
             self._m_tier_stall.set(st.get("tiered_stall_s", 0.0),
                                    engine=ename)

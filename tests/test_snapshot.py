@@ -128,7 +128,9 @@ def test_service_snapshot_restore_search_parity(tmp_path, engines, backend,
                                                 shards):
     """SearchService.open hydrates a replica whose results are bit-identical
     to the live service and to a never-crashed rebuild — including sharded
-    HNSW graphs re-committed to their devices and the tpu kernel path."""
+    HNSW graphs re-committed to their devices and the tpu kernel path.
+    The rebuild is built over the same base rows and inserts the rest, as
+    an HNSW graph is defined by where its bulk build stopped."""
     d = tmp_path / "svc"
     svc = SearchService(BASE, engines=engines, durable_dir=str(d),
                         backend=backend, compact_threshold=20,
@@ -142,10 +144,11 @@ def test_service_snapshot_restore_search_parity(tmp_path, engines, backend,
     svc.close()
 
     svc2 = SearchService.open(d)
-    reb = SearchService(np.concatenate([BASE, EXTRA[:50]]), engines=engines,
+    reb = SearchService(BASE, engines=engines,
                         backend=backend, compact_threshold=20, hnsw_m=4,
                         hnsw_ef_construction=12, hnsw_ef_search=16,
                         hnsw_shards=shards)
+    reb.insert(EXTRA[:50])      # the bulk build stopped at BASE, as it did
     for e in engines:
         got = svc2.search(QUERIES, 8, engine=e)
         ref = reb.search(QUERIES, 8, engine=e)
