@@ -13,7 +13,7 @@
   SEARCH-LAYER-BASE beam search (Alg. 2):
 
   - two fixed-shape **register-array priority queues** per query (candidates
-    C, results M) from ``core/topk.py`` — compare-and-shift / rank-merge
+    C, results M) from ``core/topk.py`` — compare-and-shift / merge
     semantics, the paper's Fig. 9 structure;
   - a **fine-grained gather-distance stage** scoring one whole beam
     expansion (``beam * 2M`` neighbour ids per query) per launch, on one of
@@ -809,7 +809,7 @@ def search_hnsw(g: HNSWDeviceGraph, queries: jax.Array, k: int, ef: int,
     ``lax.while_loop``: per iteration each still-active query pops its best
     ``beam`` candidates from the candidate queue C, gathers their base-layer
     adjacency (``beam * 2M`` neighbour ids), scores all of them in ONE
-    gather-distance launch (``score_fn``), and rank-merges the scored batch
+    gather-distance launch (``score_fn``), and merges the scored batch
     into both fixed-shape register-array queues (C and the result set M,
     ``core/topk.py``). A per-query visited bitset gives exactly-once scoring;
     queries terminate individually (Alg. 2 bound: best candidate worse than
@@ -887,8 +887,10 @@ def _beam_search(adj, queries, q_cnt, init_s, init_i, ef: int, beam: int,
     does (:func:`_search_layer_np`): a node's key is its own, so no tie
     depends on the order neighbours arrive in. The build uses it, so a
     build of one row a round retraces the sequential insertion; the search
-    path keeps the arrival order (``merge_sorted``), which the kernels'
-    runs give."""
+    path keeps the arrival order, which the kernels' runs give: each
+    iteration's sorted run folds into both queues by ``merge_sorted``, one
+    stable sort of ``[queue, run]`` that carries the ids, so ties keep the
+    queue's entries first, with no loop, gather or scatter."""
     beam = max(1, min(beam, ef))
     if max_iters is None:
         max_iters = 4 * ef + 16
@@ -993,7 +995,7 @@ def _beam_search(adj, queries, q_cnt, init_s, init_i, ef: int, beam: int,
 
         # fused expansion stage: gather + score + evict-filter + sort for
         # all B*2M neighbours per query in one launch; the sorted run
-        # rank-merges into both queues (Fig. 9)
+        # merges into both queues (Fig. 9)
         if id_ties:
             s = score_fn(queries, q_cnt, flat)
             keep = _above(s, flat, worst[:, None], worst_i[:, None])

@@ -14,9 +14,9 @@ One module models the paper's two sorting structures (DESIGN.md §2, Fig. 9):
     When full, the worst entry falls off the end (the paper's bounded result
     set M behaviour — "evict worst").
   - :func:`pq_insert_batch` — merge a batch of E unsorted candidates: one
-    sort of the batch (``lax.top_k``) followed by a rank-computation merge of
-    the two sorted runs (:func:`merge_sorted`), i.e. a *merge* network, not a
-    re-sort of the whole queue.
+    sort of the batch (``lax.top_k``) followed by :func:`merge_sorted` of
+    the two sorted runs (one stable sort of their concatenation that
+    carries the payloads: no loop, gather or scatter).
   - :func:`pq_pop` / :func:`pq_pop_many` — pop the best 1 / B entries and
     shift the array up (the candidate set C of HNSW's SEARCH-LAYER).
   - :func:`pq_worst` — the current eviction threshold (``NEG_INF`` while the
@@ -109,28 +109,28 @@ def merge_sorted(s_a: jax.Array, i_a: jax.Array,
                  s_b: jax.Array, i_b: jax.Array):
     """Merge two descending-sorted runs, keeping the best ``len(s_a)``.
 
-    Rank-computation merge (the constant-shape analogue of a merge network):
-    each element's output position is its own index plus the count of
-    elements from the other run strictly ahead of it — two vectorised
-    ``searchsorted`` calls and one scatter, no re-sort. Ties place run-A
-    elements first, so re-merging is stable w.r.t. the existing queue.
+    One stable sort of the concatenation ``[run A, run B]``, keyed on the
+    negated scores and carrying the payloads as a second operand, so the
+    ids move with their scores and no loop, gather or scatter is emitted
+    (the merge runs twice per HNSW traversal iteration). Stability gives
+    the merge's order: ties place run-A elements first and each run keeps
+    its own order, so re-merging is stable w.r.t. the existing queue;
+    ``NEG_INF`` / ``-1`` pads sort last.
     """
-    a, b = s_a.shape[0], s_b.shape[0]
-    na, nb = -s_a, -s_b                       # ascending views
-    pos_a = jnp.arange(a) + jnp.searchsorted(nb, na, side="left")
-    pos_b = jnp.arange(b) + jnp.searchsorted(na, nb, side="right")
-    out_s = jnp.zeros((a + b,), s_a.dtype).at[pos_a].set(s_a).at[pos_b].set(s_b)
-    out_i = jnp.zeros((a + b,), i_a.dtype).at[pos_a].set(i_a).at[pos_b].set(i_b)
-    return out_s[:a], out_i[:a]
+    a = s_a.shape[0]
+    neg, ids = jax.lax.sort((-jnp.concatenate([s_a, s_b]),
+                             jnp.concatenate([i_a, i_b])),
+                            num_keys=1, is_stable=True)
+    return -neg[:a], ids[:a]
 
 
 def merge_sorted_many(scores: jax.Array, ids: jax.Array):
-    """Rank-merge ``S`` descending-sorted runs into the best ``cap``.
+    """Merge ``S`` descending-sorted runs into the best ``cap``.
 
     ``scores (S, cap)`` / ``ids (S, cap)`` are stacked per-shard result runs
     (the sharded-HNSW fan-out); the reduction is a **left-leaning pairwise
     merge tree** of :func:`merge_sorted` calls — ``ceil(log2 S)`` levels,
-    each level one vmapped rank-merge. Ties keep the lower run index first
+    each level one vmapped merge. Ties keep the lower run index first
     at every level (``merge_sorted`` places run A ahead), so equal scores
     come back ordered by shard index — the deterministic cross-shard order
     the sharded engines and their parity tests rely on. Sentinel slots
@@ -157,7 +157,7 @@ def merge_sorted_many(scores: jax.Array, ids: jax.Array):
 def pq_insert_batch(pq: PQ, scores: jax.Array, payloads: jax.Array) -> PQ:
     """Merge a batch of E unsorted candidates into the queue.
 
-    Sorts the batch once (only its best ``cap`` can matter), then rank-merges
+    Sorts the batch once (only its best ``cap`` can matter), then merges
     the two sorted runs. ``NEG_INF`` scores never displace valid entries.
     """
     kk = min(scores.shape[0], pq.cap)

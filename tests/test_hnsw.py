@@ -314,3 +314,51 @@ def test_batched_build_recall_matches_the_sequential_build():
             sims, np.take_along_axis(s, ids, axis=1))   # exact scores
         recall[name] = float(np.mean(sims >= kth[:, None]))
     assert recall["batched"] >= recall["sequential"] - 0.01, recall
+
+
+# -- the traversal's queue merge ------------------------------------------------
+
+def _searchsorted_merge(s_a, i_a, s_b, i_b):
+    """The rank merge by two binary searches and a scatter that the
+    traversal's queues used before: kept here only as an oracle."""
+    import jax.numpy as jnp
+    a, b = s_a.shape[0], s_b.shape[0]
+    na, nb = -s_a, -s_b
+    pos_a = jnp.arange(a) + jnp.searchsorted(nb, na, side="left")
+    pos_b = jnp.arange(b) + jnp.searchsorted(na, nb, side="right")
+    out_s = jnp.zeros((a + b,), s_a.dtype).at[pos_a].set(s_a).at[pos_b].set(s_b)
+    out_i = jnp.zeros((a + b,), i_a.dtype).at[pos_a].set(i_a).at[pos_b].set(i_b)
+    return out_s[:a], out_i[:a]
+
+
+@pytest.mark.parametrize("layout", hn.LAYOUTS)
+def test_search_is_bit_identical_to_the_searchsorted_merge(tiny_index, layout,
+                                                           monkeypatch):
+    """The jnp-backend traversal returns the same ids, sims and
+    TraversalStats, bit for bit, with the merge it has and with the
+    binary-search merge: the queues hold the same entries in the same order
+    at every iteration."""
+    import jax
+    import jax.numpy as jnp
+    db, idx = tiny_index
+    g = hn.to_device_graph(idx, layout=layout)
+    q = jnp.asarray(queries_from_db(db, 16, seed=21))
+
+    def run():
+        expand_fn = None
+        if layout == "blocked":
+            def expand_fn(qs, qc, pop, flat, worst, kk):
+                return hn.expand_scores_jnp(qs, qc, g.nbr_fps, g.nbr_cnt, pop,
+                                            flat, worst, kk)
+        out = jax.jit(lambda qs: hn.search_hnsw(
+            g, qs, 10, 64, beam=4, expand_fn=expand_fn))(q)
+        return jax.tree.map(np.asarray, out)
+
+    ids, sims, stats = run()
+    monkeypatch.setattr(hn, "merge_sorted", _searchsorted_merge)
+    ids_o, sims_o, stats_o = run()
+    np.testing.assert_array_equal(ids, ids_o)
+    np.testing.assert_array_equal(sims.view(np.int32), sims_o.view(np.int32))
+    for got, want in zip(stats, stats_o):
+        np.testing.assert_array_equal(got, want)
+    assert (ids >= 0).all() and stats.iters.sum() > 0

@@ -1,6 +1,9 @@
 """Register-array priority queue invariants + streaming top-k properties."""
+import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
+from jax.extend.core import ClosedJaxpr, Jaxpr
 try:
     from hypothesis import given, settings, strategies as st
 except ImportError:  # collection-safe fallback (see tests/_propcheck.py)
@@ -200,3 +203,91 @@ def test_pq_worst_tracks_eviction_threshold():
     assert abs(float(pq_worst(pq)) - 0.3) < 1e-6    # full: worst retained
     pq = pq_insert(pq, jnp.float32(0.5), jnp.int32(1))
     assert abs(float(pq_worst(pq)) - 0.5) < 1e-6    # 0.3 evicted
+
+
+# -- merge_sorted, bit for bit against the stable sort ------------------------
+
+# few distinct values, so ties within a run and across the two runs abound;
+# -0.0 and 0.0 compare equal, so they tie as well
+_TIE_GRID = np.float32([1.0, 0.75, 0.5, 0.5, 0.25, 1 / 3, 0.0, -0.0])
+
+
+def _tied_runs(rng, rows, n):
+    """``rows`` descending runs of length ``n`` over :data:`_TIE_GRID`,
+    each with a ``NEG_INF`` / ``-1`` pad tail of random length (0 to n);
+    ids distinct across runs, so a misplaced entry shows in the ids."""
+    s = -np.sort(-rng.choice(_TIE_GRID, (rows, n)), axis=1)
+    i = rng.permutation(1 << 20)[:rows * n].reshape(rows, n).astype(np.int32)
+    pads = rng.integers(0, n + 1, rows)
+    tail = np.arange(n)[None, :] >= (n - pads)[:, None]
+    return (np.where(tail, -np.inf, s).astype(np.float32),
+            np.where(tail, -1, i).astype(np.int32))
+
+
+def _stable_oracle(s_a, i_a, s_b, i_b):
+    """The best ``len(s_a)`` of the concatenation by a stable descending
+    sort: ties keep run A first and each run's own order."""
+    s = np.concatenate([s_a, s_b], axis=-1)
+    i = np.concatenate([i_a, i_b], axis=-1)
+    order = np.argsort(-s, axis=-1, kind="stable")[..., :s_a.shape[-1]]
+    return (np.take_along_axis(s, order, axis=-1),
+            np.take_along_axis(i, order, axis=-1))
+
+
+def _assert_bits_equal(got_s, got_i, want_s, want_i):
+    np.testing.assert_array_equal(np.asarray(got_s).view(np.int32),
+                                  want_s.view(np.int32))
+    np.testing.assert_array_equal(np.asarray(got_i), want_i)
+
+
+@pytest.mark.parametrize("a,b", [(64, 64), (40, 7), (7, 40), (1, 1)])
+@given(st.integers(0, 2**32 - 1))
+@settings(max_examples=25, deadline=None)
+def test_merge_sorted_is_the_stable_sort_bit_for_bit(a, b, seed):
+    """Scores and ids both: the best ``a`` of the two runs, descending, run A
+    ahead of run B on ties, each run in its own order, pads last."""
+    rng = np.random.default_rng(seed)
+    s_a, i_a = _tied_runs(rng, 1, a)
+    s_b, i_b = _tied_runs(rng, 1, b)
+    got = merge_sorted(jnp.asarray(s_a[0]), jnp.asarray(i_a[0]),
+                       jnp.asarray(s_b[0]), jnp.asarray(i_b[0]))
+    _assert_bits_equal(*got, *_stable_oracle(s_a[0], i_a[0], s_b[0], i_b[0]))
+
+
+def test_merge_sorted_vmapped_at_the_traversal_shape():
+    """(Q, 64) + (Q, 64) under ``vmap``, as the HNSW traversal merges each
+    iteration's sorted run into its two queues."""
+    rng = np.random.default_rng(17)
+    s_a, i_a = _tied_runs(rng, 256, 64)
+    s_b, i_b = _tied_runs(rng, 256, 64)
+    got = jax.jit(jax.vmap(merge_sorted))(s_a, i_a, s_b, i_b)
+    _assert_bits_equal(*got, *_stable_oracle(s_a, i_a, s_b, i_b))
+
+
+def _primitive_names(jaxpr):
+    """Every primitive of ``jaxpr``, sub-jaxprs (loop bodies, branches,
+    nested jits) included."""
+    names = set()
+    for eqn in jaxpr.eqns:
+        names.add(eqn.primitive.name)
+        for p in eqn.params.values():
+            for sub in (p if isinstance(p, (tuple, list)) else (p,)):
+                if isinstance(sub, ClosedJaxpr):
+                    names |= _primitive_names(sub.jaxpr)
+                elif isinstance(sub, Jaxpr):
+                    names |= _primitive_names(sub)
+    return names
+
+
+@pytest.mark.parametrize("batched", [False, True])
+def test_merge_sorted_has_no_loop_gather_or_scatter(batched):
+    """The merge runs twice per traversal iteration: a binary search lowers
+    to a loop of batched gathers, and placing by scatter to a scatter, so
+    neither may come back."""
+    fn = jax.vmap(merge_sorted) if batched else merge_sorted
+    shape = (8, 64) if batched else (64,)
+    s, i = jnp.zeros(shape, jnp.float32), jnp.zeros(shape, jnp.int32)
+    names = _primitive_names(jax.make_jaxpr(fn)(s, i, s, i).jaxpr)
+    banned = {n for n in names
+              if n in ("while", "scan") or "gather" in n or "scatter" in n}
+    assert not banned, banned
