@@ -903,7 +903,10 @@ def _beam_search(adj, queries, q_cnt, init_s, init_i, ef: int, beam: int,
         def expand_fn(qs, qc, pop_ids, flat, worst, kk):
             # rows layout: scattered row gather + score, then evict-filter
             # and one sort (the run feeds BOTH queues — pq_insert_batch
-            # would sort twice)
+            # would sort twice). The sort stays although merge_sorted sorts
+            # again: it cuts the run from beam*2M to kk <= ef once, so each
+            # of the two merges sorts ef + kk entries, and it returns the
+            # sorted kk-run the blocked layout's expand stage returns
             s = score_fn(qs, qc, flat)
             keep = s > worst[:, None]                    # evict-worst filter
             s = jnp.where(keep, s, NEG_INF)

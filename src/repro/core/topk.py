@@ -158,7 +158,11 @@ def pq_insert_batch(pq: PQ, scores: jax.Array, payloads: jax.Array) -> PQ:
     """Merge a batch of E unsorted candidates into the queue.
 
     Sorts the batch once (only its best ``cap`` can matter), then merges
-    the two sorted runs. ``NEG_INF`` scores never displace valid entries.
+    the two sorted runs. The sort stays although :func:`merge_sorted` sorts
+    too: it cuts the batch to ``cap``, so the merge sorts at most ``2 * cap``
+    entries and not ``cap + E`` (E is a whole tile in
+    :func:`streaming_topk`). ``NEG_INF`` scores never displace valid
+    entries.
     """
     kk = min(scores.shape[0], pq.cap)
     s_sorted, pos = jax.lax.top_k(scores, kk)

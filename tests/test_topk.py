@@ -241,17 +241,23 @@ def _assert_bits_equal(got_s, got_i, want_s, want_i):
 
 
 @pytest.mark.parametrize("a,b", [(64, 64), (40, 7), (7, 40), (1, 1)])
-@given(st.integers(0, 2**32 - 1))
-@settings(max_examples=25, deadline=None)
-def test_merge_sorted_is_the_stable_sort_bit_for_bit(a, b, seed):
+def test_merge_sorted_is_the_stable_sort_bit_for_bit(a, b):
     """Scores and ids both: the best ``a`` of the two runs, descending, run A
     ahead of run B on ties, each run in its own order, pads last."""
-    rng = np.random.default_rng(seed)
-    s_a, i_a = _tied_runs(rng, 1, a)
-    s_b, i_b = _tied_runs(rng, 1, b)
-    got = merge_sorted(jnp.asarray(s_a[0]), jnp.asarray(i_a[0]),
-                       jnp.asarray(s_b[0]), jnp.asarray(i_b[0]))
-    _assert_bits_equal(*got, *_stable_oracle(s_a[0], i_a[0], s_b[0], i_b[0]))
+    # the property is drawn inside the case: ``_propcheck``'s ``given``
+    # wraps a test that takes no pytest arguments
+    @given(st.integers(0, 2**32 - 1))
+    @settings(max_examples=25, deadline=None)
+    def check(seed):
+        rng = np.random.default_rng(seed)
+        s_a, i_a = _tied_runs(rng, 1, a)
+        s_b, i_b = _tied_runs(rng, 1, b)
+        got = merge_sorted(jnp.asarray(s_a[0]), jnp.asarray(i_a[0]),
+                           jnp.asarray(s_b[0]), jnp.asarray(i_b[0]))
+        _assert_bits_equal(*got,
+                           *_stable_oracle(s_a[0], i_a[0], s_b[0], i_b[0]))
+
+    check()
 
 
 def test_merge_sorted_vmapped_at_the_traversal_shape():
