@@ -10,8 +10,7 @@ can have Tanimoto(query, cand) >= Sc (because S <= min(a,b)/max(a,b)).
 The contiguous popcount-sorted range is located with two searchsorted ops and
 the scan is restricted to it.  The paper models the pruned fraction with a
 Gaussian fit of the popcount distribution (Eq. 3) — reproduced in
-``gaussian_model`` / ``expected_speedup`` and benchmarked in
-``benchmarks/bitbound_speedup.py`` (Fig. 2).
+``gaussian_model`` / ``expected_speedup`` (Fig. 2).
 """
 from __future__ import annotations
 

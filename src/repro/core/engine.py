@@ -62,7 +62,7 @@ scores and the ``scanned`` count, in one ``jax.device_get``);
 ``engine.sync`` is the store->device refresh after inserts or a
 compaction. No span waits on the device.
 
-The engine x backend x layout matrix is summarised in README.md; data
+The engine x backend matrix is summarised in README.md; data
 layouts and the request path are documented in docs/ARCHITECTURE.md.
 """
 from __future__ import annotations
@@ -134,10 +134,6 @@ class SearchEngine:
 
     BACKENDS: tuple = ("jnp", "tpu")
     DEFAULT_BACKEND: str = "jnp"
-    #: memory layouts the engine's device path can run on; engines with a
-    #: ``layout`` field (HNSW: "rows" row-gather / "blocked"
-    #: neighbour-blocked streaming) extend this
-    LAYOUTS: tuple = ("rows",)
     #: residency modes the engine supports; the exhaustive engines extend
     #: this with "tiered" (full-resolution rows stay in host memory and are
     #: streamed into a double-buffered HBM staging window — ISSUE 7)
@@ -152,12 +148,6 @@ class SearchEngine:
                 f"{type(self).__name__} backend must be one of "
                 f"{'/'.join(repr(b) for b in self.BACKENDS)}, "
                 f"got {self.backend!r}")
-        layout = getattr(self, "layout", None)
-        if layout is not None and layout not in self.LAYOUTS:
-            raise ValueError(
-                f"{type(self).__name__} layout must be one of "
-                f"{'/'.join(repr(x) for x in self.LAYOUTS)}, "
-                f"got {layout!r}")
         self._last_scanned = 0
         self._last_n_queries = 0
         self._last_tiles = (0, 0)
@@ -1248,22 +1238,15 @@ class HNSWEngine(SearchEngine):
     * ``"numpy"`` — host reference traversal, true variable-length queues
       (:func:`repro.core.hnsw.search_hnsw_numpy`).
     * ``"jnp"``   — batched device-resident traversal with the jnp
-      gather-distance stage.
-    * ``"tpu"``   — same traversal with the Pallas ``gather_tanimoto``
-      kernel as the fine-grained distance stage.
+      expansion stage (:func:`repro.core.hnsw.expand_scores_jnp`).
+    * ``"tpu"``   — same traversal with the Pallas kernels: the fused
+      gather/score/sort expand kernel (``kernels/expand.py``) and the
+      ``gather_tanimoto`` row kernel for the entry points.
 
-    ``layout`` selects the fine-grained distance stage's memory layout
-    (bit-exact results either way):
-
-    * ``"rows"``    — scattered row gather per neighbour id (the ``(Q, E)``
-      kernel / jnp twin). One 128-byte fetch per neighbour.
-    * ``"blocked"`` — neighbour-blocked base layer (``nbr_fps (N, 2M, W)``):
-      one contiguous stream per popped node through the fused
-      gather/score/sort expand kernel (``kernels/expand.py``) or its jnp
-      twin — ``beam`` DMA streams per query-iteration instead of
-      ``beam*2M`` row fetches, at the HBM cost of one extra ``2M*W``-word
-      copy of the base layer. Upper layers and entry-point scoring keep the
-      row kernel (tiny, adjacency is M-wide).
+    The expansion stage streams a neighbour-blocked copy of the base layer
+    (``nbr_fps (N, 2M, W)``): one contiguous block per popped node, ``beam``
+    DMA streams per query-iteration instead of ``beam*2M`` row fetches, at
+    the HBM cost of one extra ``2M*W``-word copy of the base layer.
 
     ``beam`` is the number of candidates expanded per traversal iteration
     (``beam * 2M`` neighbours scored per kernel launch); ``beam=None`` (the
@@ -1285,10 +1268,9 @@ class HNSWEngine(SearchEngine):
     their shard (``gid % N``) and only touched shards refresh their device
     graphs (a full per-shard rebuild at the padded capacity — compiled
     traversals are reused; the unsharded path's finer dirty-row scatter is
-    not yet wired through the fan-out). The blocked layout shards
-    naturally: each shard packs only its own nodes' ``nbr_fps``, so the
-    extra ``2M*W``-word HBM copy is split N ways (the roofline budget in
-    ``benchmarks/roofline.py --gather``).
+    not yet wired through the fan-out). Each shard packs only its own
+    nodes' ``nbr_fps``, so the extra ``2M*W``-word HBM copy is split N
+    ways.
 
     The graph is built on the device in rounds of at most ``build_round``
     rows (:func:`repro.core.hnsw.build_hnsw`); the built adjacency becomes
@@ -1322,7 +1304,6 @@ class HNSWEngine(SearchEngine):
     index: hn.HNSWIndex = None
     _graph: hn.HNSWDeviceGraph = None
     backend: str | None = None
-    layout: str = "rows"
     beam: int | None = None
     max_iters: int | None = None
     shards: int | None = None
@@ -1340,7 +1321,6 @@ class HNSWEngine(SearchEngine):
 
     BACKENDS = ("numpy", "jnp", "tpu")
     DEFAULT_BACKEND = "jnp"
-    LAYOUTS = hn.LAYOUTS
 
     def _check_index_metric(self, index) -> None:
         built = getattr(index, "metric", TANIMOTO) or TANIMOTO
@@ -1437,7 +1417,7 @@ class HNSWEngine(SearchEngine):
                     and g.db.shape[0] == cap:
                 continue
             self._shard_graphs[s] = hn.place_graph(
-                hn.to_device_graph(idx, capacity=cap, layout=self.layout,
+                hn.to_device_graph(idx, capacity=cap,
                                    kernel=self.backend == "tpu"),
                 self._shard_devices[s])
             idx.device_build = None
@@ -1457,13 +1437,12 @@ class HNSWEngine(SearchEngine):
                 or self._graph_n > idx.n
                 or self._dirty_epoch != idx.dirty_epoch):
             self._graph = hn.to_device_graph(idx, capacity=cap,
-                                             layout=self.layout,
                                              kernel=self.backend == "tpu")
             idx.device_build = None    # the graph holds what it needs
         else:
             # incremental refresh: scatter only the rows the insert batches
-            # touched (new fingerprints + dirty base-adjacency rows and, on
-            # the blocked layout, their neighbour blocks) into the device
+            # touched (new fingerprints + dirty base-adjacency rows and
+            # their neighbour blocks) into the device
             # arrays — the full re-upload only ever happens on a capacity
             # doubling. tests/test_hnsw_backends.py pins this equal to a
             # fresh to_device_graph.
@@ -1487,14 +1466,13 @@ class HNSWEngine(SearchEngine):
                 if g.db_lines is not None:
                     lids = kops.line_ids(idx.db.shape[1], rows)
                     put("db_lines", lids, kops.db_lines_at(idx.db, lids))
-                if self.layout == "blocked":
-                    fps_np, cnt_np = hn._blocked_rows(
-                        idx.db, idx.db_popcount, adj_rows)
-                    if g.nbr_slabs is not None:
-                        put("nbr_slabs", rows, kops.nbr_slabs(fps_np))
-                    else:
-                        put("nbr_fps", rows, fps_np)
-                        put("nbr_cnt", rows, cnt_np)
+                fps_np, cnt_np = hn._blocked_rows(
+                    idx.db, idx.db_popcount, adj_rows)
+                if g.nbr_slabs is not None:
+                    put("nbr_slabs", rows, kops.nbr_slabs(fps_np))
+                else:
+                    put("nbr_fps", rows, fps_np)
+                    put("nbr_cnt", rows, cnt_np)
             # upper layers only change when a batch inserted a level>0 node
             # (index.upper_version) — level-0-only batches skip the O(cap)
             # densify+upload entirely
@@ -1569,34 +1547,26 @@ class HNSWEngine(SearchEngine):
 
     def _device_search(self, k: int, ef: int, beam: int, max_level: int):
         use_kernel = self.backend == "tpu"
-        layout = self.layout
         max_iters = self.max_iters
         metric = self.metric
-        key = (k, ef, beam, max_level, use_kernel, layout, metric)
+        key = (k, ef, beam, max_level, use_kernel, metric)
 
         def build():
             # the traversal's program is ``jit_hnsw_traverse`` in a trace
             def hnsw_traverse(q, arrays):
                 g = arrays._replace(max_level=max_level)
-                score_fn = None
-                expand_fn = None
+                score_fn = expand_fn = None     # search_hnsw's jnp stages
                 if use_kernel:
                     from ..kernels import ops as kops
 
                     def score_fn(qs, qc, ids):
                         return kops.gather_tanimoto(qs, g.db_lines, ids,
                                                     q_cnt=qc, metric=metric)
-                if layout == "blocked":
-                    if use_kernel:
-                        def expand_fn(qs, qc, pop, flat, worst, kk):
-                            return kops.expand_tanimoto_sorted(
-                                qs, g.nbr_slabs, pop, flat, worst, kk,
-                                q_cnt=qc, metric=metric)
-                    else:
-                        def expand_fn(qs, qc, pop, flat, worst, kk):
-                            return hn.expand_scores_jnp(
-                                qs, qc, g.nbr_fps, g.nbr_cnt, pop, flat,
-                                worst, kk, metric=metric)
+
+                    def expand_fn(qs, qc, pop, flat, worst, kk):
+                        return kops.expand_tanimoto_sorted(
+                            qs, g.nbr_slabs, pop, flat, worst, kk,
+                            q_cnt=qc, metric=metric)
                 return hn.search_hnsw(g, q, k, ef, max_iters=max_iters,
                                       beam=beam, score_fn=score_fn,
                                       expand_fn=expand_fn, metric=metric)
@@ -1654,7 +1624,6 @@ class HNSWEngine(SearchEngine):
         self._record_batch(int(expans.sum()) * m2, q_n)
         self.stats = {
             "backend": self.backend,
-            "layout": self.layout,
             "shards": n_shards,
             **hn.stats_summary(iters, expans, reason, m2),
             "iters_per_query": iters.sum(axis=0),
@@ -1709,7 +1678,6 @@ class HNSWEngine(SearchEngine):
             self._record_batch(int(expans.sum()) * m2, iters.shape[0])
             self.stats = {
                 "backend": self.backend,
-                "layout": self.layout,
                 **hn.stats_summary(iters, expans, tstats.reason, m2),
                 "iters_per_query": iters,
                 "expansions_per_query": expans,

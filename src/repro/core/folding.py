@@ -20,8 +20,7 @@ full-resolution DB and returns the top k.
 
 Because folding only ORs bits, folded Tanimoto is a *biased estimate*; the
 two-stage rescore restores exactness on everything stage 1 retains, so the
-only error source is stage-1 recall — measured in Table I terms by
-``benchmarks/folding_accuracy.py``.
+only error source is stage-1 recall (Table I).
 """
 from __future__ import annotations
 

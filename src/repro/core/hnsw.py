@@ -15,16 +15,16 @@
   - two fixed-shape **register-array priority queues** per query (candidates
     C, results M) from ``core/topk.py`` — compare-and-shift / merge
     semantics, the paper's Fig. 9 structure;
-  - a **fine-grained gather-distance stage** scoring one whole beam
-    expansion (``beam * 2M`` neighbour ids per query) per launch, on one of
-    two memory layouts (bit-exact results): ``rows`` — per-neighbour row
-    gather (Pallas scalar-prefetch kernel ``kernels.ops.gather_tanimoto`` or
-    its jnp twin :func:`score_ids_jnp`) — or ``blocked`` — a neighbour-packed
-    copy of the base layer (``nbr_fps (N, 2M, W)``) streamed one contiguous
-    block per popped node through the fused gather/score/sort kernel
-    ``kernels.ops.expand_tanimoto_sorted`` (jnp twin
-    :func:`expand_scores_jnp`), ``beam`` DMA streams per query-iteration
-    instead of ``beam*2M`` scattered row fetches;
+  - a **fused expansion stage** scoring one whole beam expansion
+    (``beam * 2M`` neighbour ids per query) per launch over a
+    neighbour-packed copy of the base layer (``nbr_fps (N, 2M, W)``): one
+    contiguous block per popped node streams through the fused
+    gather/score/sort kernel ``kernels.ops.expand_tanimoto_sorted`` (jnp
+    twin :func:`expand_scores_jnp`), ``beam`` DMA streams per
+    query-iteration instead of ``beam*2M`` scattered row fetches; the row
+    gather (``kernels.ops.gather_tanimoto``, jnp twin
+    :func:`score_ids_jnp`) scores the entry point, the greedy descent, the
+    build and the online inserts' frontiers;
   - per-query **termination** (Alg. 2 bound) with a global ``max_iters``
     budget; per-query telemetry (iterations, expansions, stop reason) comes
     back as :class:`TraversalStats`.
@@ -118,7 +118,7 @@ class HNSWIndex:
     rng: np.random.Generator | None = field(default=None, repr=False)
     # log of nodes whose base_adj row changed (inserted nodes +
     # bidirectional-link updates); engines consume suffixes of it to update
-    # device copies (incl. the neighbour-blocked layout) incrementally.
+    # device copies (incl. the neighbour blocks) incrementally.
     # Bounded: when it outgrows ~2n entries it is cleared and ``dirty_epoch``
     # bumps, forcing stale consumers to full-rebuild instead of leaking host
     # memory under sustained insert streams.
@@ -566,18 +566,18 @@ def auto_beam(ef_search: int) -> int:
 class HNSWDeviceGraph(NamedTuple):
     """Device-resident, constant-shape view of the index for the JAX engine.
 
-    ``layout="blocked"`` additionally carries the **neighbour-blocked copy of
-    the base layer** (ISSUE 4): ``nbr_fps[v] = db[base_adj[v]]`` with zero
-    rows for ``-1`` slots, plus the matching popcounts — one popped node's
-    whole expansion is a single contiguous ``2M*W``-word HBM stream for the
-    fused expand kernel, at the HBM cost of one extra ``2M*W``-word copy of
-    the base layer per node.
+    Besides the rows and the adjacency it carries the **neighbour-blocked
+    copy of the base layer** the expansion stage streams: ``nbr_fps[v] =
+    db[base_adj[v]]`` with zero rows for ``-1`` slots, plus the matching
+    popcounts — one popped node's whole expansion is a single contiguous
+    ``2M*W``-word HBM stream, at the HBM cost of one extra ``2M*W``-word
+    copy of the base layer per node.
 
-    A graph for the Pallas kernels (``kernel=True``) also holds the database
-    in the gather kernel's line layout (``db_lines``) and, on the blocked
-    layout, the neighbour blocks in the expand kernel's slab layout
-    (``nbr_slabs``) in place of ``nbr_fps``/``nbr_cnt`` — built once here,
-    so no traversal relays them out per call.
+    A graph for the Pallas kernels (``kernel=True``) holds the database also
+    in the gather kernel's line layout (``db_lines``), and the neighbour
+    blocks in the expand kernel's slab layout (``nbr_slabs``) in place of
+    ``nbr_fps``/``nbr_cnt`` — built once here, so no traversal relays them
+    out per call.
     """
     db: jax.Array             # (N, W) uint32
     db_popcount: jax.Array    # (N,) int32
@@ -585,13 +585,10 @@ class HNSWDeviceGraph(NamedTuple):
     upper_adj: jax.Array      # (L, N, M) int32 — dense per-level adjacency (-1 pad)
     entry_point: jax.Array    # () int32
     max_level: int
-    nbr_fps: jax.Array | None = None   # (N, 2M, W) uint32 — blocked, jnp
-    nbr_cnt: jax.Array | None = None   # (N, 2M) int32 — blocked, jnp
+    nbr_fps: jax.Array | None = None   # (N, 2M, W) uint32 — jnp only
+    nbr_cnt: jax.Array | None = None   # (N, 2M) int32 — jnp only
     db_lines: jax.Array | None = None  # ops.db_lines(db) — kernel only
-    nbr_slabs: jax.Array | None = None  # ops.nbr_slabs(nbr_fps) — kernel, blocked
-
-
-LAYOUTS = ("rows", "blocked")
+    nbr_slabs: jax.Array | None = None  # ops.nbr_slabs(nbr_fps) — kernel only
 
 
 def _dense_upper(index: HNSWIndex, cap: int) -> np.ndarray:
@@ -616,23 +613,20 @@ def _blocked_rows(db: np.ndarray, db_cnt: np.ndarray, adj: np.ndarray):
 
 
 def to_device_graph(index: HNSWIndex, capacity: int | None = None,
-                    layout: str = "rows",
                     kernel: bool = False) -> HNSWDeviceGraph:
     """Densify the index for the device engine. ``capacity`` (>= n) pads the
     node dimension — pad rows are zero fingerprints with no edges, so they
     are unreachable and the traversal is unaffected. Engines pad to a power
     of two so online inserts below the capacity reuse compiled traversals.
-    ``layout="blocked"`` also builds the neighbour-blocked base-layer copy,
-    and ``kernel=True`` the Pallas kernels' layouts of the database and of
-    that copy (:class:`HNSWDeviceGraph` docstring)."""
-    if layout not in LAYOUTS:
-        raise ValueError(f"layout must be one of {LAYOUTS}, got {layout!r}")
+    The graph carries the neighbour-blocked base-layer copy, and with
+    ``kernel=True`` the Pallas kernels' layouts of the database and of that
+    copy (:class:`HNSWDeviceGraph` docstring)."""
     L = max(index.max_level, 0)
     n = index.n
     cap = n if capacity is None else max(int(capacity), n)
     bg = index.device_build
     if bg is not None and bg.db.shape[0] == cap and n == index.bulk_rows:
-        return _graph_from_build(bg, index, layout, kernel)
+        return _graph_from_build(bg, index, kernel)
     upper = _dense_upper(index, cap)
     db = np.zeros((cap, index.db.shape[1]), dtype=np.uint32)
     db[:n] = index.db
@@ -641,16 +635,14 @@ def to_device_graph(index: HNSWIndex, capacity: int | None = None,
     base = np.full((cap, index.base_adj.shape[1]), -1, dtype=np.int32)
     base[:n] = index.base_adj
     nbr_fps = nbr_cnt = db_lines = nbr_slabs = None
+    nbr_fps_np, nbr_cnt_np = _blocked_rows(db, cnt, base)
     if kernel:
         from ..kernels import ops as kops
         db_lines = jnp.asarray(kops.db_lines(db))
-    if layout == "blocked":
-        nbr_fps_np, nbr_cnt_np = _blocked_rows(db, cnt, base)
-        if kernel:
-            nbr_slabs = jnp.asarray(kops.nbr_slabs(nbr_fps_np))
-        else:
-            nbr_fps = jnp.asarray(nbr_fps_np)
-            nbr_cnt = jnp.asarray(nbr_cnt_np)
+        nbr_slabs = jnp.asarray(kops.nbr_slabs(nbr_fps_np))
+    else:
+        nbr_fps = jnp.asarray(nbr_fps_np)
+        nbr_cnt = jnp.asarray(nbr_cnt_np)
     return HNSWDeviceGraph(
         db=jnp.asarray(db), db_popcount=jnp.asarray(cnt),
         base_adj=jnp.asarray(base), upper_adj=jnp.asarray(upper),
@@ -659,35 +651,27 @@ def to_device_graph(index: HNSWIndex, capacity: int | None = None,
         nbr_slabs=nbr_slabs)
 
 
-def _graph_from_build(bg, index: HNSWIndex, layout: str,
+def _graph_from_build(bg, index: HNSWIndex,
                       kernel: bool) -> HNSWDeviceGraph:
     """:func:`to_device_graph` of a freshly built index from the build's own
     device arrays: the same graph, with no upload."""
     L = max(index.max_level, 0)
     base = bg.adj[0]
-    nbr_fps = nbr_cnt = db_lines = nbr_slabs = None
+    db_lines = nbr_slabs = None
+    safe = jnp.maximum(base, 0)
+    nbr_fps = jnp.where((base >= 0)[:, :, None], bg.db[safe], 0)
+    nbr_cnt = jnp.where(base >= 0, bg.db_popcount[safe], 0)
     if kernel:
         from ..kernels import ops as kops
         db_lines = kops.db_lines(bg.db)
-    if layout == "blocked":
-        safe = jnp.maximum(base, 0)
-        nbr_fps = jnp.where((base >= 0)[:, :, None], bg.db[safe], 0)
-        nbr_cnt = jnp.where(base >= 0, bg.db_popcount[safe], 0)
-        if kernel:
-            nbr_slabs = kops.nbr_slabs(nbr_fps)
-            nbr_fps = nbr_cnt = None
+        nbr_slabs = kops.nbr_slabs(nbr_fps)
+        nbr_fps = nbr_cnt = None
     return HNSWDeviceGraph(
         db=bg.db, db_popcount=bg.db_popcount, base_adj=base,
         upper_adj=bg.adj[1:max(L, 1) + 1, :, :index.m],
         entry_point=jnp.int32(index.entry_point), max_level=L,
         nbr_fps=nbr_fps, nbr_cnt=nbr_cnt, db_lines=db_lines,
         nbr_slabs=nbr_slabs)
-
-
-def _sims(q: jax.Array, q_cnt: jax.Array, g: HNSWDeviceGraph, ids: jax.Array,
-          metric: Metric = TANIMOTO) -> jax.Array:
-    """Single-query view of :func:`score_ids_jnp` (greedy-descent stage)."""
-    return score_ids_jnp(q[None], q_cnt[None], g, ids[None], metric=metric)[0]
 
 
 def score_ids_jnp(queries: jax.Array, q_cnt: jax.Array, g: HNSWDeviceGraph,
@@ -716,11 +700,11 @@ def expand_scores_jnp(queries: jax.Array, q_cnt: jax.Array,
                       pop_ids: jax.Array, flat_ids: jax.Array,
                       worst: jax.Array, kk: int, metric: Metric = TANIMOTO):
     """Plain-jnp twin of the fused expand kernel (``kernels/expand.py``):
-    gather ``beam`` contiguous neighbour blocks per query from the blocked
-    layout, score, mask ``-1``/sub-threshold slots, return the top-``kk``
-    sorted run. Identical arithmetic to the kernel and to the row path's
-    gather -> score -> filter -> sort chain — the bit-exactness contract
-    between ``layout="blocked"`` and ``layout="rows"``.
+    gather ``beam`` contiguous neighbour blocks per query from the
+    neighbour-blocked copy, score, mask ``-1``/sub-threshold slots, return
+    the top-``kk`` sorted run. Identical arithmetic to the kernel and, bit
+    for bit, to gathering the rows by id, scoring them, filtering at
+    ``worst`` and sorting (``tests/test_expand_kernel.py``).
     """
     q_n = queries.shape[0]
     safe = jnp.maximum(pop_ids, 0)
@@ -809,7 +793,7 @@ def search_hnsw(g: HNSWDeviceGraph, queries: jax.Array, k: int, ef: int,
     ``lax.while_loop``: per iteration each still-active query pops its best
     ``beam`` candidates from the candidate queue C, gathers their base-layer
     adjacency (``beam * 2M`` neighbour ids), scores all of them in ONE
-    gather-distance launch (``score_fn``), and merges the scored batch
+    expansion launch (``expand_fn``), and merges the scored batch
     into both fixed-shape register-array queues (C and the result set M,
     ``core/topk.py``). A per-query visited bitset gives exactly-once scoring;
     queries terminate individually (Alg. 2 bound: best candidate worse than
@@ -820,26 +804,29 @@ def search_hnsw(g: HNSWDeviceGraph, queries: jax.Array, k: int, ef: int,
     with ids descending by similarity (-1 pads) and :class:`TraversalStats`
     device arrays.
 
-    ``score_fn(queries, q_cnt, ids) -> sims`` is the fine-grained distance
-    stage for the entry-point scoring and the ``rows``-layout expansion;
+    ``score_fn(queries, q_cnt, ids) -> sims`` scores the entry points;
     default is the jnp gather (:func:`score_ids_jnp`), engines pass the
     Pallas ``gather_tanimoto`` kernel for the ``tpu`` backend.
 
     ``expand_fn(queries, q_cnt, pop_ids, flat_ids, worst, kk) ->
-    (scores (Q, kk) desc, ids (Q, kk))`` replaces the whole
-    gather -> score -> evict-filter -> sort stage of one beam expansion
-    (``pop_ids (Q, beam)`` are the popped node ids, ``flat_ids (Q, beam*2M)``
-    their adjacency with -1 for pad/visited slots, ``worst (Q,)`` the result
-    queues' eviction bounds). Engines pass the fused blocked-layout kernel
-    (``kernels.ops.expand_tanimoto_sorted``) or its jnp twin
-    (:func:`expand_scores_jnp`) for ``layout="blocked"``; the default is the
-    row-gather chain over ``score_fn``. Either way the emitted run is sorted,
-    so the queues merge it directly (one launch per iteration).
+    (scores (Q, kk) desc, ids (Q, kk))`` is the whole gather -> score ->
+    evict-filter -> sort stage of one beam expansion (``pop_ids (Q, beam)``
+    are the popped node ids, ``flat_ids (Q, beam*2M)`` their adjacency with
+    -1 for pad/visited slots, ``worst (Q,)`` the result queues' eviction
+    bounds) over the graph's neighbour blocks; default is the jnp twin
+    (:func:`expand_scores_jnp`) over ``g.nbr_fps``/``g.nbr_cnt``, engines
+    pass the fused Pallas kernel (``kernels.ops.expand_tanimoto_sorted``)
+    over ``g.nbr_slabs`` for the ``tpu`` backend. The emitted run is
+    sorted, so the queues merge it directly (one launch per iteration).
     """
     ef = max(ef, k)
     if score_fn is None:
         def score_fn(qs, qc, ids):
             return score_ids_jnp(qs, qc, g, ids, metric=metric)
+    if expand_fn is None:
+        def expand_fn(qs, qc, pop_ids, flat_ids, worst, kk):
+            return expand_scores_jnp(qs, qc, g.nbr_fps, g.nbr_cnt, pop_ids,
+                                     flat_ids, worst, kk, metric=metric)
     q_cnt = jnp.sum(jax.lax.population_count(queries).astype(jnp.int32), -1)
 
     # greedy descent through the upper layers (Alg. 1), vmapped per query
@@ -888,9 +875,11 @@ def _beam_search(adj, queries, q_cnt, init_s, init_i, ef: int, beam: int,
     depends on the order neighbours arrive in. The build uses it, so a
     build of one row a round retraces the sequential insertion; the search
     path keeps the arrival order, which the kernels' runs give: each
-    iteration's sorted run folds into both queues by ``merge_sorted``, one
-    stable sort of ``[queue, run]`` that carries the ids, so ties keep the
-    queue's entries first, with no loop, gather or scatter."""
+    iteration's sorted run, from ``expand_fn`` (:func:`search_hnsw`), folds
+    into both queues by ``merge_sorted``, one stable sort of ``[queue,
+    run]`` that carries the ids, so ties keep the queue's entries first,
+    with no loop, gather or scatter. ``score_fn`` scores the build's
+    expansions (``id_ties``) and is not called on the search path."""
     beam = max(1, min(beam, ef))
     if max_iters is None:
         max_iters = 4 * ef + 16
@@ -899,20 +888,6 @@ def _beam_search(adj, queries, q_cnt, init_s, init_i, ef: int, beam: int,
     m2 = adj.shape[1]
     n_exp = beam * m2                                   # neighbours per launch
     kk = min(n_exp, ef)                                 # sorted-run width
-    if expand_fn is None:
-        def expand_fn(qs, qc, pop_ids, flat, worst, kk):
-            # rows layout: scattered row gather + score, then evict-filter
-            # and one sort (the run feeds BOTH queues — pq_insert_batch
-            # would sort twice). The sort stays although merge_sorted sorts
-            # again: it cuts the run from beam*2M to kk <= ef once, so each
-            # of the two merges sorts ef + kk entries, and it returns the
-            # sorted kk-run the blocked layout's expand stage returns
-            s = score_fn(qs, qc, flat)
-            keep = s > worst[:, None]                    # evict-worst filter
-            s = jnp.where(keep, s, NEG_INF)
-            fl = jnp.where(keep, flat, -1)
-            s_srt, pos = jax.lax.top_k(s, kk)
-            return s_srt, jnp.take_along_axis(fl, pos, axis=1)
 
     vwords = (n + 31) // 32
     e0 = init_i.shape[1]
@@ -1407,14 +1382,14 @@ def place_graph(g: HNSWDeviceGraph, device) -> HNSWDeviceGraph:
         for f, v in g._asdict().items()})
 
 
-def to_device_graph_sharded(indexes: list, layout: str = "rows",
+def to_device_graph_sharded(indexes: list,
                             capacities: list | None = None,
                             devices: list | None = None) -> list:
     """Per-shard device graphs for the fan-out traversal.
 
     Each shard's graph is an ordinary :func:`to_device_graph` (padded to its
-    own power-of-two capacity unless ``capacities`` overrides it — per-shard
-    ``nbr_fps`` blocks included on ``layout="blocked"``), committed to its
+    own power-of-two capacity unless ``capacities`` overrides it, with its
+    own nodes' ``nbr_fps`` blocks), committed to its
     own device (``devices``, default
     :func:`repro.core.distributed.shard_devices`) so the S traversals run
     in parallel across the mesh.
@@ -1425,7 +1400,7 @@ def to_device_graph_sharded(indexes: list, layout: str = "rows",
         devices = shard_devices(len(indexes))
     if capacities is None:
         capacities = [next_pow2(ix.n) for ix in indexes]
-    return [place_graph(to_device_graph(ix, capacity=cap, layout=layout), dev)
+    return [place_graph(to_device_graph(ix, capacity=cap), dev)
             for ix, cap, dev in zip(indexes, capacities, devices)]
 
 

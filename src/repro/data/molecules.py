@@ -36,7 +36,7 @@ class SyntheticConfig:
     #   statistically identical (verified), and under popularity-sorted
     #   layouts scheme 2 can even win. We reproduce the scheme-independent
     #   claims (accuracy vs m trend, two-stage rescore recovery) and document
-    #   this as a data-fidelity gap — see EXPERIMENTS.md §Table I.
+    #   this as a data-fidelity gap — see EXPERIMENTS.md §Folding fidelity.
     seed: int = 0
 
 
@@ -50,11 +50,9 @@ def _bit_probs(cfg) -> np.ndarray:
 
 #: libraries up to this size draw every molecule from one random stream in
 #: one pass, row for row the library this generator has always produced:
-#: every test library, and every committed ``experiments/`` row of at most
-#: this size, was made from such a stream and stays reproducible. Larger
-#: ones (a different library from the one-pass stream's, so the 1,000,000-
-#: row point of ``BENCH_tiered.json`` predates them) draw chunks of
-#: ``CHUNK_ROWS // 8`` rows from
+#: every test library was made from such a stream and stays reproducible.
+#: Larger ones (a different library from the one-pass stream's) draw
+#: chunks of ``CHUNK_ROWS // 8`` rows from
 #: independent child streams, split across worker threads (numpy releases
 #: the GIL in the heavy kernels). Each worker reuses one pair of
 #: (chunk, length) float64 buffers for all its chunks: the host holds

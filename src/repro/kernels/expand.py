@@ -32,9 +32,9 @@ This kernel is that layout change plus the fused compute:
 * Neighbour popcounts are recounted from the streamed rows (the blocked
   copy's zero rows count 0, exactly as the stored counts).
 
-Arithmetic is bit-identical to the row path (integer popcounts, the same
-``metric_from_counts`` score), so ``layout="blocked"`` engines match
-``layout="rows"`` exactly.
+Arithmetic is bit-identical to gathering the rows by id and scoring them
+(integer popcounts, the same ``metric_from_counts`` score), which
+``tests/test_expand_kernel.py`` pins.
 Validated with ``interpret=True`` on CPU against ``ref.expand_sorted_ref``,
 and compiled for v5e by ``tests/test_chip_compile.py``.
 """

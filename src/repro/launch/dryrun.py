@@ -12,8 +12,7 @@ Usage:
   PYTHONPATH=src python -m repro.launch.dryrun --arch phi3-medium-14b --shape train_4k
   PYTHONPATH=src python -m repro.launch.dryrun --all [--multi-pod] [--force]
 
-Outputs one JSON per cell under experiments/dryrun/ consumed by
-benchmarks/roofline.py and EXPERIMENTS.md.
+Outputs one JSON per cell under experiments/dryrun/.
 """
 import argparse
 import json

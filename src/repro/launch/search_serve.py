@@ -38,8 +38,8 @@ from .mesh import make_local_mesh
 
 def serve(engine: str = "sharded-brute", n_db: int = 100_000, k: int = 20,
           n_queries: int = 256, batches: int = 4, use_kernel: bool = False,
-          backend: str | None = None, hnsw_layout: str = "rows",
-          hnsw_shards: int | None = None, residency: str = "device",
+          backend: str | None = None, hnsw_shards: int | None = None,
+          residency: str = "device",
           metric: str | None = None, fp_bits: int | None = None,
           log=print):
     """``backend`` selects the engine execution path (shared contract, see
@@ -48,16 +48,14 @@ def serve(engine: str = "sharded-brute", n_db: int = 100_000, k: int = 20,
     asked for, refused otherwise — ``kernels/ops.py::interpret_on``) or
     "jnp" (device path without
     Pallas). Applies to the ``bitbound-folding`` (two-stage scan) and
-    ``hnsw`` (batched graph traversal) engines. ``hnsw_layout`` picks the
-    traversal's fine-grained distance layout ("rows" row-gather /
-    "blocked" neighbour-blocked streaming, bit-exact results);
-    ``hnsw_shards`` fans the HNSW engine out over N per-device database
-    shards with a rank-merged global top-k (EXPERIMENTS.md §Sharded
-    HNSW). ``residency="tiered"`` keeps the full-resolution DB host-side
-    and streams rescore candidates through a double-buffered HBM window
-    (bitbound-folding engine; EXPERIMENTS.md §Tiered residency).
+    ``hnsw`` (batched graph traversal) engines. ``hnsw_shards`` fans the
+    HNSW engine out over N per-device database shards with a rank-merged
+    global top-k (EXPERIMENTS.md §Sharded HNSW). ``residency="tiered"``
+    keeps the full-resolution DB host-side and streams rescore candidates
+    through a double-buffered HBM window (bitbound-folding engine;
+    EXPERIMENTS.md §Tiered residency).
     ``metric`` / ``fp_bits`` pick the similarity and fingerprint width the
-    engines are traced at (EXPERIMENTS.md §Metric sweep)."""
+    engines are traced at (EXPERIMENTS.md §Metric & width runbook)."""
     from ..core.fingerprints import resolve_metric
     met = resolve_metric(metric)
     length = int(fp_bits) if fp_bits else 1024
@@ -103,7 +101,7 @@ def serve(engine: str = "sharded-brute", n_db: int = 100_000, k: int = 20,
         eng = HNSWEngine(db[:min(n_db, 20_000)], m=CHEMBL_LIKE.hnsw_m,
                          ef_construction=CHEMBL_LIKE.hnsw_ef_construction,
                          ef_search=CHEMBL_LIKE.hnsw_ef_search,
-                         backend=backend, layout=hnsw_layout,
+                         backend=backend,
                          shards=hnsw_shards, metric=met)
         eng.search(queries[:n_queries], k)  # compile
         t0 = time.time()
@@ -231,8 +229,7 @@ def serve_frontend(engines=("brute", "bitbound-folding"), n_db: int = 20_000,
 def serve_service(engines=("brute", "bitbound-folding"), n_db: int = 20_000,
                   k: int = 10, n_ops: int = 256, write_ratio: float = 0.01,
                   backend: str | None = None, compact_threshold: int = 2048,
-                  flush_every: int = 8, hnsw_layout: str = "rows",
-                  hnsw_shards: int | None = None,
+                  flush_every: int = 8, hnsw_shards: int | None = None,
                   durable_dir: str | None = None, snapshot_every: int = 0,
                   resume: bool = False, residency: str = "device",
                   tier_chunk_rows: int | None = None,
@@ -287,7 +284,7 @@ def serve_service(engines=("brute", "bitbound-folding"), n_db: int = 20_000,
                             cutoff=CHEMBL_LIKE.cutoff,
                             fold_m=CHEMBL_LIKE.folding_m,
                             compact_threshold=compact_threshold,
-                            hnsw_layout=hnsw_layout, hnsw_shards=hnsw_shards,
+                            hnsw_shards=hnsw_shards,
                             durable_dir=durable_dir, residency=residency,
                             tier_chunk_rows=tier_chunk_rows,
                             tier_chunk=tier_chunk,
@@ -346,10 +343,6 @@ def main():
                     choices=["numpy", "jnp", "tpu"],
                     help="engine execution path for bitbound-folding "
                          "(default numpy), hnsw (default jnp) and service")
-    ap.add_argument("--hnsw-layout", default="rows",
-                    choices=["rows", "blocked"],
-                    help="HNSW fine-grained distance layout: per-row gather "
-                         "or neighbour-blocked streaming (bit-exact results)")
     ap.add_argument("--shards", type=int, default=None,
                     help="fan the HNSW engine out over N per-device database "
                          "shards (rank-merged global top-k; 1 = bit-identical "
@@ -432,7 +425,7 @@ def _run(args) -> None:
                       n_db=args.n_db, k=args.k, n_ops=args.ops,
                       write_ratio=args.write_ratio, backend=args.backend,
                       compact_threshold=args.compact_threshold,
-                      hnsw_layout=args.hnsw_layout, hnsw_shards=args.shards,
+                      hnsw_shards=args.shards,
                       durable_dir=args.durable_dir,
                       snapshot_every=args.snapshot_every,
                       resume=args.resume, residency=args.residency,
@@ -443,8 +436,8 @@ def _run(args) -> None:
     else:
         serve(args.engine, n_db=args.n_db, k=args.k,
               n_queries=args.n_queries, use_kernel=args.use_kernel,
-              backend=args.backend, hnsw_layout=args.hnsw_layout,
-              hnsw_shards=args.shards, residency=args.residency,
+              backend=args.backend, hnsw_shards=args.shards,
+              residency=args.residency,
               metric=args.metric, fp_bits=args.fp_bits)
 
 

@@ -26,8 +26,7 @@ Exposition:
   (via :mod:`repro.obs.schema`) validates the shape.
 
 ``NULL_METRICS`` is a no-op registry with the same surface — the
-``metrics=False`` service path (the serve_load overhead A/B) swaps it in so
-call sites never branch.
+``metrics=False`` service path swaps it in so call sites never branch.
 """
 from __future__ import annotations
 

@@ -36,8 +36,8 @@ that host for the TPU engines:
 
 The service is synchronous and deterministic by design (no threads): a
 driver loop decides when to flush, which keeps parity tests and benchmark
-replays exact. ``launch/search_serve.py --engine service`` and
-``benchmarks/serve_load.py`` drive it with mixed insert+query workloads.
+replays exact. ``launch/search_serve.py --engine service`` drives it with
+mixed insert+query workloads.
 The store -> service -> engine request path is documented in
 docs/ARCHITECTURE.md.
 """
@@ -93,7 +93,9 @@ class ServiceConfig:
     hnsw_m: int = 8
     hnsw_ef_construction: int = 40
     hnsw_ef_search: int = 32
-    hnsw_layout: str = "rows"    # "blocked" = neighbour-blocked expand stage
+    hnsw_layout: str = "blocked"  # the one expansion layout; kept only
+    #   because bench/configs/chembl27-hnsw.json names it, and goes when
+    #   that configuration drops it
     hnsw_shards: int | None = None  # fan-out HNSW over N per-device shards
     hnsw_build_round: int = DEFAULT_BUILD_ROUND  # the bulk build's largest
     #   round of rows inserted together; like efConstruction it defines
@@ -107,14 +109,19 @@ class ServiceConfig:
     #   per streamed rescore chunk (None = engine default)
     seed: int = 0
     # --- observability (ISSUE 8; docs/ARCHITECTURE.md §Observability) ---
-    metrics: bool = True         # False = NULL_METRICS no-op registry (the
-    #   serve_load overhead A/B baseline); summary() falls back to the
-    #   bounded recent-window deque for percentiles
+    metrics: bool = True         # False = NULL_METRICS no-op registry;
+    #   summary() falls back to the bounded recent-window deque for
+    #   percentiles
     # --- durability (ISSUE 6; docs/ARCHITECTURE.md §On-disk format) ---
     durable_dir: str | None = None  # snapshots/ + wal/ live here; None = RAM
     wal_fsync_every: int = 1     # 1 = fsync per ack; N = group commit (the
     #   last N-1 acked inserts may be lost on crash — benchmark axis only)
     snapshot_keep: int = 2       # retained snapshot generations (walk-back)
+
+    def __post_init__(self):
+        if self.hnsw_layout != "blocked":
+            raise ValueError(f"hnsw_layout must be 'blocked', got "
+                             f"{self.hnsw_layout!r}")
 
 
 class SearchService:
@@ -272,7 +279,7 @@ class SearchService:
             return dict(m=cfg.hnsw_m,
                         ef_construction=cfg.hnsw_ef_construction,
                         ef_search=cfg.hnsw_ef_search, seed=cfg.seed,
-                        backend=cfg.backend, layout=cfg.hnsw_layout,
+                        backend=cfg.backend,
                         shards=cfg.hnsw_shards, metric=cfg.metric,
                         fp_bits=cfg.fp_bits,
                         build_round=cfg.hnsw_build_round)
@@ -577,7 +584,11 @@ class SearchService:
         end's replica construction/rehydration (``serve/replica.py``): a
         read replica is exactly a service built this way plus a replayed
         WAL tail it does not own."""
-        cfg = ServiceConfig(**{**meta["config"], **overrides})
+        saved = dict(meta["config"])
+        # older snapshots name the retired "rows" expansion layout, which
+        # returned the same answers
+        saved.pop("hnsw_layout", None)
+        cfg = ServiceConfig(**{**saved, **overrides})
         snap_metric = resolve_metric(meta["config"].get("metric", "tanimoto"))
         want_metric = resolve_metric(cfg.metric)
         if want_metric.spec != snap_metric.spec:

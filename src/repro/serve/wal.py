@@ -93,8 +93,7 @@ class WriteAheadLog:
 
     ``fsync_every=1`` fsyncs each append before returning (the default,
     full acked-implies-recovered). ``fsync_every=N`` group-commits: fsync
-    every N appends, trading an N-1 record ack window for throughput —
-    measured by ``benchmarks/serve_load.py --wal``.
+    every N appends, trading an N-1 record ack window for throughput.
     """
 
     def __init__(self, directory: str | os.PathLike, words: int, *,

@@ -119,7 +119,8 @@ def test_bitcount_compiles(one_chip, mosaic):
 
 @pytest.mark.parametrize("q_n", [8, 64])
 def test_gather_compiles(one_chip, mosaic, q_n):
-    """One beam expansion of the rows layout (beam 4 x 2M = 128 ids)."""
+    """The row-gather kernel at one beam expansion's width (beam 4 x 2M =
+    128 ids), as the insert frontier and the entry points call it."""
     e = 4 * 2 * CHEMBL_LIKE.hnsw_m
     _compile(ops.gather_tanimoto,
              _spec(one_chip, (q_n, W), jnp.uint32),
@@ -130,7 +131,7 @@ def test_gather_compiles(one_chip, mosaic, q_n):
 
 @pytest.mark.parametrize("q_n", [8, 64])
 def test_expand_compiles(one_chip, mosaic, q_n):
-    """The blocked layout's fused expansion, at the served HNSW size."""
+    """The fused expansion, at the served HNSW size."""
     m2, beam = 2 * CHEMBL_LIKE.hnsw_m, 4
     _compile(lambda q, nbr, pop, flat, worst: ops.expand_tanimoto_sorted(
                  q, nbr, pop, flat, worst, CHEMBL_LIKE.hnsw_ef_search),
@@ -204,19 +205,17 @@ def test_bitbound_engine_pipeline_fits(one_chip, mosaic):
 
 
 
-@pytest.mark.parametrize("layout", ["rows", "blocked"])
-def test_hnsw_engine_pipeline_fits(one_chip, mosaic, layout):
+def test_hnsw_engine_pipeline_fits(one_chip, mosaic):
     """The served HNSWEngine traversal at Q=64 over a graph of the full
     library's capacity. The kernels read the graph's own line and slab
-    layouts, so no launch relays the database out: the blocked layout's
-    2M-neighbour slabs (8 GiB at this size) fit the chip only because no
-    per-call copy of them is made."""
+    layouts, so no launch relays the database out: the 2M-neighbour slabs
+    (8 GiB at this size) fit the chip only because no per-call copy of
+    them is made."""
     from repro.core import HNSWEngine
     small = np.random.default_rng(0).integers(0, 2**32, (300, W),
                                               dtype=np.uint32)
     eng = HNSWEngine(small, m=CHEMBL_LIKE.hnsw_m, ef_construction=20,
-                     ef_search=CHEMBL_LIKE.hnsw_ef_search, backend="tpu",
-                     layout=layout)
+                     ef_search=CHEMBL_LIKE.hnsw_ef_search, backend="tpu")
     fn, (q, graph) = eng.device_pipeline(small[:64], K)
     n0 = graph.db_popcount.shape[0]
 

@@ -4,17 +4,20 @@ ISSUE 4 satellite: edge cases of the gather/expand stage — expansion widths
 and 2M off the 128-lane grid, duplicate neighbour ids inside one expansion,
 fully masked (all ``-1``) expansion rows, and odd word counts (W padding) —
 each checked bit-for-bit against ``ref.expand_sorted_ref``, plus the jnp
-twin (``core.hnsw.expand_scores_jnp``) against both.
+twin (``core.hnsw.expand_scores_jnp``) against both. Under every metric,
+both equal the row-gather chain bit for bit: each neighbour's row gathered
+by id, scored, filtered at ``worst`` and sorted.
 """
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core.fingerprints import popcount
+from repro.core.fingerprints import metric_from_counts, popcount
 from repro.core.hnsw import expand_scores_jnp
 from repro.data.molecules import SyntheticConfig, synthetic_fingerprints
 from repro.kernels import ops, ref
+from test_metrics import M_IDS, METRICS
 
 
 def _case(n, q_n, m2, beam, w_words=None, seed=0, masked_frac=0.3):
@@ -161,3 +164,43 @@ def test_expand_inside_jitted_loop():
 
     out = jax.jit(f)(jnp.asarray(pop), jnp.asarray(flat))
     assert np.isfinite(float(out)) and float(out) > 0
+
+
+def _row_chain(q, db, flat, worst, kk, metric):
+    """The expansion by rows: gather each neighbour's print by its id,
+    score it, drop ``-1`` ids and scores not above ``worst``, keep the best
+    ``kk`` (``lax.top_k`` order)."""
+    q, db, ids = jnp.asarray(q), jnp.asarray(db), jnp.asarray(flat)
+    safe = jnp.maximum(ids, 0)
+    inter = jnp.sum(jax.lax.population_count(
+        q[:, None, :] & db[safe]).astype(jnp.int32), axis=-1)
+    s = metric_from_counts(metric, inter, popcount(q)[:, None],
+                           popcount(db)[safe])
+    s = jnp.where(ids >= 0, s, -jnp.inf)
+    keep = s > jnp.asarray(worst)[:, None]
+    s = jnp.where(keep, s, -jnp.inf)
+    ids = jnp.where(keep, ids, -1)
+    s_srt, pos = jax.lax.top_k(s, kk)
+    return np.asarray(s_srt), np.asarray(jnp.take_along_axis(ids, pos, 1))
+
+
+@pytest.mark.parametrize("metric", METRICS, ids=M_IDS)
+def test_expand_equals_the_row_gather_chain(metric):
+    """The expand kernel (interpret mode) and its jnp twin return the
+    row-gather chain's run bit for bit, eviction filter included."""
+    db, nbr, cnt, pop, flat, worst = _case(400, 4, 16, 4, seed=11)
+    worst[:] = [-np.inf, 0.15, 0.3, 0.45]
+    kk = 32
+    q = jnp.asarray(db[:4])
+    want_s, want_i = _row_chain(q, db, flat, worst, kk, metric)
+    args = (jnp.asarray(pop), jnp.asarray(flat), jnp.asarray(worst), kk)
+    ks, ki = ops.expand_tanimoto_sorted(q, ops.nbr_slabs(jnp.asarray(nbr)),
+                                        *args, metric=metric)
+    ts, ti = expand_scores_jnp(q, popcount(q), jnp.asarray(nbr),
+                               jnp.asarray(cnt), *args, metric=metric)
+    for s, i in ((ks, ki), (ts, ti)):
+        np.testing.assert_array_equal(np.asarray(s).view(np.int32),
+                                      want_s.view(np.int32))
+        np.testing.assert_array_equal(np.asarray(i), want_i)
+    # the filter dropped some neighbours and kept others
+    assert (want_i == -1).any() and (want_i >= 0).any()

@@ -59,7 +59,7 @@ def test_folding_schemes_equivalent_on_uniform_bits(small_db, queries,
                                                      brute_truth):
     """On hash-uniform bits the two OR-folding schemes are statistically
     equivalent (the paper's scheme-1 > scheme-2 gap needs RDKit's real bit
-    layout — documented data-fidelity gap, EXPERIMENTS.md §Table I). Both
+    layout — a documented data-fidelity gap of the synthetic library). Both
     must stay accurate at m=8 thanks to the two-stage k_r1 rescore."""
     from repro.core import BitBoundFoldingEngine, recall_at_k
     _, true_ids = brute_truth

@@ -8,6 +8,9 @@ import pytest
 
 from repro.data.molecules import (SyntheticConfig, queries_from_db,
                                   synthetic_fingerprints)
+from repro.obs.schema import (FRONTEND_METRIC_FAMILIES,
+                              REQUIRED_METRIC_FAMILIES,
+                              validate_metrics_jsonl)
 from repro.serve import (DeadlineExceeded, FrontendConfig, Overloaded,
                          SearchFrontend, SearchService, ServiceConfig,
                          Unavailable)
@@ -115,6 +118,44 @@ def test_overload_sheds_typed_and_bounded(data):
         s = fe.summary()
         assert s["shed"] == shed
         assert s["n_completed"] == len(futs)
+    finally:
+        fe.close()
+
+
+def test_overload_window_sheds_and_completes_nothing_late(data):
+    """Instant submits far past the high-water mark: the front end sheds,
+    and every admitted request either completes inside its deadline or is
+    dropped with ``DeadlineExceeded`` — none completes late."""
+    db, _, q = data
+    deadline_ms = 5000.0
+    fe = SearchFrontend(db, engines=("brute",),
+                        frontend=FrontendConfig(
+                            replicas=1, high_water=16,
+                            default_deadline_ms=deadline_ms,
+                            flush_interval_ms=1.0),
+                        **SVC_KW)
+    try:
+        for n in (1, 2, 4, 8, 16):             # warm every batch bucket
+            fe.search(np.resize(q, (n, q.shape[1])), 4, deadline_ms=None)
+        futs, shed = [], 0
+        for i in range(200):
+            try:
+                futs.append(fe.submit(q[i % len(q)], 4))
+            except Overloaded:
+                shed += 1
+        completed = expired = 0
+        for f in futs:
+            try:
+                f.result(timeout=60)
+                completed += 1
+            except DeadlineExceeded:
+                expired += 1
+        fe.drain(30)
+        assert shed > 0 and completed > 0
+        assert completed + expired == len(futs)
+        assert fe.summary()["shed"] == shed
+        lat = fe.metrics.family("frontend_request_latency_ms")
+        assert lat.quantile(1.0) <= deadline_ms
     finally:
         fe.close()
 
@@ -351,5 +392,10 @@ def test_export_metrics_merges_frontend_and_replicas(data, tmp_path):
         svc_rows = [r for r in rows if r["name"].startswith("service_")]
         assert svc_rows and all("replica" in r["labels"] for r in svc_rows)
         assert (tmp_path / "m.jsonl.prom").exists()
+        # the serving floor and every front-end family, schema-valid
+        assert validate_metrics_jsonl(
+            p, require_families=REQUIRED_METRIC_FAMILIES
+            + FRONTEND_METRIC_FAMILIES) == []
     finally:
         fe.close()
+

@@ -331,8 +331,7 @@ def _searchsorted_merge(s_a, i_a, s_b, i_b):
     return out_s[:a], out_i[:a]
 
 
-@pytest.mark.parametrize("layout", hn.LAYOUTS)
-def test_search_is_bit_identical_to_the_searchsorted_merge(tiny_index, layout,
+def test_search_is_bit_identical_to_the_searchsorted_merge(tiny_index,
                                                            monkeypatch):
     """The jnp-backend traversal returns the same ids, sims and
     TraversalStats, bit for bit, with the merge it has and with the
@@ -341,17 +340,11 @@ def test_search_is_bit_identical_to_the_searchsorted_merge(tiny_index, layout,
     import jax
     import jax.numpy as jnp
     db, idx = tiny_index
-    g = hn.to_device_graph(idx, layout=layout)
+    g = hn.to_device_graph(idx)
     q = jnp.asarray(queries_from_db(db, 16, seed=21))
 
     def run():
-        expand_fn = None
-        if layout == "blocked":
-            def expand_fn(qs, qc, pop, flat, worst, kk):
-                return hn.expand_scores_jnp(qs, qc, g.nbr_fps, g.nbr_cnt, pop,
-                                            flat, worst, kk)
-        out = jax.jit(lambda qs: hn.search_hnsw(
-            g, qs, 10, 64, beam=4, expand_fn=expand_fn))(q)
+        out = jax.jit(lambda qs: hn.search_hnsw(g, qs, 10, 64, beam=4))(q)
         return jax.tree.map(np.asarray, out)
 
     ids, sims, stats = run()

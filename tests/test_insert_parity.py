@@ -88,27 +88,24 @@ def test_bitbound_insert_parity(data, backend, m, cutoff):
     assert eng.scanned(len(q)) == reb.scanned(len(q)), label
 
 
-@pytest.mark.parametrize("backend,layout", [
-    ("numpy", "rows"), ("jnp", "rows"), ("jnp", "blocked"),
-    ("tpu", "rows"), ("tpu", "blocked"),
-])
-def test_hnsw_insert_parity(data, backend, layout):
+@pytest.mark.parametrize("backend", ["numpy", "jnp", "tpu"])
+def test_hnsw_insert_parity(data, backend):
     base, extra, full, q = data
     eng = HNSWEngine(base[:600], m=6, ef_construction=24, ef_search=24,
-                     seed=3, backend=backend, layout=layout)
+                     seed=3, backend=backend)
     eng.search(q, 10)       # build the device graph at n=600 so the insert
     eng.insert(extra[:20])  # refresh below exercises the incremental path
     eng.insert(extra[20:40])
     reb = _hnsw_rebuilt(base[:600], extra[:40], m=6, ef_construction=24,
-                        ef_search=24, seed=3, backend=backend, layout=layout)
-    _assert_equal(eng, reb, q, 10, f"hnsw/{backend}/{layout}")
+                        ef_search=24, seed=3, backend=backend)
+    _assert_equal(eng, reb, q, 10, f"hnsw/{backend}")
     assert eng.n_total == 640
     if backend != "numpy":
         # the incrementally-refreshed device graph (dirty_log scatter) is
         # identical to a from-scratch to_device_graph densify+upload
         g_inc = eng._graph
         g_new = hn.to_device_graph(eng.index, capacity=g_inc.db.shape[0],
-                                   layout=layout, kernel=backend == "tpu")
+                                   kernel=backend == "tpu")
         for field in ("db", "db_popcount", "base_adj", "upper_adj",
                       "nbr_fps", "nbr_cnt", "db_lines", "nbr_slabs"):
             a, b = getattr(g_inc, field), getattr(g_new, field)
@@ -116,22 +113,7 @@ def test_hnsw_insert_parity(data, backend, layout):
                 assert b is None, field
                 continue
             np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
-                                          err_msg=f"{backend}/{layout}/"
-                                                  f"{field}")
-
-
-def test_hnsw_cross_layout_results_identical(data):
-    """ISSUE 4 acceptance: after online inserts, the blocked layout returns
-    bit-identical results to the rows layout (jnp backend)."""
-    base, extra, full, q = data
-    res = {}
-    for layout in ("rows", "blocked"):
-        eng = HNSWEngine(base[:600], m=6, ef_construction=24, ef_search=24,
-                         seed=3, backend="jnp", layout=layout)
-        eng.insert(extra[:40])
-        res[layout] = eng.search(q, 10)
-    np.testing.assert_array_equal(res["rows"][0], res["blocked"][0])
-    np.testing.assert_array_equal(res["rows"][1], res["blocked"][1])
+                                          err_msg=f"{backend}/{field}")
 
 
 def test_hnsw_incremental_graph_identical(data):
@@ -206,7 +188,7 @@ def test_hnsw_dirty_log_bounded(data):
     sustained insert streams, no stale device graphs."""
     base, extra, _, q = data
     eng = HNSWEngine(base[:300], m=4, ef_construction=12, ef_search=16,
-                     seed=3, backend="jnp", layout="blocked")
+                     seed=3, backend="jnp")
     eng.search(q, 5)
     idx = eng.index
     idx.dirty_log = [0] * (2 * idx.n + 1025)   # long-consumed service log
@@ -214,8 +196,7 @@ def test_hnsw_dirty_log_bounded(data):
     assert idx.dirty_epoch == 1
     assert len(idx.dirty_log) <= 2 * idx.n + 1024
     reb = _hnsw_rebuilt(base[:300], extra[:8], m=4, ef_construction=12,
-                        ef_search=16, seed=3, backend="jnp",
-                        layout="blocked")
+                        ef_search=16, seed=3, backend="jnp")
     _assert_equal(eng, reb, q, 5, "hnsw dirty-log epoch rebuild")
     assert eng._dirty_epoch == idx.dirty_epoch
 

@@ -242,6 +242,32 @@ def test_wal_and_snapshot_spans(data, tmp_path):
     assert rec.named("snapshot.extract")[0].args["engines"] == "brute"
 
 
+def test_phase_histograms_count_every_phase(data, tmp_path):
+    """The per-phase latency families a latency breakdown reads count one
+    observation per request (queue wait), flush group (engine batch),
+    insert and WAL append, and each reads back a median."""
+    db, extra, q = data
+    svc = SearchService(db, engines=("brute",), backend="jnp", k=K,
+                        durable_dir=str(tmp_path / "durable"))
+    try:
+        for i in range(3):
+            svc.insert(extra[i:i + 1])
+        for i in range(5):
+            svc.submit(q[i], engine="brute")
+        svc.flush()
+        svc.submit(q[5:7], engine="brute")
+        svc.flush()
+        m = svc.metrics
+        counts = {"service_queue_wait_ms": 6, "service_engine_batch_ms": 2,
+                  "service_insert_ms": 3, "service_wal_append_ms": 3}
+        for name, want in counts.items():
+            fam = m.family(name)
+            assert fam.count() == want, name
+            assert fam.quantile(0.5) is not None and fam.quantile(0.5) >= 0
+    finally:
+        svc.close()
+
+
 ENGINES = ("brute", "bitbound-folding")
 
 

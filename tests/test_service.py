@@ -8,6 +8,8 @@ from repro.core import BruteForceEngine, BitBoundFoldingEngine, HNSWEngine
 from repro.data.molecules import (SyntheticConfig, queries_from_db,
                                   synthetic_fingerprints)
 from repro.serve import SearchService
+from repro.serve import snapshot as snap
+from repro.serve.service import ServiceConfig
 
 
 @pytest.fixture(scope="module")
@@ -53,6 +55,31 @@ def test_router_rejects_unknown_engine(data):
         svc.submit(q[0], engine="hnsw")
     with pytest.raises(ValueError, match="engine"):
         SearchService(db, engines=("fpga",))
+
+
+def test_config_refuses_any_hnsw_layout_but_blocked(data):
+    """The traversal has one expansion layout; any other is refused."""
+    with pytest.raises(ValueError, match="blocked"):
+        ServiceConfig(hnsw_layout="rows")
+    db, _, _ = data
+    with pytest.raises(ValueError, match="blocked"):
+        SearchService(db, engines=("brute",), hnsw_layout="rows")
+
+
+def test_snapshot_naming_the_retired_rows_layout_restores(data):
+    """A snapshot whose config names the retired "rows" layout restores
+    onto the one layout and answers as the service it was taken from."""
+    db, _, q = data
+    svc = SearchService(db, engines=("brute",), backend="jnp", k=5)
+    arrays, meta = snap.service_state(svc)
+    meta["words"] = svc.words              # as SearchService.snapshot adds
+    meta["config"]["hnsw_layout"] = "rows"
+    back = SearchService.from_state(arrays, meta)
+    assert back.config.hnsw_layout == "blocked"
+    want = svc.engines["brute"].search(q, 5)
+    got = back.engines["brute"].search(q, 5)
+    np.testing.assert_array_equal(got[0], want[0])
+    np.testing.assert_array_equal(got[1], want[1])
 
 
 def test_insert_broadcast_and_compaction_counts(data):
@@ -218,3 +245,42 @@ def test_close_during_background_snapshot_from_other_thread(data, tmp_path):
     assert reopened._snap_id == sid      # the raced snapshot did publish
     assert reopened.n_total == len(db) + 10
     reopened.close()
+
+
+@pytest.mark.parametrize("backend", ["jnp", "tpu"])
+def test_steady_state_window_compiles_nothing(data, backend):
+    """A window of mixed inserts and queries through both exhaustive
+    engines compiles no pipeline once a warm-up has replayed the same op
+    mix (other insert rows), crossed a compaction and pinned the delta
+    phase: steady-state serving replays compiled shapes only."""
+    from repro.launch.search_serve import make_workload
+    db, extra, q = data
+    pool = synthetic_fingerprints(SyntheticConfig(n=96, seed=7))
+    svc = SearchService(db, engines=("brute", "bitbound-folding"),
+                        backend=backend, k=10, compact_threshold=8,
+                        cutoff=0.4, fold_m=2)
+    names = list(svc.engines)
+
+    def run(ops):
+        for i, (op, payload) in enumerate(ops):
+            if op == "insert":
+                svc.insert(payload)
+            else:
+                svc.submit(payload, engine=names[i % 2])
+                if i % 8 == 7:
+                    svc.flush()
+        svc.flush()
+
+    ops = make_workload(48, 0.25, pool[:48], q, seed=3)
+    warm = [("insert", extra[i:i + 1]) if op == "insert" else (op, x)
+            for i, (op, x) in enumerate(ops)]
+    run(warm)
+    assert svc.compactions >= 1
+    svc.compact_all()
+    caps = {n: e.store.main.capacity for n, e in svc.engines.items()}
+    before, inserted = svc.compiled_pipelines(), svc.n_inserts
+    run(ops)
+    assert {n: e.store.main.capacity
+            for n, e in svc.engines.items()} == caps
+    assert svc.n_inserts > inserted
+    assert svc.compiled_pipelines() == before

@@ -3,11 +3,11 @@
 Contracts pinned here:
 
 * ``HNSWEngine(shards=1)`` is **bit-identical** to the unsharded engine
-  (same build seed, identity merge) on every device backend and layout.
+  (same build seed, identity merge).
 * Multi-shard recall at the fig8 operating point is within 0.01 of the
   unsharded engine (partition-then-merge covers the global top-k as long as
   each shard covers its local share).
-* Backends and layouts stay bit-exact with each other *through the
+* The device backends stay bit-exact with each other *through the
   fan-out* (same per-shard graph walks, same merge).
 * Online inserts route round-robin and stay rebuild-identical.
 * On a forced multi-device host platform the per-shard graphs land on
@@ -49,15 +49,14 @@ def unsharded(corpus):
 
 
 def test_one_shard_bit_parity(corpus, unsharded):
-    """shards=1 == unsharded, bit for bit (ids and sims), on both layouts."""
+    """shards=1 == unsharded, bit for bit (ids and sims)."""
     db, q, _ = corpus
     ids0, sims0 = unsharded
-    for layout in ("rows", "blocked"):
-        eng = HNSWEngine(db, m=8, ef_construction=40, ef_search=32,
-                         backend="jnp", layout=layout, shards=1)
-        ids, sims = eng.search(q, K)
-        np.testing.assert_array_equal(ids, ids0, err_msg=layout)
-        np.testing.assert_array_equal(sims, sims0, err_msg=layout)
+    eng = HNSWEngine(db, m=8, ef_construction=40, ef_search=32,
+                     backend="jnp", shards=1)
+    ids, sims = eng.search(q, K)
+    np.testing.assert_array_equal(ids, ids0)
+    np.testing.assert_array_equal(sims, sims0)
 
 
 def test_multi_shard_recall_pin(corpus, unsharded):
@@ -78,22 +77,19 @@ def test_multi_shard_recall_pin(corpus, unsharded):
 
 
 def test_sharded_backend_layout_parity(corpus):
-    """Every device backend x layout is bit-exact through the fan-out."""
+    """Every device backend is bit-exact through the fan-out."""
     db, q, _ = corpus
     db, q = db[:1200], q[:4]
     base = None
-    for backend, layout in [("jnp", "rows"), ("jnp", "blocked"),
-                            ("tpu", "rows"), ("tpu", "blocked")]:
+    for backend in ("jnp", "tpu"):
         eng = HNSWEngine(db, m=8, ef_construction=40, ef_search=32,
-                         backend=backend, layout=layout, shards=2)
+                         backend=backend, shards=2)
         ids, sims = eng.search(q, K)
         if base is None:
             base = (ids, sims)
         else:
-            np.testing.assert_array_equal(ids, base[0],
-                                          err_msg=f"{backend}/{layout}")
-            np.testing.assert_array_equal(sims, base[1],
-                                          err_msg=f"{backend}/{layout}")
+            np.testing.assert_array_equal(ids, base[0], err_msg=backend)
+            np.testing.assert_array_equal(sims, base[1], err_msg=backend)
 
 
 def test_sharded_numpy_backend(corpus, unsharded):
@@ -166,7 +162,7 @@ def test_sharded_search_hnsw_module_api(corpus, unsharded):
 
 def test_forced_multi_device_placement():
     """On an 8-device host platform the shard graphs land on distinct
-    devices; parity and the recall pin hold (the EXPERIMENTS.md recipe)."""
+    devices; parity and the recall pin hold."""
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     env["PYTHONPATH"] = os.path.join(ROOT, "src")

@@ -1,6 +1,6 @@
 """Snapshot serialization round-trips (ISSUE 6 tentpole, snapshot half).
 
-The core contract: for any engine × backend × layout × shards and any
+The core contract: for any engine × backend × shards and any
 interleaving of inserts/compactions, serializing the search state and
 restoring it yields **byte-equal** extracted state — and *continuing* to
 insert into the restored replica tracks the live engine exactly (the HNSW
@@ -29,38 +29,37 @@ BASE = POOL[:140]
 EXTRA = POOL[140:]
 QUERIES = queries_from_db(POOL, 6, seed=4)
 
-# engine-kind × backend × layout × shards grid the property sweep samples
-# from ("tpu" rides the interpret-mode Pallas path — covered by the
+# engine-kind × backend × shards grid the property sweep samples from
+# ("tpu" rides the interpret-mode Pallas path — covered by the
 # service-level tests below to keep the sweep's compile count at zero)
 CASES = [
-    ("brute", "jnp", None, None),
-    ("bitbound", "numpy", None, None),
-    ("bitbound", "jnp", None, None),
-    ("hnsw", "numpy", "rows", None),
-    ("hnsw", "jnp", "rows", None),
-    ("hnsw", "jnp", "blocked", None),
-    ("hnsw", "numpy", "rows", 2),
-    ("hnsw", "jnp", "blocked", 2),
+    ("brute", "jnp", None),
+    ("bitbound", "numpy", None),
+    ("bitbound", "jnp", None),
+    ("hnsw", "numpy", None),
+    ("hnsw", "jnp", None),
+    ("hnsw", "numpy", 2),
+    ("hnsw", "jnp", 2),
 ]
 
 
-def _mk_engine(kind, backend, layout, shards, db):
+def _mk_engine(kind, backend, shards, db):
     if kind == "brute":
         return BruteForceEngine(db, backend=backend, compact_threshold=24)
     if kind == "bitbound":
         return BitBoundFoldingEngine(db, cutoff=0.3, m=2, backend=backend,
                                      compact_threshold=24)
     return HNSWEngine(db, m=4, ef_construction=12, ef_search=16, seed=3,
-                      backend=backend, layout=layout, shards=shards)
+                      backend=backend, shards=shards)
 
 
-def _restore_kwargs(kind, backend, layout):
+def _restore_kwargs(kind, backend):
     if kind == "brute":
         return dict(backend=backend, compact_threshold=24)
     if kind == "bitbound":
         return dict(cutoff=0.3, m=2, backend=backend, compact_threshold=24)
     return dict(m=4, ef_construction=12, ef_search=16, seed=3,
-                backend=backend, layout=layout)
+                backend=backend)
 
 
 def _assert_state_equal(e_live, e_restored, label=""):
@@ -92,8 +91,8 @@ def test_snapshot_roundtrip_interleavings(case, ops, off):
     """Random insert/compact/snapshot interleavings: restored state is
     byte-equal to live state, and inserting *after* the restore tracks the
     live engine exactly (rng-stream + counter continuation)."""
-    kind, backend, layout, shards = case
-    eng = _mk_engine(kind, backend, layout, shards, BASE)
+    kind, backend, shards = case
+    eng = _mk_engine(kind, backend, shards, BASE)
     pos = off % (len(EXTRA) - 64)
     for op, size in ops:
         if op == "insert":
@@ -103,9 +102,8 @@ def test_snapshot_roundtrip_interleavings(case, ops, off):
             eng.store.compact()
     arrays, meta = _roundtrip_via_disk(eng)
     restored = snap.engine_from_state(arrays, meta,
-                                      **_restore_kwargs(kind, backend,
-                                                        layout))
-    label = f"{kind}/{backend}/{layout}/shards={shards}"
+                                      **_restore_kwargs(kind, backend))
+    label = f"{kind}/{backend}/shards={shards}"
     _assert_state_equal(eng, restored, label)
     # continuation: both sides take the same two extra batches
     for a, b in ((pos, pos + 5), (pos + 5, pos + 12)):
