@@ -14,7 +14,7 @@ Two scaling recipes share the merge primitives in ``core/topk.py``:
 * **HNSW fan-out** (:func:`merge_shard_topk` + :func:`shard_devices`): the
   sharded graph engine (``core/hnsw.py`` / ``HNSWEngine(shards=N)``) runs
   one independent lock-step traversal per database shard — each with its
-  own entry point, visited bitset and PQ queues, placed on its own device —
+  own entry point and PQ queues, placed on its own device —
   and this module's rank-merge tree (``core/topk.merge_sorted_many``)
   combines the per-shard result runs into one global top-k.
 

@@ -1258,8 +1258,8 @@ class HNSWEngine(SearchEngine):
     ``shards`` (ISSUE 5) splits the database round-robin into N independent
     per-shard graphs, one per mesh device (FPScreen-style partition-then-
     merge — the paper's replicated traversal pipelines): a search fans out
-    one lock-step traversal per shard — per-shard entry points, visited
-    bitsets and PQ queues, each on its own device so launches overlap under
+    one lock-step traversal per shard — per-shard entry points and PQ
+    queues, each on its own device so launches overlap under
     async dispatch — and rank-merges the per-shard result runs into one
     global top-k (``core/distributed.merge_shard_topk`` over
     ``core/topk.merge_sorted_many``). ``shards=1`` is bit-identical to the
@@ -1293,8 +1293,9 @@ class HNSWEngine(SearchEngine):
 
     After each ``search``, :attr:`stats` holds the batch's traversal
     telemetry: ``iters`` / ``expansions`` / ``neighbour_evals`` totals and,
-    on device backends, per-query arrays plus termination-reason counts
-    (``converged`` vs ``max_iters_hit``).
+    on device backends, per-query arrays, the neighbour slots the dedup
+    masked (``deduped``) and termination-reason counts (``converged`` vs
+    ``max_iters_hit``).
     """
     db: np.ndarray
     m: int = 16
@@ -1621,11 +1622,12 @@ class HNSWEngine(SearchEngine):
         iters = np.stack([np.asarray(st.iters) for st in shard_stats])
         expans = np.stack([np.asarray(st.expansions) for st in shard_stats])
         reason = np.stack([np.asarray(st.reason) for st in shard_stats])
+        dedup = np.stack([np.asarray(st.deduped) for st in shard_stats])
         self._record_batch(int(expans.sum()) * m2, q_n)
         self.stats = {
             "backend": self.backend,
             "shards": n_shards,
-            **hn.stats_summary(iters, expans, reason, m2),
+            **hn.stats_summary(iters, expans, reason, dedup, m2),
             "iters_per_query": iters.sum(axis=0),
             "expansions_per_query": expans.sum(axis=0),
             "per_shard": [{"iters": int(i.sum()), "expansions": int(e.sum())}
@@ -1656,7 +1658,8 @@ class HNSWEngine(SearchEngine):
             if self.shards is not None:
                 ids, sims = self._search_sharded(queries, k, ef, beam)
                 sp.set(**{kk: self.stats[kk]
-                          for kk in ("iters", "expansions", "neighbour_evals")
+                          for kk in ("iters", "expansions", "neighbour_evals",
+                                     "deduped")
                           if kk in self.stats})
                 return ids, sims
             m2 = self.index.base_adj.shape[1]
@@ -1678,13 +1681,15 @@ class HNSWEngine(SearchEngine):
             self._record_batch(int(expans.sum()) * m2, iters.shape[0])
             self.stats = {
                 "backend": self.backend,
-                **hn.stats_summary(iters, expans, tstats.reason, m2),
+                **hn.stats_summary(iters, expans, tstats.reason,
+                                   tstats.deduped, m2),
                 "iters_per_query": iters,
                 "expansions_per_query": expans,
             }
             sp.set(iters=self.stats["iters"],
                    expansions=self.stats["expansions"],
                    neighbour_evals=self.stats["neighbour_evals"],
+                   deduped=self.stats["deduped"],
                    converged=self.stats["converged"],
                    max_iters_hit=self.stats["max_iters_hit"])
             return ids, sims

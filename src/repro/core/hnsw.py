@@ -764,14 +764,16 @@ class TraversalStats(NamedTuple):
     iters: jax.Array        # (Q,) int32 — beam-expansion iterations executed
     expansions: jax.Array   # (Q,) int32 — candidates actually expanded
     reason: jax.Array       # (Q,) int32 — REASON_CONVERGED / REASON_MAX_ITERS
+    deduped: jax.Array      # (Q,) int32 — neighbour slots masked as seen
 
 
-def stats_summary(iters, expansions, reason, m2: int) -> dict:
+def stats_summary(iters, expansions, reason, deduped, m2: int) -> dict:
     """Fold (already stacked/summed-ready) per-query traversal telemetry
     into the scalar totals dict shared by :attr:`HNSWEngine.stats` and the
     ``hnsw.search`` trace-span args (ISSUE 8): iteration/expansion totals,
-    neighbour evaluations (``expansions * 2M``) and termination-reason
-    counts. Accepts device arrays or numpy; always returns plain ints."""
+    neighbour evaluations (``expansions * 2M``), the slots the dedup masked
+    and termination-reason counts. Accepts device arrays or numpy; always
+    returns plain ints."""
     iters = np.asarray(iters)
     expansions = np.asarray(expansions)
     reason = np.asarray(reason)
@@ -779,6 +781,7 @@ def stats_summary(iters, expansions, reason, m2: int) -> dict:
         "iters": int(iters.sum()),
         "expansions": int(expansions.sum()),
         "neighbour_evals": int(expansions.sum()) * int(m2),
+        "deduped": int(np.asarray(deduped).sum()),
         "converged": int((reason == REASON_CONVERGED).sum()),
         "max_iters_hit": int((reason == REASON_MAX_ITERS).sum()),
     }
@@ -795,8 +798,9 @@ def search_hnsw(g: HNSWDeviceGraph, queries: jax.Array, k: int, ef: int,
     adjacency (``beam * 2M`` neighbour ids), scores all of them in ONE
     expansion launch (``expand_fn``), and merges the scored batch
     into both fixed-shape register-array queues (C and the result set M,
-    ``core/topk.py``). A per-query visited bitset gives exactly-once scoring;
-    queries terminate individually (Alg. 2 bound: best candidate worse than
+    ``core/topk.py``). A neighbour already in the result queue or repeated in
+    the expansion is masked, so no node enters a queue twice; queries
+    terminate individually (Alg. 2 bound: best candidate worse than
     the worst retained result) and finished queries ride along masked until
     the last one converges or ``max_iters`` is hit.
 
@@ -812,7 +816,8 @@ def search_hnsw(g: HNSWDeviceGraph, queries: jax.Array, k: int, ef: int,
     (scores (Q, kk) desc, ids (Q, kk))`` is the whole gather -> score ->
     evict-filter -> sort stage of one beam expansion (``pop_ids (Q, beam)``
     are the popped node ids, ``flat_ids (Q, beam*2M)`` their adjacency with
-    -1 for pad/visited slots, ``worst (Q,)`` the result queues' eviction
+    -1 for pad slots and those already in the result queue or repeated in
+    the expansion, ``worst (Q,)`` the result queues' eviction
     bounds) over the graph's neighbour blocks; default is the jnp twin
     (:func:`expand_scores_jnp`) over ``g.nbr_fps``/``g.nbr_cnt``, engines
     pass the fused Pallas kernel (``kernels.ops.expand_tanimoto_sorted``)
@@ -839,14 +844,15 @@ def search_hnsw(g: HNSWDeviceGraph, queries: jax.Array, k: int, ef: int,
 
     ep = jax.vmap(descend)(queries, q_cnt)               # (Q,)
     ep_sim = score_fn(queries, q_cnt, ep[:, None])[:, 0]
-    res, active, iters, expans = _beam_search(
+    res, active, iters, expans, dedup = _beam_search(
         g.base_adj, queries, q_cnt, ep_sim[:, None], ep[:, None], ef, beam,
         max_iters, score_fn, expand_fn)
     reason = jnp.where(active, REASON_MAX_ITERS, REASON_CONVERGED)
     ids = res.payload[:, :k]
     sims = jnp.where(ids >= 0, res.scores[:, :k], 0.0)
     return ids, sims, TraversalStats(iters=iters, expansions=expans,
-                                     reason=reason.astype(jnp.int32))
+                                     reason=reason.astype(jnp.int32),
+                                     deduped=dedup)
 
 
 def _keyed(s, i):
@@ -868,7 +874,18 @@ def _beam_search(adj, queries, q_cnt, init_s, init_i, ef: int, beam: int,
     query's entry list ``init_s``/``init_i (Q, E0)`` (descending, ``-1`` /
     ``NEG_INF`` padded; an empty list leaves the query idle). Returns the
     result queue (the best ``ef`` found, descending), the per-query
-    still-active flags, iterations and expansions.
+    still-active flags, iterations, expansions and deduped slots.
+
+    No visited set: a neighbour slot is masked (``-1``) when its id is
+    already in the result queue or repeated at an earlier slot of the same
+    expansion (the first wins). Every other node scored before sits at or
+    below today's worst result, which never falls: it was filtered at the
+    worst of its time, cut from a run whose kept entries were at least as
+    good, or evicted, and a tie never evicts a queue entry (the merges put
+    the queue's entries first; ``_keyed`` keys are unique). So the strict
+    filter (``s > worst``; ``_above`` under ``id_ties``) drops it again,
+    and the queues, the stats and the answers are those of an exactly-once
+    visited check, with no state of size N.
 
     ``id_ties`` orders both queues by (similarity, id) as the host search
     does (:func:`_search_layer_np`): a node's key is its own, so no tie
@@ -884,12 +901,10 @@ def _beam_search(adj, queries, q_cnt, init_s, init_i, ef: int, beam: int,
     if max_iters is None:
         max_iters = 4 * ef + 16
     q_n = queries.shape[0]
-    n = adj.shape[0]
     m2 = adj.shape[1]
     n_exp = beam * m2                                   # neighbours per launch
     kk = min(n_exp, ef)                                 # sorted-run width
 
-    vwords = (n + 31) // 32
     e0 = init_i.shape[1]
 
     # C (candidates, pop-best) and M (results, evict-worst): batched
@@ -904,30 +919,26 @@ def _beam_search(adj, queries, q_cnt, init_s, init_i, ef: int, beam: int,
     res = cand
     if id_ties:
         cand = res = PQ(*_keyed(cand.scores, cand.payload))
-    rows = jnp.arange(q_n)
-    visited = jnp.zeros((q_n, vwords), jnp.uint32)
-    safe0 = jnp.maximum(init_i, 0)
-    visited = visited.at[rows[:, None], safe0 // 32].add(jnp.where(
-        init_i >= 0, jnp.uint32(1) << (safe0 % 32).astype(jnp.uint32),
-        jnp.uint32(0)))
+    earlier = jnp.tri(n_exp, k=-1, dtype=bool)          # slot j < slot i
 
     def where_rows(mask, new, old):
         """Per-query select between two batched PQ pytrees."""
         return jax.tree.map(
             lambda a, b: jnp.where(mask[:, None], a, b), new, old)
 
-    state = (cand, res, visited,
+    state = (cand, res,
              jnp.ones((q_n,), bool),                     # active
              jnp.zeros((q_n,), jnp.int32),               # iters
              jnp.zeros((q_n,), jnp.int32),               # expansions
+             jnp.zeros((q_n,), jnp.int32),               # deduped slots
              jnp.int32(0))                               # lock-step counter
 
     def cond(st):
-        active, it = st[3], st[6]
+        active, it = st[2], st[6]
         return jnp.logical_and(jnp.any(active), it < max_iters)
 
     def body(st):
-        cand, res, visited, active, iters, expans, it = st
+        cand, res, active, iters, expans, dedup, it = st
         worst = jax.vmap(pq_worst)(res)                  # eviction threshold
         worst_i = res.payload[:, -1]
 
@@ -948,28 +959,16 @@ def _beam_search(adj, queries, q_cnt, init_s, init_i, ef: int, beam: int,
         valid_pop = (pop_s > NEG_INF) & kept(pop_s, pop_i) & go[:, None]
         cand = where_rows(go, popped, cand)
 
-        # beam expansion: adjacency gather, (Q, beam, 2M)
+        # beam expansion: adjacency gather, (Q, beam*2M) in slot order
         nb = adj[jnp.maximum(pop_i, 0)]
-        nb = jnp.where(valid_pop[:, :, None], nb, -1)
+        nb = jnp.where(valid_pop[:, :, None], nb, -1).reshape(q_n, n_exp)
 
-        # visited check + mark, one beam slot at a time (static unroll): ids
-        # within a slot are unique (one node's adjacency), so the scatter-ADD
-        # below equals scatter-OR; marking between slots dedups neighbours
-        # shared by two popped candidates in the same iteration.
-        fresh_slots = []
-        for b in range(beam):
-            ids_b = nb[:, b, :]
-            safe = jnp.maximum(ids_b, 0)
-            word = jnp.take_along_axis(visited, safe // 32, axis=1)
-            bit = (word >> (safe % 32).astype(jnp.uint32)) & 1
-            fresh = jnp.logical_and(ids_b >= 0, bit == 0)
-            upd = jnp.where(
-                fresh, jnp.uint32(1) << (safe % 32).astype(jnp.uint32),
-                jnp.uint32(0))
-            visited = visited.at[rows[:, None], safe // 32].add(upd)
-            fresh_slots.append(fresh)
-        fresh = jnp.stack(fresh_slots, axis=1).reshape(q_n, n_exp)
-        flat = jnp.where(fresh, nb.reshape(q_n, n_exp), -1)
+        # dedup: mask a slot whose id is already in the result queue or
+        # repeated at an earlier slot of this expansion (the first wins)
+        in_res = jnp.any(nb[:, :, None] == res.payload[:, None, :], axis=2)
+        repeat = jnp.any((nb[:, :, None] == nb[:, None, :]) & earlier, axis=2)
+        dup = (nb >= 0) & (in_res | repeat)
+        flat = jnp.where(dup, -1, nb)
 
         # fused expansion stage: gather + score + evict-filter + sort for
         # all B*2M neighbours per query in one launch; the sorted run
@@ -996,10 +995,12 @@ def _beam_search(adj, queries, q_cnt, init_s, init_i, ef: int, beam: int,
 
         iters = iters + go.astype(jnp.int32)
         expans = expans + jnp.sum(valid_pop, axis=1).astype(jnp.int32)
-        return cand, res, visited, go, iters, expans, it + 1
+        dedup = dedup + jnp.sum(dup, axis=1).astype(jnp.int32)
+        return cand, res, go, iters, expans, dedup, it + 1
 
-    _, res, _, active, iters, expans, _ = jax.lax.while_loop(cond, body, state)
-    return res, active, iters, expans
+    _, res, active, iters, expans, dedup, _ = jax.lax.while_loop(
+        cond, body, state)
+    return res, active, iters, expans, dedup
 
 
 # ---------------------------------------------------------------------------
@@ -1086,7 +1087,7 @@ def _build_search(adj, lev, db, cnt, rows, e_s, e_i, pos, ef: int,
 
     def score_fn(qs, qcnt, ids):
         return _score_rows(db, cnt, qs, qcnt, ids, metric)
-    res, _, _, _ = _beam_search(adj[lev], db[safe], cnt[safe], init_s,
+    res, _, _, _, _ = _beam_search(adj[lev], db[safe], cnt[safe], init_s,
                                 init_i, ef, beam, None, score_fn,
                                 id_ties=True)
     dest = jnp.where(pos >= 0, pos, rows.shape[0])
@@ -1290,8 +1291,7 @@ def _build_round(bg: _BuildGraph, levels: np.ndarray, a: int, b: int,
 # are **round-robin partitioned** into S shards (global row g lives in shard
 # ``g % S`` at local row ``g // S``), each shard builds its own independent
 # HNSW graph, queries fan out to one lock-step traversal per shard (each
-# with its own entry point, visited bitset and PQ queues, placed on its own
-# device), and the per-shard result runs rank-merge into one global top-k
+# with its own entry point and PQ queues, placed on its own device), and the per-shard result runs rank-merge into one global top-k
 # (``core/topk.merge_sorted_many`` via ``core/distributed.merge_shard_topk``).
 # Round-robin keeps shards balanced under online inserts and makes the
 # local<->global id map a closed form — no translation table on device.

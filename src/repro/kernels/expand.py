@@ -23,7 +23,8 @@ This kernel is that layout change plus the fused compute:
   row fetches), same total bytes.
 * The body popcounts all ``beam*2M`` neighbours per query in-register,
   re-lays each line's word-group sums into one lane-dense score row, masks
-  invalid / visited slots (id ``-1`` in the flattened candidate ids) and
+  pad slots and those already in the result queue or repeated in the
+  expansion (id ``-1`` in the flattened candidate ids) and
   sub-threshold scores (``<= worst[q]``, the result queue's eviction
   bound), and **sorts in-kernel**: ``kk`` rounds of row max plus the lowest
   lane at that max (:func:`tanimoto_topk.select_topk`, ``lax.top_k``'s
@@ -100,7 +101,7 @@ def _expand_body(pop_sm, q_ref, qcnt_ref, ids_ref, worst_ref, nbr_hbm,
         s = metric_from_counts(metric, inter, qcnt_ref[j:j + 1, :], cnt)
         s_all = jnp.where(sub == j, s, s_all)
     ids = ids_ref[...]
-    s_all = jnp.where(ids >= 0, s_all, NEG)            # -1 = pad/visited/dup
+    s_all = jnp.where(ids >= 0, s_all, NEG)            # -1 = pad/in queue/dup
     s_all = jnp.where(s_all > worst_ref[...], s_all, NEG)  # evict-worst
     ids = jnp.where(s_all > NEG, ids, -1)
     s_top, i_top = select_topk(s_all, ids, kk, s_out.shape[1])
@@ -118,7 +119,8 @@ def expand_sorted_scores(q_lines: jax.Array, q_cnt: jax.Array,
     node's neighbour block in line layout, ``S`` a multiple of 8), pop_ids
     (Q, beam) i32 (popped node ids, -1 = empty pop), slot_ids (Q, E_pad) i32
     (the adjacency of the popped beam in slot order ``b * S * 128 / wp +
-    r``, -1 for pad / visited / duplicate slots), worst (Q, 1) f32
+    r``, -1 for pad slots and those already in the result queue or
+    repeated in the expansion), worst (Q, 1) f32
     (per-query eviction threshold; scores must be strictly greater to
     survive). Q must be a multiple of ``qb`` (``qb`` a multiple of 8, or
     ``qb == Q``).

@@ -20,8 +20,8 @@ FPGA -> TPU mapping:
   the id's own ``wp``-word group, giving a lane-dense ``(1, E)`` row of
   intersection counts per query; the row's BitCnt is recomputed the same
   way (cheaper than a second gather of the precomputed count).
-* Validity masking: id ``-1`` marks padded / already-visited / masked-out
-  neighbours. Their fetch is clamped to line 0 (it must stay addressable)
+* Validity masking: id ``-1`` marks padded neighbours and those already in
+  the result queue or repeated in the expansion. Their fetch is clamped to line 0 (it must stay addressable)
   and their score is overwritten with ``-inf`` so the PQ merge downstream
   never admits them.
 

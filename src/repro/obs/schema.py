@@ -38,6 +38,7 @@ HNSW_METRIC_FAMILIES = (
     "service_hnsw_neighbour_evals_total",
     "service_hnsw_iters_total",
     "service_hnsw_max_iters_hit_total",
+    "service_hnsw_deduped_slots_total",
 )
 
 #: families the concurrent front end (ISSUE 9) must additionally export —

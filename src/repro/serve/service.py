@@ -248,6 +248,10 @@ class SearchService:
             "service_hnsw_iters_total",
             "HNSW traversal iterations, summed over queries",
             labels=("engine",))
+        self._m_hnsw_deduped = m.counter(
+            "service_hnsw_deduped_slots_total",
+            "HNSW neighbour slots masked as already in the result queue or "
+            "repeated in the expansion", labels=("engine",))
         self._m_hnsw_max_iters = m.counter(
             "service_hnsw_max_iters_hit_total",
             "queries whose HNSW traversal stopped at its iteration budget",
@@ -707,6 +711,7 @@ class SearchService:
         if isinstance(eng, HNSWEngine):
             self._m_hnsw_evals.inc(st.get("neighbour_evals", 0), engine=ename)
             self._m_hnsw_iters.inc(st.get("iters", 0), engine=ename)
+            self._m_hnsw_deduped.inc(st.get("deduped", 0), engine=ename)
             self._m_hnsw_max_iters.inc(st.get("max_iters_hit", 0),
                                        engine=ename)
         if st.get("residency") == "tiered":

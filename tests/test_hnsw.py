@@ -355,3 +355,86 @@ def test_search_is_bit_identical_to_the_searchsorted_merge(tiny_index,
     for got, want in zip(stats, stats_o):
         np.testing.assert_array_equal(got, want)
     assert (ids >= 0).all() and stats.iters.sum() > 0
+
+
+# -- the traversal's dedup against the result queue ----------------------------
+
+def _digest(*arrays):
+    import hashlib
+    h = hashlib.sha256()
+    for a in arrays:
+        h.update(np.ascontiguousarray(a).tobytes())
+    return h.hexdigest()
+
+
+def _search_digest(tiny_index):
+    import jax
+    import jax.numpy as jnp
+    db, idx = tiny_index
+    g = hn.to_device_graph(idx)
+    q = jnp.asarray(queries_from_db(db, 16, seed=21))
+    out = jax.jit(lambda qs: hn.search_hnsw(g, qs, 10, 64, beam=4))(q)
+    ids, sims, st_ = jax.tree.map(np.asarray, out)
+    return _digest(ids.astype("<i4"), sims.astype("<f4").view("<i4"),
+                   st_.iters.astype("<i4"), st_.expansions.astype("<i4"),
+                   st_.reason.astype("<i4"))
+
+
+def _build_digest(tiny_index):
+    db = synthetic_fingerprints(SyntheticConfig(n=3000, seed=0))
+    idx = hn.build_hnsw(db, m=16, ef_construction=100, seed=0,
+                        build_round=512)
+    return _digest(idx.base_adj.astype("<i4"))
+
+
+# taken on the traversal that marked a per-query visited bitset slot by
+# slot: the dedup against the result queue must give the same searches
+# (ids, sims as bits, iterations, expansions, stop reasons) and the same
+# bulk build (the id-tied path), bit for bit
+@pytest.mark.parametrize("what,digest", [
+    (_search_digest,
+     "430bc472afa1676574b2c8044733b6bb0be7b4275a4097b2b35ab8a50bee61c7"),
+    (_build_digest,
+     "ffb9e2882fccd6da3ae71f0a7909996bab9d5ca5ff9cc247c3fa4720f5af8cce"),
+], ids=["search", "build"])
+def test_traversal_is_bit_identical_to_the_visited_bitset(tiny_index, what,
+                                                          digest):
+    assert what(tiny_index) == digest
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_no_id_returned_twice(seed):
+    """The whole result queue (k = ef) holds each node once, at every
+    beam, with neighbours shared between the popped candidates."""
+    import jax
+    import jax.numpy as jnp
+    db = synthetic_fingerprints(SyntheticConfig(n=600, seed=seed))
+    idx = hn.build_hnsw(db, m=6, ef_construction=24, seed=seed)
+    g = hn.to_device_graph(idx)
+    q = jnp.asarray(queries_from_db(db, 16, seed=seed + 30))
+    for beam in (1, 4):
+        ids, _, _ = jax.tree.map(np.asarray, jax.jit(
+            lambda qs: hn.search_hnsw(g, qs, 32, 32, beam=beam))(q))
+        for row in ids:
+            real = row[row >= 0]
+            assert len(real) and len(set(real.tolist())) == len(real), row
+
+
+def test_deduped_slots_are_counted(tiny_index):
+    """``deduped`` counts the neighbour slots the traversal masked as
+    already in the result queue or repeated in the expansion: some on
+    every search that expands, never more than the slots expanded."""
+    import jax
+    import jax.numpy as jnp
+    db, idx = tiny_index
+    g = hn.to_device_graph(idx)
+    m2 = idx.base_adj.shape[1]
+    q = jnp.asarray(queries_from_db(db, 16, seed=22))
+    _, _, st_ = jax.tree.map(np.asarray, jax.jit(
+        lambda qs: hn.search_hnsw(g, qs, 10, 64, beam=4))(q))
+    assert st_.deduped.shape == st_.expansions.shape
+    assert (st_.deduped > 0).all()
+    assert (st_.deduped <= st_.expansions * m2).all()
+    eng = HNSWEngine(db, index=idx, ef_search=64, backend="jnp")
+    eng.search(np.asarray(q), 10)
+    assert 0 < eng.stats["deduped"] <= eng.stats["neighbour_evals"]

@@ -410,3 +410,36 @@ def test_hnsw_service_publishes_build_and_traversal_families(data, tmp_path):
     assert validate_metrics_jsonl(
         out, require_families=REQUIRED_METRIC_FAMILIES
         + HNSW_METRIC_FAMILIES) == []
+
+
+def test_hnsw_batch_exports_the_deduped_slots(data, tmp_path):
+    """Each HNSW batch adds the traversal's deduped neighbour slots to
+    ``service_hnsw_deduped_slots_total``, and the schema check CLI passes
+    an export that must hold every HNSW family, that one included."""
+    import importlib.util
+    from pathlib import Path
+    from repro.obs.schema import HNSW_METRIC_FAMILIES
+    db, _, q = data
+    svc = SearchService(db[:300], engines=("hnsw",), backend="jnp", k=K,
+                        hnsw_m=6, hnsw_ef_construction=24,
+                        hnsw_ef_search=24, hnsw_build_round=64)
+    fam = svc.metrics.family("service_hnsw_deduped_slots_total")
+    total = 0
+    for batch in (q[:8], q[8:16]):
+        svc.submit(batch, engine="hnsw")
+        svc.flush()
+        total += svc.engines["hnsw"].stats["deduped"]
+        assert fam.value(engine="hnsw") == total
+    assert total > 0
+    assert "service_hnsw_deduped_slots_total" in HNSW_METRIC_FAMILIES
+    out = tmp_path / "metrics.jsonl"
+    svc.metrics.export_jsonl(out)
+    cli = Path(__file__).resolve().parents[1] / "benchmarks" \
+        / "check_obs_schema.py"
+    spec = importlib.util.spec_from_file_location("check_obs_schema", cli)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    args = ["--metrics", str(out)]
+    for f in REQUIRED_METRIC_FAMILIES + HNSW_METRIC_FAMILIES:
+        args += ["--require-family", f]
+    assert mod.main(args) == 0
